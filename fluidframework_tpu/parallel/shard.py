@@ -109,6 +109,22 @@ def _shard_put(mesh: Mesh, tree):
     return jax.tree.map(lambda x: jax.device_put(jnp.asarray(x), shard), tree)
 
 
+def _docs_per_device(export, n_padded: int, n_real: int) -> dict:
+    """``{"docs_on_device_<id>": n}``: how many REAL (non-pad) documents
+    each device folded, read off the doc-sharded export's own shards —
+    a shard's leading-axis row range is its device's documents."""
+    out: dict = {}
+    if export.shape[0] != n_padded:
+        return out  # not doc-major: nothing to attribute
+    for shard in export.addressable_shards:
+        rows = shard.index[0]
+        lo = rows.start or 0
+        hi = n_padded if rows.stop is None else rows.stop
+        key = f"docs_on_device_{shard.device.id}"
+        out[key] = out.get(key, 0) + max(0, min(hi, n_real) - lo)
+    return out
+
+
 def sharded_export_step(mesh: Mesh, S: int, i16: bool, ob_rows: bool,
                         ov_rows: bool, i8: bool, sequential: bool,
                         has_props: bool, warm: bool,
@@ -248,6 +264,8 @@ def replay_family_sharded(
         t0 = perf_counter()
         _block_until_ready(core, dig)
         _bump(stage, "device_wait", t0)
+        _bump_stats(_docs_per_device(jax.tree.leaves(core)[0],
+                                     len(padded), n_real))
 
         # Pad trimming: served/changed/extraction all operate on the
         # REAL prefix (pads sit at the tail), so stats and the tier-0

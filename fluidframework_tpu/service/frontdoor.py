@@ -168,6 +168,9 @@ class ProcShard(ShardHandle):
         if fault_plan_path:
             cmd += ["--fault-plan", fault_plan_path]
         cmd += list(extra_args)
+        # Shard hosts fold on the CPU: a chip belongs to one process, so
+        # N hosts cannot share it (ROADMAP queue 2).  Their catch-up
+        # answers say so in "platform".
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         self.proc = subprocess.Popen(
             cmd, cwd=_REPO_ROOT, env=env, stdout=subprocess.PIPE,
@@ -807,8 +810,8 @@ class FrontDoor:
                 doc_ids = sorted(self._docs)
         groups = self._group_by_owner(doc_ids)
         merged = {"docs": {}, "skipped": [], "deviceDocs": 0, "cpuDocs": 0,
-                  "cache": None, "deltaCache": None, "lane": None,
-                  "lanes": {}, "degraded": []}
+                  "platform": None, "cache": None, "deltaCache": None,
+                  "lane": None, "lanes": {}, "degraded": []}
         for sid, docs in sorted(groups.items()):
             part = self._shard(sid).request(
                 "catchup", dict(params, docs=docs))
@@ -816,6 +819,7 @@ class FrontDoor:
             merged["skipped"].extend(part.get("skipped", ()))
             merged["deviceDocs"] += part.get("deviceDocs", 0)
             merged["cpuDocs"] += part.get("cpuDocs", 0)
+            merged["platform"] = part.get("platform") or merged["platform"]
             merged["degraded"].extend(part.get("degraded", ()))
             merged["lanes"][sid] = part.get("lane")
         merged["skipped"] = sorted(merged["skipped"])

@@ -60,7 +60,12 @@ def test_sharded_replay_matches_oracle_and_single_chip(fuzz_docs):
     # silently dropped its stats).
     assert stats.get("device_docs", 0) + stats.get("fallback_docs", 0) \
         == len(docs)
+    # ...plus where each device-folded document ran (mesh only).
+    per_device = {k: stats.pop(k) for k in list(stats)
+                  if k.startswith("docs_on_device_")}
     assert stats == single_stats
+    assert len(per_device) == mesh.size
+    assert stats["device_docs"] <= sum(per_device.values()) <= len(docs)
 
 
 def test_sharded_replay_single_doc_pads_to_mesh(fuzz_docs):

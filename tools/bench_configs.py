@@ -1,7 +1,7 @@
 """All five BASELINE.json configs measured: CPU oracle vs device path.
 
-BASELINE.md's measurement table is produced by this harness (run on the
-bench TPU; the committed numbers there cite the run).  Each config times
+BASELINE.md's measurement table is produced by this harness.  Each config
+times
 
 - the CPU oracle (per-op ``process`` replay through the DDS, the pinned 1×
   denominator) on a doc sample, and
@@ -334,19 +334,16 @@ def run_config(name, docs, n_ops, oracle_fn, device_batch_fn,
 
 
 def main() -> None:
-    """Environment-hardened entry: bench.run_hardened is the ONE shared
-    harness (probe skip-line, deadline watchdog, env-vs-bug-vs-correctness
-    classification) — no second copy to drift out of sync."""
+    """Prints the one result line; any failure propagates with its
+    traceback and a non-zero exit."""
     import bench
+    from fluidframework_tpu.utils.compile_cache import setup_compile_cache
 
-    bench.run_hardened(
-        "baseline_configs", _run_configs,
-        float(os.environ.get("BENCHCFG_DEADLINE", "3000")),
-        skip_base={"configs": None},
-    )
+    setup_compile_cache()
+    print(json.dumps(_run_configs(bench.device_info())), flush=True)
 
 
-def _run_configs(probe: dict) -> dict:
+def _run_configs(device: dict) -> dict:
     sizes = {
         "sharedstring": (int(os.environ.get("BENCHCFG_STRING_DOCS", "4096")),
                          96),
@@ -403,8 +400,9 @@ def _run_configs(probe: dict) -> dict:
 
     return {
         "metric": "baseline_configs",
-        "backend": probe.get("platform", jax.default_backend()),
-        "device_kind": probe.get("device_kind", "?"),
+        "backend": device["platform"],
+        "device_kind": device["device_kind"],
+        "n_devices": device["n_devices"],
         "configs": results,
     }
 

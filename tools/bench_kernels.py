@@ -24,8 +24,8 @@ Byte-identity is asserted in-run: caches-on == caches-off ==
 forced epoch invalidation, and against the ``dds/`` per-op oracles on a
 deterministic sample (``BENCHK_ORACLE_EVERY``; 1 = every doc).
 
-Prints ONE JSON line (``bench.run_hardened`` — probe skip-line, deadline
-watchdog, correctness-vs-environment classification):
+Prints ONE JSON line naming its device; any failure exits non-zero with
+its traceback:
 
     JAX_PLATFORMS=cpu python tools/bench_kernels.py \
         > BENCH_kernels_cpu_r14.json
@@ -33,6 +33,7 @@ watchdog, correctness-vs-environment classification):
 
 from __future__ import annotations
 
+import json
 import os
 import random
 import sys
@@ -63,7 +64,6 @@ IV_OPS = int(os.environ.get("BENCHK_IV_OPS", "96"))
 ORACLE_EVERY = int(os.environ.get("BENCHK_ORACLE_EVERY", "4"))
 CHUNK = int(os.environ.get("BENCHK_CHUNK", "1024"))
 GROW_EVERY = int(os.environ.get("BENCHK_GROW_EVERY", "8"))
-DEADLINE = float(os.environ.get("BENCHK_DEADLINE", "2700"))
 
 ALPHABET = "abcdefghijklmnopqrstuvwxyz "
 
@@ -575,17 +575,14 @@ def run_interval_stabbing() -> dict:
     }
 
 
-def _run(probe: dict) -> dict:
-    import bench
-
-    bench.CURRENT_PHASE["phase"] = "tree-rebase"
+def _run(device: dict) -> dict:
     tree = run_tree_rebase()
-    bench.CURRENT_PHASE["phase"] = "interval-stabbing"
     intervals = run_interval_stabbing()
-    bench.CURRENT_PHASE["phase"] = "done"
     return {
         "metric": METRIC,
-        "backend": probe.get("platform", "unknown"),
+        "backend": device["platform"],
+        "device_kind": device["device_kind"],
+        "n_devices": device["n_devices"],
         "tree_rebase": tree,
         "interval_stabbing": intervals,
     }
@@ -593,11 +590,10 @@ def _run(probe: dict) -> dict:
 
 def main() -> None:
     import bench
+    from fluidframework_tpu.utils.compile_cache import setup_compile_cache
 
-    bench.run_hardened(
-        METRIC, _run, DEADLINE,
-        skip_base={"tree_rebase": None, "interval_stabbing": None},
-    )
+    setup_compile_cache()
+    print(json.dumps(_run(bench.device_info())), flush=True)
 
 
 if __name__ == "__main__":
