@@ -9,6 +9,8 @@ from __future__ import annotations
 
 from typing import Callable, List, Optional, Sequence, TypeVar, Union
 
+from ..utils.telemetry import span
+
 Doc = TypeVar("Doc")
 Result = TypeVar("Result")
 
@@ -35,6 +37,7 @@ def partition_replay(
     fallback_fn: Callable[[Doc], Result],
     batch_fn: Callable[[List[Doc]], List[Result]],
     stats: Optional[dict] = None,
+    stage: Optional[dict] = None,
 ) -> List[Result]:
     """Route docs matching ``known_fallback`` through ``fallback_fn`` (the
     oracle), fold the rest as one device batch, and return results in the
@@ -44,18 +47,25 @@ def partition_replay(
     ``stats`` (optional dict) then accumulates ``fallback_docs`` plus a
     per-reason ``fallback_<reason>`` counter for the pre-pack routing
     (post-fold fallbacks are the extractors' to count, through the same
-    :func:`count_fallback`)."""
+    :func:`count_fallback`).  ``stage`` (optional dict) accumulates the
+    seconds the fallback folds took under ``fallback``."""
     if not docs:
         return []
     out: List[Optional[Result]] = [None] * len(docs)
     device_idx: List[int] = []
+    fallback: List[tuple] = []
     for i, doc in enumerate(docs):
         reason = known_fallback(doc)
         if reason:
-            out[i] = fallback_fn(doc)
-            count_fallback(stats, reason)
+            fallback.append((i, reason))
         else:
             device_idx.append(i)
+    if fallback:
+        with span("pipeline.fallback", stage, "fallback",
+                  docs=len(fallback)):
+            for i, reason in fallback:
+                out[i] = fallback_fn(docs[i])
+                count_fallback(stats, reason)
     if device_idx:
         results = batch_fn([docs[i] for i in device_idx])
         assert len(results) == len(device_idx)

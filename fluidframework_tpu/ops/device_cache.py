@@ -124,7 +124,6 @@ def _splice_ops(ops: MTOps, rows: MTOps, start, count) -> MTOps:
 
 @functools.lru_cache(maxsize=16)
 def _splice_jit(tuple_type):
-    @functools.partial(jax.jit, donate_argnums=(0,))
     def _splice(resident, rows, start, count):
         lead = getattr(resident, tuple_type._fields[0])
         T = lead.shape[1]
@@ -146,7 +145,11 @@ def _splice_jit(tuple_type):
         return tuple_type(*(one(getattr(resident, f), getattr(rows, f))
                             for f in tuple_type._fields))
 
-    return _splice
+    # A stable program name per spliced plane group on the device trace
+    # (splice_mtops, splice_treeedits, splice_treenodeplanes, ...).
+    _splice.__name__ = _splice.__qualname__ = \
+        f"splice_{tuple_type.__name__.strip('_').lower()}"
+    return jax.jit(_splice, donate_argnums=(0,))
 
 
 def gather_suffix_rows(tuple_type, host_tree, t_old: np.ndarray,

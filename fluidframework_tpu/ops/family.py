@@ -70,8 +70,9 @@ class KernelFamily:
       - ``fetch(core)`` → host arrays (the full d2h transfer);
       - ``gather_rows(core, idx)`` → ``(rows, moved_bytes)``: only the
         changed documents' rows;
-      - ``extract(meta, arr, stats)`` → summaries (counting post-fold
-        fallbacks per reason into ``stats``);
+      - ``extract(meta, arr, stats, stage)`` → summaries (counting
+        post-fold fallbacks per reason into ``stats``, and the seconds of
+        their oracle folds into ``stage["fallback"]``);
       - ``per_doc_meta``: names of per-doc ndarray meta entries the
         changed-rows sub-meta must slice alongside docs/doc_packs.
 
@@ -102,7 +103,7 @@ class KernelFamily:
     # download / extract / tier 0
     fetch: Callable[[Any], Any]
     gather_rows: Callable[[Any, Any], Tuple[Any, int]]
-    extract: Callable[[dict, Any, dict], Any]
+    extract: Callable[[dict, Any, dict, Optional[dict]], Any]
     per_doc_meta: Tuple[str, ...] = ()
     # mesh
     make_pad: Optional[Callable[[], Any]] = None

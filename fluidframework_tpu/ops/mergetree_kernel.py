@@ -47,6 +47,7 @@ import numpy as np
 
 from ..protocol.messages import MessageType, SequencedMessage
 from ..protocol.summary import SummaryTree, canonical_json
+from ..utils.telemetry import span
 from .interning import Interner, TextArena, next_bucket, next_bucket_fine
 from .native_pack import count_stream
 
@@ -656,7 +657,9 @@ def split_export_digest(export, digested: bool):
 
 
 @jax.jit
-def _take_docs(a: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+def export_gather(a: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """The delta download's device gather of changed documents' export
+    rows (both families)."""
     return jnp.take(a, idx, axis=0)
 
 
@@ -709,7 +712,7 @@ def gather_export_rows(export, idx: np.ndarray):
                 padded = np.concatenate(
                     [rows, np.repeat(rows[-1:], pad)]) if pad else rows
                 dev_idx = jnp.asarray(padded, jnp.int32)
-            dev = _take_docs(a, dev_idx)  # bucketed-by: next_bucket_fine
+            dev = export_gather(a, dev_idx)  # bucketed-by: next_bucket_fine
             full = np.asarray(dev)
             moved += full.nbytes
             got = full[:m]
@@ -953,12 +956,24 @@ def _export_with_digest(final, doc_base, i16, ob_rows, ov_rows, i8,
                         has_props, digest: bool):
     """Export a final state, optionally appending the [D, 2] digest plane
     as the LAST output leaf (see ``split_export_digest``)."""
-    ex = _export_state(final, doc_base, i16, ob_rows, ov_rows, i8,
-                       props_rows=has_props)
+    with jax.named_scope("export"):
+        ex = _export_state(final, doc_base, i16, ob_rows, ov_rows, i8,
+                           props_rows=has_props)
     if not digest:
         return ex
-    dig = _doc_digests(final, doc_base)
+    with jax.named_scope("digest"):
+        dig = _doc_digests(final, doc_base)
     return ex + (dig,) if isinstance(ex, tuple) else (ex, dig)
+
+
+def program_name(family: str, digest: bool, start: str = "") -> str:
+    """The stable module name of a family's fold+export program
+    (``jit_<name>`` on the device trace's XLA Modules line): the family,
+    whether the digest plane rides along, and the start (``cold`` or
+    ``warm``) where the family compiles the two apart.  Inside it the
+    ``fold``, ``export`` and ``digest`` named scopes label the ops."""
+    return (f"{family}_fold_export{'_digest' if digest else ''}"
+            f"{'_' + start if start else ''}")
 
 
 @functools.lru_cache(maxsize=None)
@@ -977,12 +992,13 @@ def _export_cold_fn(S: int, i16: bool, ob_rows: bool = True,
     fold = _fold_fn(fold_mode, sequential, ob_rows, has_props, ov_rows)
 
     def f(ops, doc_base):
-        ops = _widen_ops(ops, doc_base)
-        return _export_with_digest(
-            fold(_cold_start(ops, S), ops), doc_base, i16, ob_rows,
-            ov_rows, i8, has_props, digest,
-        )
+        with jax.named_scope("fold"):
+            ops = _widen_ops(ops, doc_base)
+            final = fold(_cold_start(ops, S), ops)
+        return _export_with_digest(final, doc_base, i16, ob_rows, ov_rows,
+                                   i8, has_props, digest)
 
+    f.__name__ = f.__qualname__ = program_name("mergetree", digest, "cold")
     fmt = _export_out(i8, out_sharding, digest)
     return jax.jit(f, out_shardings=fmt) if fmt is not None else jax.jit(f)
 
@@ -997,11 +1013,14 @@ def _export_warm_fn(i16: bool, ob_rows: bool = True, fold_mode: str = "",
     fold = _fold_fn(fold_mode, sequential, ob_rows, has_props, ov_rows)
 
     def f(state, ops, doc_base):
-        state = _widen_state(state, doc_base)
-        ops = _widen_ops(ops, doc_base)
-        return _export_with_digest(fold(state, ops), doc_base, i16,
-                                   ob_rows, ov_rows, i8, has_props, digest)
+        with jax.named_scope("fold"):
+            state = _widen_state(state, doc_base)
+            ops = _widen_ops(ops, doc_base)
+            final = fold(state, ops)
+        return _export_with_digest(final, doc_base, i16, ob_rows, ov_rows,
+                                   i8, has_props, digest)
 
+    f.__name__ = f.__qualname__ = program_name("mergetree", digest, "warm")
     fmt = _export_out(i8, out_sharding, digest)
     return jax.jit(f, out_shardings=fmt) if fmt is not None else jax.jit(f)
 
@@ -1761,14 +1780,17 @@ def oracle_fallback_summary(doc: MergeTreeDocInput) -> SummaryTree:
 
 
 def summaries_from_export(meta, export_np: np.ndarray,
-                          stats: Optional[dict] = None) -> List[SummaryTree]:
+                          stats: Optional[dict] = None,
+                          stage: Optional[dict] = None) -> List[SummaryTree]:
     """Canonical summaries for a whole chunk from the fused export buffer.
 
     Bodies come from the C++ extractor (one pass over the buffer) when
     liboppack is available, else the per-slot Python extraction; interval
     blobs and oracle-fallback docs take the host paths either way.
     ``stats`` (optional dict) accumulates ``device_docs`` /
-    ``fallback_docs`` counters — the true device-vs-oracle split."""
+    ``fallback_docs`` counters — the true device-vs-oracle split;
+    ``stage`` (optional dict) the seconds of the chunk's oracle folds
+    under ``fallback``."""
     from .interval_replay import FinalStateView, replay_intervals
     from .native_pack import extract_bodies
 
@@ -1808,12 +1830,12 @@ def summaries_from_export(meta, export_np: np.ndarray,
         meta["prop_keys"], list(meta["values"].values),
         msn, body_skip, int(NOT_REMOVED),
     )
-    out: List[SummaryTree] = []
+    out: List[Optional[SummaryTree]] = []
     live_len = state_np["live_len"]
     for d, doc in enumerate(docs):
         pack = meta["doc_packs"][d]
         if skip[d]:
-            out.append(oracle_fallback_summary(doc))
+            out.append(None)  # the oracle's, below
             continue
         tree = SummaryTree()
         # Byte-equal to canonical_json({...}) (keys pre-sorted, minimal
@@ -1851,6 +1873,12 @@ def summaries_from_export(meta, export_np: np.ndarray,
             if intervals:
                 tree.add_blob("intervals", canonical_json(intervals))
         out.append(tree)
+    skipped = np.flatnonzero(skip)
+    if len(skipped):
+        with span("pipeline.fallback", stage, "fallback",
+                  docs=len(skipped)):
+            for d in skipped:
+                out[d] = oracle_fallback_summary(docs[d])
     return out
 
 

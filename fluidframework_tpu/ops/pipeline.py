@@ -39,11 +39,11 @@ import collections
 import itertools
 import threading
 from concurrent.futures import ThreadPoolExecutor
-from time import perf_counter
 from typing import List, Optional, Sequence
 
 import numpy as np
 
+from ..utils.telemetry import span
 from .batching import partition_replay
 from .family import KernelFamily
 from .interning import Interner, next_bucket_fine
@@ -558,8 +558,9 @@ def pipelined_family_replay(
     documents served from the tier-0 delta cache without a download);
     ``stage`` (if given) accumulates busy seconds under
     ``pack``/``dispatch``/``upload``/``device_wait``/``download``/
-    ``extract`` and the integer byte counters ``h2d_bytes``/``d2h_bytes``
-    — the bench harness's instrumentation hook; ``packed_out`` (if
+    ``extract``/``fallback`` (the ``pipeline.<key>`` spans, one per
+    chunk or call) and the integer byte counters
+    ``h2d_bytes``/``d2h_bytes``; ``packed_out`` (if
     given) collects ``(state, ops, meta, tag)`` per chunk in schedule
     order so a caller can reuse the pack work; ``pack_cache`` (if given,
     built over THIS family) reuses packed windows across calls for docs
@@ -596,7 +597,7 @@ def pipelined_family_replay(
 
     return partition_replay(
         docs, family.known_fallback, family.fallback_summary, fold,
-        stats=stats,
+        stats=stats, stage=stage,
     )
 
 
@@ -627,11 +628,6 @@ def pipelined_mergetree_replay(
         delta_cache=delta_cache, device_cache=device_cache,
         pin_resident=pin_resident,
     )
-
-
-def _bump(stage: Optional[dict], key: str, t0: float) -> None:
-    if stage is not None:
-        stage[key] = stage.get(key, 0.0) + (perf_counter() - t0)
 
 
 def _count_d2h(stage: Optional[dict], nbytes: int) -> None:
@@ -681,9 +677,12 @@ def _block_until_ready(*handles) -> None:
 
 
 #: THE stage schema, identical for every family, single-device and mesh
-#: (the byte counters ride as ints next to the busy seconds).
+#: (the byte counters ride as ints next to the busy seconds).  Each key
+#: is the aggregate of the ``pipeline.<key>`` spans; ``fallback`` (the
+#: oracle folds of routed-out documents) is also counted inside
+#: ``extract`` where the extractor takes them.
 STAGE_KEYS = ("pack", "upload", "dispatch", "device_wait", "download",
-              "extract")
+              "extract", "fallback")
 
 
 def seed_stage(stage: Optional[dict]) -> None:
@@ -707,55 +706,51 @@ def _pipelined_fold(family, batch, chunk_docs, pack_threads,
     starts = list(range(0, len(sched), chunk_docs))
 
     def pack_one(lo):
-        t0 = perf_counter()
-        chunk = sched[lo:lo + chunk_docs]
-        if pack_cache is not None:
-            state, ops, meta = pack_cache.pack(chunk)
-        else:
-            state, ops, meta = family.pack(chunk)
-        state, ops = family.narrow(chunk, state, ops, meta)
-        return state, ops, meta, perf_counter() - t0
+        with span("pipeline.pack", stage, "pack", chunk=lo // chunk_docs):
+            chunk = sched[lo:lo + chunk_docs]
+            if pack_cache is not None:
+                state, ops, meta = pack_cache.pack(chunk)
+            else:
+                state, ops, meta = family.pack(chunk)
+            state, ops = family.narrow(chunk, state, ops, meta)
+        return state, ops, meta, lo // chunk_docs
 
-    def extract_one(meta, arr):
-        t0 = perf_counter()
-        st: dict = {}
-        res = family.extract(meta, arr, st)
-        return res, st, perf_counter() - t0
-
-    def extract_full_store(meta, arr, dig_np):
-        """Full-download extraction that also (re)publishes every doc's
-        tier-0 entry — the cold-fill leg of the delta path."""
-        res, st, dt = extract_one(meta, arr)
-        t0 = perf_counter()
-        delta_store_all(delta_cache, meta["docs"], dig_np, res)
-        return res, st, dt + (perf_counter() - t0)
+    def extract_one(i, meta, arr, dig_np=None):
+        """Full-download extraction; with ``dig_np`` it also
+        (re)publishes every doc's tier-0 entry — the cold-fill leg of the
+        delta path."""
+        with span("pipeline.extract", stage, "extract", chunk=i):
+            st: dict = {}
+            res = family.extract(meta, arr, st, stage)
+            if dig_np is not None:
+                delta_store_all(delta_cache, meta["docs"], dig_np, res)
+        return res, st
 
     def extract_served(docs, served):
         """Whole chunk served from tier 0: zero download, zero extract."""
         return [served[d] for d in range(len(docs))], \
-            {"delta_docs": len(docs)}, 0.0
+            {"delta_docs": len(docs)}
 
-    def extract_delta(meta, arr, changed, served, dig_np):
+    def extract_delta(i, meta, arr, changed, served, dig_np):
         """Extract ONLY the changed documents from their gathered rows;
         unchanged documents serve their cached summaries byte-identically
         (the cached tree came out of this same extraction under an equal
         digest + host anchor)."""
-        t0 = perf_counter()
-        st: dict = {}
-        got = family.extract(
-            delta_sub_meta(meta, changed, family.per_doc_meta), arr, st)
-        res = delta_merge_changed(delta_cache, meta, dig_np, served,
-                                  changed, got)
+        with span("pipeline.extract", stage, "extract", chunk=i):
+            st: dict = {}
+            got = family.extract(
+                delta_sub_meta(meta, changed, family.per_doc_meta), arr, st,
+                stage)
+            res = delta_merge_changed(delta_cache, meta, dig_np, served,
+                                      changed, got)
         st["delta_docs"] = st.get("delta_docs", 0) + len(served)
-        return res, st, perf_counter() - t0
+        return res, st
 
     out: List = []
 
     def collect(fut) -> None:
-        res, st, dt = fut.result()
+        res, st = fut.result()
         out.extend(res)
-        if stage is not None:
-            stage["extract"] = stage.get("extract", 0.0) + dt
         if stats is not None:
             for k, v in st.items():
                 stats[k] = stats.get(k, 0) + v
@@ -771,24 +766,24 @@ def _pipelined_fold(family, batch, chunk_docs, pack_threads,
                 pack_futs.append(pack_pool.submit(pack_one, starts[next_i]))
                 next_i += 1
 
-            def fetch_one(meta, core, dig, cand) -> None:
+            def fetch_one(i, meta, core, dig, cand) -> None:
                 # Honest stage split: wait for the DEVICE to finish first
                 # (fold + export compute), so "download" times the copy
                 # alone and d2h_bytes attributes what actually crossed.
-                t0 = perf_counter()
-                _block_until_ready(core, dig)
-                _bump(stage, "device_wait", t0)
+                with span("pipeline.device_wait", stage, "device_wait",
+                          chunk=i):
+                    _block_until_ready(core, dig)
                 docs = meta["docs"]
                 if dig is None:
-                    t0 = perf_counter()
-                    arr = family.fetch(core)  # the d2h link RPC(s)
-                    _bump(stage, "download", t0)
+                    with span("pipeline.download", stage, "download",
+                              chunk=i):
+                        arr = family.fetch(core)  # the d2h link RPC(s)
                     _count_d2h(stage, _nbytes(arr))
-                    ex_futs.append(ex_pool.submit(extract_one, meta, arr))
+                    ex_futs.append(ex_pool.submit(extract_one, i, meta, arr))
                 else:
-                    t0 = perf_counter()
-                    dig_np = np.asarray(dig)  # the tiny eager round-trip
-                    _bump(stage, "download", t0)
+                    with span("pipeline.download", stage, "download",
+                              chunk=i):
+                        dig_np = np.asarray(dig)  # the tiny eager round-trip
                     _count_d2h(stage, dig_np.nbytes)
                     # Host cache work stays OUTSIDE the download window
                     # (the stage times link traffic alone); one lock
@@ -800,12 +795,12 @@ def _pipelined_fold(family, batch, chunk_docs, pack_threads,
                     if route == "full":
                         # Cold / all-changed / fallback route — and the
                         # golden oracle the delta path is tested against.
-                        t0 = perf_counter()
-                        arr = family.fetch(core)
-                        _bump(stage, "download", t0)
+                        with span("pipeline.download", stage, "download",
+                                  chunk=i):
+                            arr = family.fetch(core)
                         _count_d2h(stage, _nbytes(arr))
                         ex_futs.append(ex_pool.submit(
-                            extract_full_store, meta, arr, dig_np))
+                            extract_one, i, meta, arr, dig_np))
                     elif route == "served":
                         delta_cache.note_bytes_saved(_nbytes(core))
                         ex_futs.append(ex_pool.submit(
@@ -816,15 +811,15 @@ def _pipelined_fold(family, batch, chunk_docs, pack_threads,
                         # when padding would move it all) elsewhere —
                         # the family's gather owns that choice and
                         # reports the bytes that really crossed.
-                        t0 = perf_counter()
-                        sub, fetched = family.gather_rows(
-                            core, np.asarray(changed, np.int32))
-                        _bump(stage, "download", t0)
+                        with span("pipeline.download", stage, "download",
+                                  chunk=i):
+                            sub, fetched = family.gather_rows(
+                                core, np.asarray(changed, np.int32))
                         _count_d2h(stage, fetched)
                         delta_cache.note_bytes_saved(
                             max(0, _nbytes(core) - fetched))
                         ex_futs.append(ex_pool.submit(
-                            extract_delta, meta, sub, changed, served,
+                            extract_delta, i, meta, sub, changed, served,
                             dig_np))
                 if len(ex_futs) >= extract_threads + 1:
                     collect(ex_futs.popleft())
@@ -832,13 +827,11 @@ def _pipelined_fold(family, batch, chunk_docs, pack_threads,
             want_digest = delta_cache is not None
             while pack_futs:
                 fut = pack_futs.popleft()
-                state, ops, meta, dt = fut.result()
+                state, ops, meta, i = fut.result()
                 if next_i < len(starts):
                     pack_futs.append(
                         pack_pool.submit(pack_one, starts[next_i]))
                     next_i += 1
-                if stage is not None:
-                    stage["pack"] = stage.get("pack", 0.0) + dt
                 # --- upload leg (tier 2.5): resident buffers on a warm
                 # window, donated suffix splice on a grown one, full
                 # device_put otherwise.  All device interaction stays on
@@ -850,31 +843,29 @@ def _pipelined_fold(family, batch, chunk_docs, pack_threads,
                 base_dev = None
                 host_state, host_ops = state, ops
                 if device_cache is not None:
-                    t0 = perf_counter()
-                    state, ops, base_dev, up_bytes = \
-                        device_cache.acquire(state, ops, meta,
-                                             pin=pin_resident)
-                    _bump(stage, "upload", t0)
+                    with span("pipeline.upload", stage, "upload", chunk=i):
+                        state, ops, base_dev, up_bytes = \
+                            device_cache.acquire(state, ops, meta,
+                                                 pin=pin_resident)
                     _count_h2d(stage, up_bytes)
                 else:
                     _count_h2d(stage,
                                _np_nbytes(state) + _np_nbytes(ops))
-                t0 = perf_counter()
-                ex = family.dispatch(state, ops, meta, want_digest,
-                                     base_dev)
-                core, dig = family.split_digest(ex, want_digest)
-                cand = want_digest and delta_cache.any_candidate(
-                    meta["docs"])
-                if dig is not None:
-                    _start_host_copy(dig)
-                if dig is None or not cand:
-                    # No tier-0 candidate can skip the download: start
-                    # the full async copy at dispatch like the plain
-                    # path.  With candidates present, starting it would
-                    # transfer the very bytes delta download exists to
-                    # avoid.
-                    _start_host_copy(core)
-                _bump(stage, "dispatch", t0)
+                with span("pipeline.dispatch", stage, "dispatch", chunk=i):
+                    ex = family.dispatch(state, ops, meta, want_digest,
+                                         base_dev)
+                    core, dig = family.split_digest(ex, want_digest)
+                    cand = want_digest and delta_cache.any_candidate(
+                        meta["docs"])
+                    if dig is not None:
+                        _start_host_copy(dig)
+                    if dig is None or not cand:
+                        # No tier-0 candidate can skip the download:
+                        # start the full async copy at dispatch like the
+                        # plain path.  With candidates present, starting
+                        # it would transfer the very bytes delta download
+                        # exists to avoid.
+                        _start_host_copy(core)
                 if packed_out is not None:
                     # state included so a caller re-timing the fold can
                     # replay WARM chunks with the same executable the e2e
@@ -884,7 +875,7 @@ def _pipelined_fold(family, batch, chunk_docs, pack_threads,
                     # reference must never die under the caller.
                     packed_out.append((host_state, host_ops, meta,
                                        family.chunk_tag(meta)))
-                inflight.append((meta, core, dig, cand))
+                inflight.append((i, meta, core, dig, cand))
                 if len(inflight) > fetch_depth:
                     fetch_one(*inflight.popleft())
             while inflight:
@@ -1021,8 +1012,8 @@ MERGETREE_FAMILY = KernelFamily(
     chunk_tag=_chunk_S,
     fetch=export_to_numpy,
     gather_rows=gather_export_rows,
-    extract=lambda meta, arr, st: summaries_from_export(meta, arr,
-                                                        stats=st),
+    extract=lambda meta, arr, st, stage: summaries_from_export(
+        meta, arr, stats=st, stage=stage),
     per_doc_meta=("doc_base",),
     make_pad=lambda: MergeTreeDocInput(doc_id="\x00pad", ops=[]),
     pad_token=_mt_pad_token,

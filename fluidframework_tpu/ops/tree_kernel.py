@@ -38,6 +38,8 @@ import numpy as np
 
 from ..protocol.messages import SequencedMessage
 from ..protocol.summary import SummaryTree, canonical_json
+from ..utils.telemetry import span
+from .batching import count_fallback
 from .interning import Interner, next_bucket
 
 NOT_REMOVED = np.int32(np.iinfo(np.int32).max)
@@ -730,20 +732,54 @@ def _known_tree_fallback_uncached(doc: TreeDocInput):
     return None
 
 
-def summary_from_state(meta, state_np: dict, d: int,
-                       stats: Optional[dict] = None) -> SummaryTree:
-    """Final device state → the oracle's canonical summary bytes.
-    ``stats`` counts this doc as device/fallback WHERE the routing
-    decision is made — per REASON (revive / multi-id move / MAX_DEPTH
-    overflow / …) through the shared ``count_fallback`` — so the
-    counters can never drift from the actual serving path."""
-    from .batching import count_fallback
+def summaries_from_tree_export(meta, arr, stats: Optional[dict] = None,
+                               stage: Optional[dict] = None
+                               ) -> List[SummaryTree]:
+    """Downloaded final-forest planes → canonical summaries.  The tree
+    family's one routing site: ``stats`` counts each document as device
+    or fallback WHERE it is routed — per REASON (revive / multi-id move /
+    MAX_DEPTH overflow / chain cycle / …) through the shared
+    ``count_fallback`` — and the oracle folds of the fallbacks run in one
+    ``pipeline.fallback`` span (seconds under ``stage["fallback"]``).
+    ``arr`` is the fetched core tuple in ``TreeState`` field order —
+    either a whole chunk's rows or the tier-0 changed-rows gather (the
+    meta is then the sliced sub-meta)."""
+    state_np = dict(zip(TreeState._fields, arr))
+    docs = meta["docs"]
+    out: List[Optional[SummaryTree]] = [None] * len(docs)
+    skipped = []
+    for d in range(len(docs)):
+        pack: _DocPack = meta["doc_packs"][d]
+        if pack.needs_fallback or bool(state_np["overflow"][d]):
+            skipped.append((d, pack.fallback_reason or "max_depth"))
+            continue
+        try:
+            out[d] = summary_from_state(meta, state_np, d)
+        except (_ChainCycleError, RecursionError):
+            # A next-link or container-nesting cycle (out-of-contract
+            # input such as duplicate node ids): extraction must never
+            # hang or blow the stack — lose the device win, serve the
+            # oracle bytes.
+            skipped.append((d, "chain_cycle"))
+            continue
+        if stats is not None:
+            stats["device_docs"] = stats.get("device_docs", 0) + 1
+    if skipped:
+        with span("pipeline.fallback", stage, "fallback",
+                  docs=len(skipped)):
+            for d, reason in skipped:
+                count_fallback(stats, reason)
+                out[d] = oracle_fallback_summary(docs[d])
+    return out
 
+
+def summary_from_state(meta, state_np: dict, d: int) -> SummaryTree:
+    """Final device state of a document the device fold served → the
+    oracle's canonical summary bytes.  Raises ``_ChainCycleError`` (or
+    ``RecursionError``) on a link cycle, which
+    :func:`summaries_from_tree_export` routes to the oracle."""
     doc: TreeDocInput = meta["docs"][d]
     pack: _DocPack = meta["doc_packs"][d]
-    if pack.needs_fallback or bool(state_np["overflow"][d]):
-        count_fallback(stats, pack.fallback_reason or "max_depth")
-        return oracle_fallback_summary(doc)
     values: Interner = meta["values"]
     msn = max(doc.final_msn, pack.base_min_seq)
 
@@ -812,36 +848,26 @@ def summary_from_state(meta, state_np: dict, d: int,
                 out[fname] = kids
         return out
 
-    try:
-        root_obj = {
-            "fields": fields_obj(0),
-            "minSeq": msn,
-            "seq": pack.header_seq,
-        }
-        # Limbo: kept nodes still linked in a chain whose owning node is
-        # NOT kept (their enclosing tombstone expired).  The oracle
-        # detaches them at purge time; here they surface at extraction —
-        # same set, because rescued nodes were re-linked under kept
-        # owners by their moves.  Unlinked rows (e.g. content of
-        # oracle-skipped inserts, which are a pack-time fallback anyway)
-        # are reachable from no chain.
-        limbo_idxs = []
-        for c in range(len(pack.containers)):
-            owner = int(state_np["container_parent"][d][c])
-            if owner == NIL or keep(owner):
-                continue
-            limbo_idxs.extend(i for i in chain(c) if keep(i))
-        if limbo_idxs:
-            limbo_idxs.sort(key=lambda i: pack.node_ids.values[i])
-            root_obj["limbo"] = [node_obj(i) for i in limbo_idxs]
-    except (_ChainCycleError, RecursionError):
-        # A next-link or container-nesting cycle (out-of-contract input
-        # such as duplicate node ids): extraction must never hang or
-        # blow the stack — lose the device win, serve the oracle bytes.
-        count_fallback(stats, "chain_cycle")
-        return oracle_fallback_summary(doc)
-    if stats is not None:
-        stats["device_docs"] = stats.get("device_docs", 0) + 1
+    root_obj = {
+        "fields": fields_obj(0),
+        "minSeq": msn,
+        "seq": pack.header_seq,
+    }
+    # Limbo: kept nodes still linked in a chain whose owning node is NOT
+    # kept (their enclosing tombstone expired).  The oracle detaches them
+    # at purge time; here they surface at extraction — same set, because
+    # rescued nodes were re-linked under kept owners by their moves.
+    # Unlinked rows (e.g. content of oracle-skipped inserts, which are a
+    # pack-time fallback anyway) are reachable from no chain.
+    limbo_idxs = []
+    for c in range(len(pack.containers)):
+        owner = int(state_np["container_parent"][d][c])
+        if owner == NIL or keep(owner):
+            continue
+        limbo_idxs.extend(i for i in chain(c) if keep(i))
+    if limbo_idxs:
+        limbo_idxs.sort(key=lambda i: pack.node_ids.values[i])
+        root_obj["limbo"] = [node_obj(i) for i in limbo_idxs]
     tree = SummaryTree()
     tree.add_blob("header", canonical_json(root_obj))
     if doc.attribution:
@@ -882,10 +908,7 @@ def replay_tree_batch(docs: Sequence[TreeDocInput],
     """
     if not docs:
         return []
-    out: List[Optional[SummaryTree]] = [None] * len(docs)
     state, edits, meta = pack_tree_batch(docs)
     final = _replay_batch(state, edits)
-    state_np = {k: np.asarray(v) for k, v in final._asdict().items()}
-    for d in range(len(docs)):
-        out[d] = summary_from_state(meta, state_np, d, stats=stats)
-    return out
+    return summaries_from_tree_export(
+        meta, tuple(np.asarray(v) for v in final), stats=stats)

@@ -44,7 +44,7 @@ REASON through ``ops/batching.count_fallback``.
 from __future__ import annotations
 
 import functools
-from typing import List, NamedTuple, Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -58,7 +58,12 @@ from .device_cache import (
 )
 from .family import KernelFamily
 from .interning import Interner
-from .mergetree_kernel import _mix_u32, export_to_numpy, gather_export_rows
+from .mergetree_kernel import (
+    _mix_u32,
+    export_to_numpy,
+    gather_export_rows,
+    program_name,
+)
 from .pipeline import (
     PackCache,
     _copy_interner,
@@ -75,7 +80,7 @@ from .tree_kernel import (
     pack_tree_batch,
     replay_vmapped,
     scatter_tree_doc_rows,
-    summary_from_state,
+    summaries_from_tree_export,
     tree_buckets,
 )
 
@@ -183,13 +188,25 @@ def _tree_export_fn(digest: bool):
     same split contract as the merge-tree export."""
 
     def run(state: TreeState, edits: TreeEdits, n_nodes, n_cont):
-        final = replay_vmapped(state, edits)
-        out = tuple(final)
-        if digest:
-            out = out + (tree_doc_digests(final, n_nodes, n_cont),)
-        return out
+        return tree_fold_export(state, edits, n_nodes, n_cont, digest)
 
+    run.__name__ = run.__qualname__ = program_name("tree", digest)
     return jax.jit(run)
+
+
+def tree_fold_export(state: TreeState, edits: TreeEdits, n_nodes, n_cont,
+                     digest: bool) -> tuple:
+    """The traced body of the tree fold+export programs (single-device
+    and mesh): the final forest planes in ``TreeState`` field order (the
+    export is the planes themselves), then the digest plane under
+    ``digest``."""
+    with jax.named_scope("fold"):
+        final = replay_vmapped(state, edits)
+    out = tuple(final)
+    if digest:
+        with jax.named_scope("digest"):
+            out = out + (tree_doc_digests(final, n_nodes, n_cont),)
+    return out
 
 
 def _tree_aux(meta: dict, digest: bool):
@@ -223,18 +240,6 @@ def _split_tree_digest(export, digested: bool):
     if not digested:
         return export, None
     return export[:-1], export[-1]
-
-
-def summaries_from_tree_export(meta, arr, stats: Optional[dict] = None
-                               ) -> List:
-    """Downloaded final-forest planes → canonical summaries, routing
-    pack-time and overflow fallbacks to the oracle (counted per reason).
-    ``arr`` is the fetched core tuple in ``TreeState`` field order —
-    either a whole chunk's rows or the tier-0 changed-rows gather (the
-    meta is then the sliced sub-meta)."""
-    state_np = dict(zip(TreeState._fields, arr))
-    return [summary_from_state(meta, state_np, d, stats=stats)
-            for d in range(len(meta["docs"]))]
 
 
 def _tree_narrow(chunk, state, edits, meta):
@@ -527,8 +532,8 @@ TREE_FAMILY = KernelFamily(
     chunk_tag=lambda meta: None,
     fetch=export_to_numpy,
     gather_rows=gather_export_rows,
-    extract=lambda meta, arr, st: summaries_from_tree_export(
-        meta, arr, stats=st),
+    extract=lambda meta, arr, st, stage: summaries_from_tree_export(
+        meta, arr, stats=st, stage=stage),
     per_doc_meta=("n_nodes", "n_cont", "t_rows"),
     make_pad=lambda: TreeDocInput(doc_id="\x00pad", ops=[]),
     pad_token=_mt_pad_token,

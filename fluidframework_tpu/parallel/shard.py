@@ -38,8 +38,10 @@ from ..ops.mergetree_kernel import (
     MergeTreeDocInput,
     _export_cold_fn,
     _export_warm_fn,
+    program_name,
 )
 from ..protocol.summary import SummaryTree
+from ..utils.telemetry import span
 
 DOC_AXIS = "docs"
 SLICE_AXIS = "slice"
@@ -184,7 +186,6 @@ def replay_family_sharded(
     from ..ops.batching import partition_replay
     from ..ops.pipeline import (
         _block_until_ready,
-        _bump,
         _count_d2h,
         _count_h2d,
         _nbytes,
@@ -193,7 +194,6 @@ def replay_family_sharded(
         delta_route,
         delta_store_all,
         delta_sub_meta,
-        perf_counter,
         seed_stage,
     )
 
@@ -221,49 +221,46 @@ def replay_family_sharded(
                 and all(d.cache_token is not None for d in batch):
             for k in range(pad_base, len(padded)):
                 padded[k].cache_token = family.pad_token(k)
-        t0 = perf_counter()
-        if pack_cache is not None:
-            state, ops, meta = pack_cache.pack(padded)
-        else:
-            state, ops, meta = family.pack(padded)
-        state_n, ops_n = family.narrow(padded, state, ops, meta)
-        _bump(stage, "pack", t0)
+        with span("pipeline.pack", stage, "pack", chunk=0):
+            if pack_cache is not None:
+                state, ops, meta = pack_cache.pack(padded)
+            else:
+                state, ops, meta = family.pack(padded)
+            state_n, ops_n = family.narrow(padded, state, ops, meta)
         want_digest = delta_cache is not None
 
         # --- upload leg: resident tier or explicit sharded device_put;
         # h2d_bytes counts what really crossed either way.
-        t0 = perf_counter()
-        aux_dev = None
-        if device_cache is not None:
-            state_u, ops_u, aux_dev, up_bytes = device_cache.acquire(
-                state_n, ops_n, meta)
-            if isinstance(jax.tree.leaves(ops_u)[0], np.ndarray):
-                # Bypass route (token-less chunk): shard-place like the
-                # plain path so the step still runs mesh-partitioned.
-                ops_u = _shard_put(mesh, ops_u)
-                state_u = _shard_put(mesh, state_u) \
-                    if state_u is not None else None
-        else:
-            up_bytes = _np_nbytes(state_n) + _np_nbytes(ops_n)
-            ops_u = _shard_put(mesh, ops_n)
-            state_u = _shard_put(mesh, state_n) \
-                if state_n is not None else None
-        if aux_dev is None:
-            aux_host = family.aux(meta, want_digest)
-            up_bytes += _np_nbytes(tuple(jax.tree.leaves(aux_host)))
-            aux_dev = _shard_put(mesh, aux_host)
-        _bump(stage, "upload", t0)
+        with span("pipeline.upload", stage, "upload", chunk=0):
+            aux_dev = None
+            if device_cache is not None:
+                state_u, ops_u, aux_dev, up_bytes = device_cache.acquire(
+                    state_n, ops_n, meta)
+                if isinstance(jax.tree.leaves(ops_u)[0], np.ndarray):
+                    # Bypass route (token-less chunk): shard-place like
+                    # the plain path so the step still runs
+                    # mesh-partitioned.
+                    ops_u = _shard_put(mesh, ops_u)
+                    state_u = _shard_put(mesh, state_u) \
+                        if state_u is not None else None
+            else:
+                up_bytes = _np_nbytes(state_n) + _np_nbytes(ops_n)
+                ops_u = _shard_put(mesh, ops_n)
+                state_u = _shard_put(mesh, state_n) \
+                    if state_n is not None else None
+            if aux_dev is None:
+                aux_host = family.aux(meta, want_digest)
+                up_bytes += _np_nbytes(tuple(jax.tree.leaves(aux_host)))
+                aux_dev = _shard_put(mesh, aux_host)
         _count_h2d(stage, up_bytes)
 
         # --- dispatch + honest device wait.
-        t0 = perf_counter()
-        export = family.dispatch_sharded(mesh, state_u, ops_u, meta,
-                                         want_digest, aux_dev)
-        core, dig = family.split_digest(export, want_digest)
-        _bump(stage, "dispatch", t0)
-        t0 = perf_counter()
-        _block_until_ready(core, dig)
-        _bump(stage, "device_wait", t0)
+        with span("pipeline.dispatch", stage, "dispatch", chunk=0):
+            export = family.dispatch_sharded(mesh, state_u, ops_u, meta,
+                                             want_digest, aux_dev)
+            core, dig = family.split_digest(export, want_digest)
+        with span("pipeline.device_wait", stage, "device_wait", chunk=0):
+            _block_until_ready(core, dig)
         _bump_stats(_docs_per_device(jax.tree.leaves(core)[0],
                                      len(padded), n_real))
 
@@ -286,29 +283,26 @@ def replay_family_sharded(
                 if isinstance(ex_np, tuple) else ex_np[:n_real]
 
         def extract(meta_x, arr, extra=()):
-            t1 = perf_counter()
-            st: dict = {}
-            res = family.extract(meta_x, arr, st)
-            for fn in extra:
-                fn(res)
-            _bump(stage, "extract", t1)
+            with span("pipeline.extract", stage, "extract", chunk=0):
+                st: dict = {}
+                res = family.extract(meta_x, arr, st, stage)
+                for fn in extra:
+                    fn(res)
             _bump_stats(st)
             return res
 
         def fetch_full():
             # d2h_bytes counts the PADDED buffer — that is what crosses
             # the link; pads trim host-side after the transfer.
-            t1 = perf_counter()
-            raw = family.fetch(core)
-            _bump(stage, "download", t1)
+            with span("pipeline.download", stage, "download", chunk=0):
+                raw = family.fetch(core)
             _count_d2h(stage, _nbytes(raw))
             return trim(raw)
 
         if dig is None:
             return extract(meta_real, fetch_full())
-        t0 = perf_counter()
-        dig_full = np.asarray(dig)  # the full padded plane crosses
-        _bump(stage, "download", t0)
+        with span("pipeline.download", stage, "download", chunk=0):
+            dig_full = np.asarray(dig)  # the full padded plane crosses
         _count_d2h(stage, dig_full.nbytes)
         dig_np = dig_full[:n_real]
         # The shared tier-0 decision + entry publication
@@ -327,27 +321,25 @@ def replay_family_sharded(
             delta_cache.note_bytes_saved(_nbytes(core))
             _bump_stats({"delta_docs": len(real_docs)})
             return [served[d] for d in range(len(real_docs))]
-        t0 = perf_counter()
-        sub, fetched = family.gather_rows(
-            core, np.asarray(changed, np.int32))
-        _bump(stage, "download", t0)
+        with span("pipeline.download", stage, "download", chunk=0):
+            sub, fetched = family.gather_rows(
+                core, np.asarray(changed, np.int32))
         _count_d2h(stage, fetched)
         delta_cache.note_bytes_saved(max(0, _nbytes(core) - fetched))
-        t0 = perf_counter()
-        st: dict = {}
-        got = family.extract(
-            delta_sub_meta(meta_real, changed, family.per_doc_meta),
-            sub, st)
-        res = delta_merge_changed(delta_cache, meta_real, dig_np, served,
-                                  changed, got)
+        with span("pipeline.extract", stage, "extract", chunk=0):
+            st: dict = {}
+            got = family.extract(
+                delta_sub_meta(meta_real, changed, family.per_doc_meta),
+                sub, st, stage)
+            res = delta_merge_changed(delta_cache, meta_real, dig_np,
+                                      served, changed, got)
         st["delta_docs"] = st.get("delta_docs", 0) + len(served)
-        _bump(stage, "extract", t0)
         _bump_stats(st)
         return res
 
     return partition_replay(
         docs, family.known_fallback, family.fallback_summary,
-        fold_batch_export, stats=stats,
+        fold_batch_export, stats=stats, stage=stage,
     )
 
 
@@ -573,18 +565,14 @@ def tree_sharded_export_step(mesh: Mesh, digest: bool):
     planes.  The tree family's ``dispatch_sharded`` hook; the fold is
     per-doc elementwise, so no collective is inserted."""
     from ..ops.tree_kernel import TreeEdits, TreeState
-    from ..ops.tree_kernel import replay_vmapped as tree_replay_vmapped
-    from ..ops.tree_pipeline import tree_doc_digests
+    from ..ops.tree_pipeline import tree_fold_export
 
     shard = NamedSharding(mesh, _doc_spec(mesh))
 
     def _step(state: TreeState, edits: TreeEdits, n_nodes, n_cont):
-        final = tree_replay_vmapped(state, edits)
-        out = tuple(final)
-        if digest:
-            out = out + (tree_doc_digests(final, n_nodes, n_cont),)
-        return out
+        return tree_fold_export(state, edits, n_nodes, n_cont, digest)
 
+    _step.__name__ = _step.__qualname__ = program_name("tree", digest)
     n_out = len(TreeState._fields) + (1 if digest else 0)
     return jax.jit(
         _step,

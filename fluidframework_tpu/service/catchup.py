@@ -32,6 +32,7 @@ from ..protocol.summary import SummaryTree, canonical_json
 from ..runtime.container import ContainerRuntime
 from ..runtime.op_pipeline import decode_stream
 from ..runtime.registry import ChannelRegistry, default_registry
+from ..utils.telemetry import span
 from . import gates
 from .orderer import LocalOrderingService
 
@@ -413,7 +414,12 @@ class CatchupService:
             # and hit counting never run twice.
             prefetched = served
         profile_dir = gates.raw(self.mc.config, "Catchup.ProfileDir")
-        with CatchupService._serial:
+        # The stage dict is written only by the lock's holder: this span
+        # adds its wait at exit, once the lock is held.
+        with span("catchup.serial_wait", self.pipeline_stage,
+                  "serial_wait"):
+            CatchupService._serial.acquire()
+        try:
             self._pin_resident = pin_resident
             tracer = (
                 jax_profiler_trace(str(profile_dir))
@@ -438,6 +444,8 @@ class CatchupService:
             if stats is not None:
                 stats.update(deltas)
             return results
+        finally:
+            CatchupService._serial.release()
 
     def _cache_key_at(self, doc_id: str, base_handle: str, ref_seq: int,
                       head_seq: int) -> tuple:
@@ -537,53 +545,57 @@ class CatchupService:
         works: List[_DocWork] = []
         results: Dict[str, Tuple[str, int]] = dict(prefetched or {})
         leading: set = set()
+        stage = self.pipeline_stage
         try:
-            for doc_id in (doc_ids if doc_ids is not None
-                           else self.service.doc_ids()):
-                if results.get(doc_id) is not None:
-                    continue  # served by the pre-lock cache pass
-                summary, ref_seq, handle = \
-                    self.service.storage.latest_with_handle(doc_id)
-                if summary is None:
-                    continue  # never attached: nothing to summarize from
-                tail = self.service.oplog.get(doc_id, from_seq=ref_seq)
-                if not tail:
-                    results[doc_id] = (handle, ref_seq)
-                    continue
-                key = None
-                if self.cache is not None:
-                    key = self._cache_key(doc_id, handle, ref_seq, tail)
-                    status, fold = self.cache.begin(key)
-                    if status == "hit":
-                        results[doc_id] = self._finish_result(
-                            doc_id, fold, tail[-1].seq, upload)
+            with span("catchup.prepare", stage, "prepare"):
+                for doc_id in (doc_ids if doc_ids is not None
+                               else self.service.doc_ids()):
+                    if results.get(doc_id) is not None:
+                        continue  # served by the pre-lock cache pass
+                    summary, ref_seq, handle = \
+                        self.service.storage.latest_with_handle(doc_id)
+                    if summary is None:
+                        continue  # never attached: nothing to summarize
+                    tail = self.service.oplog.get(doc_id, from_seq=ref_seq)
+                    if not tail:
+                        results[doc_id] = (handle, ref_seq)
                         continue
-                    leading.add(key)
-                work = _DocWork(doc_id, summary, ref_seq, tail)
-                work.cache_key = key
-                work.decoded = list(decode_stream(tail))
-                work.plan = self._device_plan(work)
-                works.append(work)
+                    key = None
+                    if self.cache is not None:
+                        key = self._cache_key(doc_id, handle, ref_seq, tail)
+                        status, fold = self.cache.begin(key)
+                        if status == "hit":
+                            results[doc_id] = self._finish_result(
+                                doc_id, fold, tail[-1].seq, upload)
+                            continue
+                        leading.add(key)
+                    work = _DocWork(doc_id, summary, ref_seq, tail)
+                    work.cache_key = key
+                    work.decoded = list(decode_stream(tail))
+                    work.plan = self._device_plan(work)
+                    works.append(work)
 
             trees = partition_replay(
                 works,
                 known_fallback=lambda w: w.plan is None,
                 fallback_fn=self._cpu_fold,
                 batch_fn=self._device_fold,
+                stage=stage,
             )
             from .catchup_cache import CachedFold
 
-            for work, tree in zip(works, trees):
-                if work.cache_key is not None:
-                    # Publish BEFORE the upload so single-flight waiters
-                    # unblock as early as possible; finish() hands back
-                    # the one digest it computed.
-                    fold = self.cache.finish(work.cache_key, tree)
-                    leading.discard(work.cache_key)
-                else:
-                    fold = CachedFold(tree, tree.digest())
-                results[work.doc_id] = self._finish_result(
-                    work.doc_id, fold, work.tail[-1].seq, upload)
+            with span("catchup.publish", stage, "publish"):
+                for work, tree in zip(works, trees):
+                    if work.cache_key is not None:
+                        # Publish BEFORE the upload so single-flight
+                        # waiters unblock as early as possible; finish()
+                        # hands back the one digest it computed.
+                        fold = self.cache.finish(work.cache_key, tree)
+                        leading.discard(work.cache_key)
+                    else:
+                        fold = CachedFold(tree, tree.digest())
+                    results[work.doc_id] = self._finish_result(
+                        work.doc_id, fold, work.tail[-1].seq, upload)
             return results
         finally:
             # A failed fold must never strand single-flight waiters.
@@ -708,15 +720,14 @@ class CatchupService:
                 advance(msg.seq, msg.min_seq)
         return channel.summarize(final_msn)
 
-    def _device_fold(self, works: List[_DocWork]) -> List[SummaryTree]:
-        # holds-lock: _serial
-        """Batch every (doc, channel) pair into its kernel's batch (one
-        device call per kernel type); fold non-kernel channels host-side;
-        reassemble full container summary trees, byte-identical to
-        ``ContainerRuntime.summarize()``."""
-        from ..ops.map_kernel import MapDocInput, replay_map_batch
-        from ..ops.matrix_kernel import MatrixDocInput, replay_matrix_batch
-        from ..ops.tree_kernel import TreeDocInput, replay_tree_batch
+    def _kernel_inputs(self, works: List[_DocWork]):  # holds-lock: _serial
+        """Each (doc, channel) pair of ``works`` as its kernel's input, or
+        folded host-side for a channel type with no kernel: ``(inputs by
+        kernel type, {(work_idx, plan_idx): (type, input index)}, {(work_idx,
+        plan_idx): host-folded channel tree})``."""
+        from ..ops.map_kernel import MapDocInput
+        from ..ops.matrix_kernel import MatrixDocInput
+        from ..ops.tree_kernel import TreeDocInput
 
         # Collect per-kernel inputs; (work_idx, plan_idx) → result slot.
         string_in: List[MergeTreeDocInput] = []
@@ -784,6 +795,21 @@ class CatchupService:
                         attribution=work.attribution,
                         cache_token=channel_token(),
                     ))
+        inputs = {STRING_TYPE: string_in, MAP_TYPE: map_in,
+                  MATRIX_TYPE: matrix_in, TREE_TYPE: tree_in}
+        return inputs, slots, host_trees
+
+    def _device_fold(self, works: List[_DocWork]) -> List[SummaryTree]:
+        # holds-lock: _serial
+        """Batch every (doc, channel) pair into its kernel's batch (one
+        device call per kernel type); fold non-kernel channels host-side;
+        reassemble full container summary trees, byte-identical to
+        ``ContainerRuntime.summarize()``."""
+        from ..ops.map_kernel import replay_map_batch
+        from ..ops.matrix_kernel import replay_matrix_batch
+
+        with span("catchup.prepare", self.pipeline_stage, "prepare"):
+            inputs, slots, host_trees = self._kernel_inputs(works)
         mesh = self._resolve_mesh()
         if mesh is not None:
             # Mesh-sharded service fold: the same byte-identical
@@ -870,10 +896,8 @@ class CatchupService:
             }
         fb_before = self.pipeline_stats.get("fallback_docs", 0)
         results = {
-            STRING_TYPE: replay[STRING_TYPE](string_in),
-            MAP_TYPE: replay[MAP_TYPE](map_in) if map_in else [],
-            MATRIX_TYPE: replay[MATRIX_TYPE](matrix_in) if matrix_in else [],
-            TREE_TYPE: replay[TREE_TYPE](tree_in) if tree_in else [],
+            kind: replay[kind](docs) if docs or kind == STRING_TYPE else []
+            for kind, docs in inputs.items()
         }
         # Kernel channels that fell back to their oracle (pre-pack
         # routing + post-fold overflow alike bump fallback_docs at the
@@ -882,60 +906,61 @@ class CatchupService:
             self.pipeline_stats.get("fallback_docs", 0) - fb_before)
 
         out: List[SummaryTree] = []
-        for wi, work in enumerate(works):
-            final_seq = work.tail[-1].seq
-            final_msn = max(m.min_seq for m in work.tail)
-            tree = SummaryTree()
-            tree.add_blob(
-                ".metadata",
-                canonical_json(
-                    ContainerRuntime.container_metadata(
-                        final_seq, final_msn,
-                        attribution=work.attribution,
-                    )
-                ),
-            )
-            tree.add_blob(
-                ".protocol", canonical_json(self._fold_protocol(work))
-            )
-            tree.add_blob(
-                ".idCompressor",
-                canonical_json(self._fold_id_compressor(work)),
-            )
-            if work.attribution:
+        with span("catchup.assemble", self.pipeline_stage, "assemble"):
+            for wi, work in enumerate(works):
+                final_seq = work.tail[-1].seq
+                final_msn = max(m.min_seq for m in work.tail)
+                tree = SummaryTree()
                 tree.add_blob(
-                    ".attribution",
-                    canonical_json(self._fold_attribution(work)),
+                    ".metadata",
+                    canonical_json(
+                        ContainerRuntime.container_metadata(
+                            final_seq, final_msn,
+                            attribution=work.attribution,
+                        )
+                    ),
                 )
-            # Eligibility guaranteed nothing becomes unreferenced and no
-            # blobs exist: the folded gc/blob state is the empty state.
-            from ..runtime.gc import GarbageCollector
+                tree.add_blob(
+                    ".protocol", canonical_json(self._fold_protocol(work))
+                )
+                tree.add_blob(
+                    ".idCompressor",
+                    canonical_json(self._fold_id_compressor(work)),
+                )
+                if work.attribution:
+                    tree.add_blob(
+                        ".attribution",
+                        canonical_json(self._fold_attribution(work)),
+                    )
+                # Eligibility guaranteed nothing becomes unreferenced and no
+                # blobs exist: the folded gc/blob state is the empty state.
+                from ..runtime.gc import GarbageCollector
 
-            tree.add_blob(".gc",
-                          canonical_json(GarbageCollector.empty_state()))
-            tree.add_tree(".blobs")
-            ds_tree = tree.add_tree(".datastores")
-            by_ds: Dict[str, List[Tuple[str, str, int]]] = {}
-            for pi, (ds_id, channel_id, type_name, _base) in \
-                    enumerate(work.plan):
-                by_ds.setdefault(ds_id, []).append(
-                    (channel_id, type_name, pi)
-                )
-            for ds_id in sorted(by_ds):
-                sub = SummaryTree()
-                channel_types = {}
-                for channel_id, type_name, pi in sorted(by_ds[ds_id]):
-                    if (wi, pi) in host_trees:
-                        sub.children[channel_id] = host_trees[wi, pi]
-                    else:
-                        kind, idx = slots[wi, pi]
-                        sub.children[channel_id] = results[kind][idx]
-                    channel_types[channel_id] = type_name
-                sub.add_blob(".attributes", canonical_json(
-                    {"channels": channel_types, "rooted": True}
-                ))
-                ds_tree.children[ds_id] = sub
-            out.append(tree)
+                tree.add_blob(".gc",
+                              canonical_json(GarbageCollector.empty_state()))
+                tree.add_tree(".blobs")
+                ds_tree = tree.add_tree(".datastores")
+                by_ds: Dict[str, List[Tuple[str, str, int]]] = {}
+                for pi, (ds_id, channel_id, type_name, _base) in \
+                        enumerate(work.plan):
+                    by_ds.setdefault(ds_id, []).append(
+                        (channel_id, type_name, pi)
+                    )
+                for ds_id in sorted(by_ds):
+                    sub = SummaryTree()
+                    channel_types = {}
+                    for channel_id, type_name, pi in sorted(by_ds[ds_id]):
+                        if (wi, pi) in host_trees:
+                            sub.children[channel_id] = host_trees[wi, pi]
+                        else:
+                            kind, idx = slots[wi, pi]
+                            sub.children[channel_id] = results[kind][idx]
+                        channel_types[channel_id] = type_name
+                    sub.add_blob(".attributes", canonical_json(
+                        {"channels": channel_types, "rooted": True}
+                    ))
+                    ds_tree.children[ds_id] = sub
+                out.append(tree)
         return out
 
     def _fold_attribution(self, work: _DocWork) -> dict:

@@ -1477,10 +1477,10 @@ class FrontDoor:
         # snapshots its catchup admission counters locally, but an
         # operator watching a storm needs the TIER's overload picture in
         # one place — sum every live shard's admission counters here.
-        admission: Dict[str, int] = {}
+        admission: Dict[str, float] = {}
         for per_shard in shards.values():
             for key, value in (per_shard.get("admission") or {}).items():
-                admission[key] = admission.get(key, 0) + int(value)
+                admission[key] = admission.get(key, 0) + value
         return {
             "shards": shards,
             "alive": self.router.alive(),

@@ -31,6 +31,7 @@ Run standalone (the Tinylicious shape):
 from __future__ import annotations
 
 import asyncio
+import itertools
 import json
 import threading
 import time
@@ -42,6 +43,7 @@ from ..protocol.summary import tree_from_obj, tree_to_obj
 from ..protocol.wire import (LEN as _LEN, MAX_FRAME, WIRE_VERSION,
                              decode_raw_operation,
                              encode_sequenced_message, frame_bytes)
+from ..utils.telemetry import span
 from . import gates
 from .broadcaster import Broadcaster
 from .orderer import LocalOrderingService
@@ -372,6 +374,12 @@ class _ClientSession:
         self.connected_clients.clear()
 
 
+class _RequestLocal(threading.local):
+    """Per-thread request context of :class:`OrderingServer`."""
+
+    rid: Optional[int] = None
+
+
 class OrderingServer:
     """Asyncio TCP server exposing a LocalOrderingService to the network."""
 
@@ -475,11 +483,22 @@ class OrderingServer:
         #: the overload surface: ``catchup.requests`` counts fold-lane
         #: entries and balances exactly — requests = admitted + shed +
         #: degraded; ``catchup.warm`` counts priority-lane serves that
-        #: never entered the fold lane at all.
+        #: never entered the fold lane at all.  The ``_s`` counters are
+        #: float seconds: ``catchup.queued_s`` from frame decode to an
+        #: executor thread taking the request, ``catchup.serve_s`` in
+        #: admitted folds (the ``catchup.serve`` span), and
+        #: ``catchup.retry_after_s`` the pacing handed out on sheds.
         self.admission = LockedCounterSet(
             "catchup.requests", "catchup.admitted", "catchup.shed",
             "catchup.degraded", "catchup.degraded_docs", "catchup.warm",
-            "catchup.stream")
+            "catchup.stream", "catchup.queued_s", "catchup.serve_s",
+            "catchup.retry_after_s")
+        #: server sequence numbers (``rid``) given to requests at frame
+        #: decode; every server span of a request carries its rid.
+        self._rids = itertools.count(1)
+        #: the rid of the request a thread is running (None between
+        #: requests); the catch-up spans read it.
+        self._request = _RequestLocal()
         #: streaming fold (ISSUE 16): when the ``Catchup.Stream`` gate is
         #: on, a sequencer-attached :class:`~.streamfold.StreamFoldService`
         #: folds committed micro-batches continuously (pinned device
@@ -751,14 +770,23 @@ class OrderingServer:
         }
 
     def _track_dispatch(self, session: _ClientSession, method: str,
-                        params: dict):
+                        params: dict, rid: Optional[int] = None,
+                        decoded_at: Optional[float] = None):
         """Executor-side dispatch wrapper: counts in-flight offloaded
-        work so the drain sequence can wait it out before sealing."""
+        work so the drain sequence can wait it out before sealing, and
+        adds a catch-up's wait for this thread (from ``decoded_at``, a
+        ``perf_counter`` reading at frame decode) to ``catchup.queued_s``
+        — a counter only: a profiler span cannot cross threads."""
+        if method == "catchup" and decoded_at is not None:
+            self.admission.bump("catchup.queued_s",
+                                time.perf_counter() - decoded_at)
         with self._inflight_lock:
             self._inflight += 1
+        self._request.rid = rid
         try:
             return self._dispatch(session, method, params)
         finally:
+            self._request.rid = None
             with self._inflight_lock:
                 self._inflight -= 1
 
@@ -861,6 +889,14 @@ class OrderingServer:
         ``catchup.requests == admitted + shed + degraded``, with
         ``catchup.warm`` counting lane-1 serves outside that balance.
         """
+        if self._request.rid is None:
+            # Called in-process, not through a frame: the request takes
+            # its rid here, for the length of the call.
+            self._request.rid = next(self._rids)
+            try:
+                return self._catchup_entry(session, params)
+            finally:
+                self._request.rid = None
         # Wire-clock admission (ISSUE 18): a deterministic out-of-proc
         # caller stamps its virtual tick onto the request; in virtual
         # mode the controller advances ONLY on these, so every verdict
@@ -868,6 +904,7 @@ class OrderingServer:
         vnow = params.get("vnow")
         if vnow is not None and self.admission_control.virtual:
             self.admission_control.observe(float(vnow))
+        rid = self._request.rid
         catchup = self._ensure_catchup()
         # Epoch-keyed invalidation (EpochTracker parity for the SERVER's
         # own fold caches): entries are keyed by the storage generation
@@ -900,7 +937,13 @@ class OrderingServer:
                 self._zero_fold_stats(), lane=lane,
                 stream=stream_docs)
         self.admission.bump("catchup.requests")
-        verdict, grant = self.admission_control.admit()
+        with span("catchup.admit", rid=rid) as admit:
+            verdict, grant = self.admission_control.admit()
+            if admit.recording:
+                control = self.admission_control.snapshot()
+                admit.set(verdict=verdict, inflight=control["inflight"],
+                          shed_streak=control["shed_streak"],
+                          retry_after=0.0 if verdict == "admit" else grant)
         if verdict != "admit":
             if verdict == "degrade" and self.degraded_serve:
                 degraded = self._degraded_serve(session, catchup, prefix,
@@ -909,6 +952,7 @@ class OrderingServer:
                     self.admission.bump("catchup.degraded")
                     return degraded
             self.admission.bump("catchup.shed")
+            self.admission.bump("catchup.retry_after_s", float(grant))
             raise NackError(
                 "catch-up tier overloaded; backfill from deltas "
                 "or retry", retry_after=float(grant), code="overloaded",
@@ -917,10 +961,12 @@ class OrderingServer:
         try:
             # The warm pre-pass's partial serves ride along so the fold
             # never re-scans (or re-counts hits for) those documents.
-            return self._catchup_rpc(session, params, catchup=catchup,
-                                     doc_ids=doc_ids, prefix=prefix,
-                                     prefetched=served,
-                                     stream=stream_docs)
+            with span("catchup.serve", self.admission, "catchup.serve_s",
+                      rid=rid):
+                return self._catchup_rpc(session, params, catchup=catchup,
+                                         doc_ids=doc_ids, prefix=prefix,
+                                         prefetched=served,
+                                         stream=stream_docs)
         finally:
             self.admission_control.release(
                 grant, hold=self.catchup_hold_seconds)
@@ -1019,61 +1065,62 @@ class OrderingServer:
                           prefix: str, doc_ids, results: dict,
                           stats: dict, lane: str, degraded=(),
                           stream=()):
-        """ONE response shape for every catchup lane."""
-        service = self.service
-        out = {}
-        for doc_id, (handle, seq) in results.items():
-            self._grant_tree(service.storage.read(handle),
-                             session.tenant)
-            out[doc_id[len(prefix):]] = [handle, seq]
-        return {
-            "docs": out,
-            # Explicitly-requested documents the fold could not serve
-            # (unknown id, or nothing to fold from): callers must be
-            # able to tell success from a typo.
-            "skipped": sorted(
-                d[len(prefix):] for d in doc_ids if d not in results
-            ),
-            # Which lane answered ("warm" | "fold" | "degraded") and —
-            # for degraded serves — which documents were answered at a
-            # ref_seq older than the durable head (the client's cue
-            # that a tail replay is coming via gap repair).
-            "lane": lane,
-            "degraded": sorted(d[len(prefix):] for d in degraded),
-            # Documents answered from the STREAMING HEAD: a summary at
-            # most one fold cadence behind the durable head, served at
-            # its ref_seq with the client replaying the bounded tail.
-            "stream": sorted(d[len(prefix):] for d in stream),
-            "deviceDocs": stats.get("deviceDocs", 0),
-            "cpuDocs": stats.get("cpuDocs", 0),
-            # Platform of the devices this service's device folds run on
-            # ("tpu", "cpu"; None before its first device fold) — a fold
-            # pinned to the CPU says so here instead of hiding behind
-            # deviceDocs.
-            "platform": catchup.fold_platform,
-            # Per-channel split inside device-routed documents:
-            # non-kernel channels folded host-side vs kernel channels
-            # that FELL BACK to their oracle (ISSUE 14 satellite — the
-            # two were indistinguishable before).
-            "hostChannels": stats.get("hostChannels", 0),
-            "fallbackChannels": stats.get("fallbackChannels", 0),
-            # Cumulative fold-cache health (hits/misses/evictions/
-            # waits + bytes) — operators watching a herd of loading
-            # clients see the single-flight amortization here.
-            "cache": (catchup.cache.stats()
-                      if catchup.cache is not None else None),
-            # Tier-0 delta-download health: documents whose rows
-            # never crossed the d2h link + the bytes that saved.
-            "deltaCache": (catchup.delta_cache.stats()
-                           if catchup.delta_cache is not None
-                           else None),
-            # Tier-2.5 resident-upload health: chunks dispatched with
-            # zero h2d pack bytes (served), donated suffix splices
-            # (spliced), and the upload bytes the tier kept off the link.
-            "deviceCache": (catchup.device_cache.stats()
-                            if catchup.device_cache is not None
-                            else None),
-        }
+        """ONE response shape for every catchup lane (the
+        ``catchup.respond`` span: handle grants and the answer)."""
+        with span("catchup.respond", rid=self._request.rid, lane=lane):
+            service = self.service
+            out = {}
+            for doc_id, (handle, seq) in results.items():
+                self._grant_tree(service.storage.read(handle),
+                                 session.tenant)
+                out[doc_id[len(prefix):]] = [handle, seq]
+            return {
+                "docs": out,
+                # Explicitly-requested documents the fold could not serve
+                # (unknown id, or nothing to fold from): callers must be
+                # able to tell success from a typo.
+                "skipped": sorted(
+                    d[len(prefix):] for d in doc_ids if d not in results
+                ),
+                # Which lane answered ("warm" | "fold" | "degraded") and —
+                # for degraded serves — which documents were answered at a
+                # ref_seq older than the durable head (the client's cue
+                # that a tail replay is coming via gap repair).
+                "lane": lane,
+                "degraded": sorted(d[len(prefix):] for d in degraded),
+                # Documents answered from the STREAMING HEAD: a summary at
+                # most one fold cadence behind the durable head, served at
+                # its ref_seq with the client replaying the bounded tail.
+                "stream": sorted(d[len(prefix):] for d in stream),
+                "deviceDocs": stats.get("deviceDocs", 0),
+                "cpuDocs": stats.get("cpuDocs", 0),
+                # Platform of the devices this service's device folds run on
+                # ("tpu", "cpu"; None before its first device fold) — a fold
+                # pinned to the CPU says so here instead of hiding behind
+                # deviceDocs.
+                "platform": catchup.fold_platform,
+                # Per-channel split inside device-routed documents:
+                # non-kernel channels folded host-side vs kernel channels
+                # that FELL BACK to their oracle, counted apart.
+                "hostChannels": stats.get("hostChannels", 0),
+                "fallbackChannels": stats.get("fallbackChannels", 0),
+                # Cumulative fold-cache health (hits/misses/evictions/
+                # waits + bytes) — operators watching a herd of loading
+                # clients see the single-flight amortization here.
+                "cache": (catchup.cache.stats()
+                          if catchup.cache is not None else None),
+                # Tier-0 delta-download health: documents whose rows
+                # never crossed the d2h link + the bytes that saved.
+                "deltaCache": (catchup.delta_cache.stats()
+                               if catchup.delta_cache is not None
+                               else None),
+                # Tier-2.5 resident-upload health: chunks dispatched with
+                # zero h2d pack bytes (served), donated suffix splices
+                # (spliced), and the upload bytes the tier kept off the link.
+                "deviceCache": (catchup.device_cache.stats()
+                                if catchup.device_cache is not None
+                                else None),
+            }
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
@@ -1092,6 +1139,7 @@ class OrderingServer:
                     try:
                         method = frame.get("method")
                         params = frame.get("params", {})
+                        rid = next(self._rids)
                         if method in self.offloaded_methods:
                             # Device folds take seconds and storage
                             # mutations hold the commit-chain lock across
@@ -1102,7 +1150,8 @@ class OrderingServer:
                             result = await asyncio.get_running_loop() \
                                 .run_in_executor(
                                     None, self._track_dispatch, session,
-                                    method, params,
+                                    method, params, rid,
+                                    time.perf_counter(),
                                 )
                         else:
                             result = self._dispatch(session, method, params)

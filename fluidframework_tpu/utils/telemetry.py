@@ -102,6 +102,77 @@ class PerformanceEvent:
         })
 
 
+#: serializes :class:`span`'s read-modify-write of a dict aggregate: one
+#: fold's pack and extract worker threads add to the same stage keys.
+_SPAN_ACC_LOCK = threading.Lock()
+
+
+class span:
+    """A timed section of the served path: ``with span(name, acc, key,
+    **args):``.
+
+    On exit its ``perf_counter`` duration is added to ``acc[key]`` (a
+    dict, or a :class:`CounterSet` bumped under its own lock) when
+    ``acc`` is given — the in-memory aggregate that metrics read.  When
+    ``jax`` is already imported it also enters
+    ``jax.profiler.TraceAnnotation(name, **args)``, so under a profiler
+    capture the section lands on the host plane on the same clock as the
+    device's ops; it never imports ``jax`` itself, so client-only
+    processes stay free of it.  With no capture running the cost is one
+    ``perf_counter`` pair and the annotation's enabled check.
+
+    Spans are per request, per chunk or per call, never per document or
+    per op: a loop over documents gets one span and a counter.  A span
+    records no parent; containment in time is the link.  Folds are
+    serialized (``CatchupService._serial``), so every ``pipeline.*`` and
+    ``catchup.*`` span lies inside exactly one ``catchup.serve`` of the
+    request that holds the fold, and the server spans of a request share
+    its ``rid`` arg."""
+
+    __slots__ = ("name", "acc", "key", "args", "_trace", "_t0")
+
+    def __init__(self, name: str, acc=None, key: Optional[str] = None,
+                 **args) -> None:
+        self.name = name
+        self.acc = acc
+        self.key = name if key is None else key
+        self.args = args
+        self._trace = None
+        self._t0 = 0.0
+
+    def __enter__(self) -> "span":
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None:
+            self._trace = profiler.TraceAnnotation(self.name, **self.args)
+            self._trace.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    @property
+    def recording(self) -> bool:
+        """Whether a profiler capture is recording this span (only then
+        are :meth:`set`'s args worth computing)."""
+        return self._trace is not None and self._trace.is_enabled()
+
+    def set(self, **args) -> None:
+        """Args known only once the section ran (an admission verdict),
+        attached to the profiler event."""
+        if self._trace is not None:
+            self._trace.set_metadata(**args)
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.perf_counter() - self._t0
+        if isinstance(self.acc, CounterSet):
+            self.acc.bump(self.key, dt)
+        elif self.acc is not None:
+            with _SPAN_ACC_LOCK:
+                self.acc[self.key] = self.acc.get(self.key, 0.0) + dt
+        if self._trace is not None:
+            self._trace.__exit__(*exc)
+            self._trace = None
+        return False
+
+
 class CounterSet:
     """Named monotonic counters for steady-state subsystems (caches,
     retry loops): cheap bumps on the hot path, one dict snapshot for

@@ -301,6 +301,31 @@ def test_degraded_serve_gate_off_sheds_instead():
     assert snap["catchup.shed"] == 1
 
 
+def test_retry_after_counter_sums_the_pacing_handed_out():
+    """``catchup.retry_after_s`` is the sum of the retryAfter values on
+    the nacks callers received — the hold the pacing put on them —
+    while the counter balance stays exact."""
+    service, _loader = _service_with_doc(sets=2)
+    server = OrderingServer(
+        service, catchup_max_inflight=1, clock=VirtualClock(),
+        mc=_mc(**{"Catchup.DegradedServe": "off"}))
+    server.admission_control.admit()  # the one slot, never freed
+    held = []
+    for _ in range(5):
+        with pytest.raises(NackError) as exc_info:
+            server._dispatch(_Session(), "catchup", {"docs": ["doc"]})
+        assert exc_info.value.code == "overloaded"
+        held.append(exc_info.value.retry_after)
+    assert held == sorted(held) and held[-1] > held[0]  # the streak paces
+    snap = server.admission.snapshot()
+    assert snap["catchup.retry_after_s"] == pytest.approx(sum(held))
+    assert snap["catchup.shed"] == 5
+    assert snap["catchup.requests"] == (
+        snap["catchup.admitted"] + snap["catchup.shed"]
+        + snap["catchup.degraded"])
+    assert snap["catchup.serve_s"] == 0  # no fold was served
+
+
 def test_drain_retry_after_is_gate_configurable():
     server = OrderingServer(LocalOrderingService(),
                             mc=_mc(**{"Server.DrainRetryAfter": 2.5}))

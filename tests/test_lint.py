@@ -240,27 +240,40 @@ def test_fluidshape_modules_lint_clean_with_zero_suppressions():
 def test_counter_names_asserted_in_tests_are_produced():
     """ISSUE 17 satellite: counter-name drift.  Every namespaced counter
     literal a test references (catchup.*, fd.*, retry.*, swarm.*) must
-    appear as a ``.bump()`` literal in the package — a renamed producer
-    otherwise turns the assertion into a vacuous ``.get()`` default and
-    the regression goes green."""
+    appear as a ``.bump()`` literal — or as the key of a
+    ``span(name, acc, key)``, whose seconds are added to that counter —
+    in the package: a renamed producer otherwise turns the assertion into
+    a vacuous ``.get()`` default and the regression goes green.  A span's
+    NAME is no counter: a test may name it only where it reads a trace,
+    never as a ``.get()`` argument or a subscript."""
     import ast
     import re
 
-    produced = set()
+    def literal(arg):
+        return arg.value if isinstance(arg, ast.Constant) \
+            and isinstance(arg.value, str) else None
+
+    produced, span_names = set(), set()
     for path in (ROOT / "fluidframework_tpu").rglob("*.py"):
         tree = ast.parse(path.read_text(encoding="utf-8"))
         for node in ast.walk(tree):
+            if not isinstance(node, ast.Call):
+                continue
             # direct counter bumps plus one-level bump-forwarding
             # helpers (the storm driver's `self._bump("swarm.storm_x")`
             # routes its literal to counters.bump)
-            if (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
+            if (isinstance(node.func, ast.Attribute)
                     and node.func.attr.endswith("bump") and node.args
-                    and isinstance(node.args[0], ast.Constant)
-                    and isinstance(node.args[0].value, str)):
-                produced.add(node.args[0].value)
+                    and literal(node.args[0]) is not None):
+                produced.add(literal(node.args[0]))
+            if isinstance(node.func, ast.Name) and node.func.id == "span":
+                if node.args and literal(node.args[0]):
+                    span_names.add(literal(node.args[0]))
+                if len(node.args) > 2 and literal(node.args[2]):
+                    produced.add(literal(node.args[2]))
     namespaces = {n.split(".", 1)[0] for n in produced if "." in n}
     assert namespaces, "no namespaced counters produced — check .bump() scan"
+    assert "catchup.serve_s" in produced and "catchup.serve" in span_names
     # fault sites share the dotted-lowercase shape ('catchup.slow'); they
     # are owned by the seam registry, not the counter producers
     from fluidframework_tpu.testing import faults
@@ -269,13 +282,21 @@ def test_counter_names_asserted_in_tests_are_produced():
     drifted = {}
     for path in sorted((ROOT / "tests").glob("*.py")):
         tree = ast.parse(path.read_text(encoding="utf-8"))
+        reads = set()  # id()s of literals read as counters
         for node in ast.walk(tree):
-            if not (isinstance(node, ast.Constant)
-                    and isinstance(node.value, str)):
+            if isinstance(node, ast.Subscript):
+                reads.add(id(node.slice))
+            elif (isinstance(node, ast.Call)
+                  and isinstance(node.func, ast.Attribute)
+                  and node.func.attr == "get" and node.args):
+                reads.add(id(node.args[0]))
+        for node in ast.walk(tree):
+            lit = literal(node)
+            if lit is None:
                 continue
-            lit = node.value
             if (shape.match(lit) and lit.split(".", 1)[0] in namespaces
-                    and lit not in sites and lit not in produced):
+                    and lit not in sites and lit not in produced
+                    and (lit not in span_names or id(node) in reads)):
                 drifted.setdefault(lit, []).append(
                     f"{path.name}:{node.lineno}")
     assert not drifted, (

@@ -2955,7 +2955,8 @@ def _write_family_tree(root, pipeline_body, shard_body):
 def test_kern_family_positive(tmp_path):
     _write_family_tree(tmp_path, """
         from .family import KernelFamily
-        STAGE_KEYS = ("pack", "upload", "dispatch", "download", "extract")
+        STAGE_KEYS = ("pack", "upload", "dispatch", "device_wait",
+                      "download", "extract")
         def seed_stage(stage):
             return stage
         FAM = KernelFamily(
@@ -2981,7 +2982,7 @@ def test_kern_family_negative(tmp_path):
     _write_family_tree(tmp_path, """
         from .family import KernelFamily
         STAGE_KEYS = ("pack", "upload", "dispatch", "device_wait",
-                      "download", "extract")
+                      "download", "extract", "fallback")
         def seed_stage(stage):
             return stage
         FAM = KernelFamily(

@@ -814,7 +814,7 @@ _FAMILY_PATH = "fluidframework_tpu/ops/family.py"
 _PIPELINE_PATH = "fluidframework_tpu/ops/pipeline.py"
 _MESH_PATH = "fluidframework_tpu/parallel/shard.py"
 _CANON_STAGES = ("pack", "upload", "dispatch", "device_wait", "download",
-                 "extract")
+                 "extract", "fallback")
 _MESH_HOOKS = ("make_pad", "pad_token", "dispatch_sharded")
 
 
