@@ -9,7 +9,9 @@ kept in sequence order as a struct-of-int32-arrays; each sequenced op is one
 1. masked visible lengths for the op's view (ref_seq, client) — the
    "partial lengths" of the reference, recomputed as a masked prefix sum;
 2. up to two *splits* (range/position boundaries falling inside segments),
-   each a shift-by-one gather over the pool;
+   each a shift of the pool right by one slot past the split point — a
+   static one-slot roll and a select, elementwise (a per-document
+   ``take`` compiles to a general batched gather on the TPU);
 3. the op body as masked updates: insert = shift + write at the tie-break
    index (first slot whose exclusive prefix ≥ pos — catch-up has no pending
    segments, so the SEMANTICS.md tie-break degenerates to exactly this);
@@ -123,6 +125,23 @@ def _excl_cumsum(v: jnp.ndarray) -> jnp.ndarray:
     return jnp.cumsum(v) - v
 
 
+def _shift_right(f: jnp.ndarray, keep: jnp.ndarray) -> jnp.ndarray:
+    """The pool moved right by one slot past a prefix: slot s keeps f[s]
+    where ``keep`` (a prefix mask over the slot axis, true at slot 0)
+    holds and reads f[s - 1] after it, so the roll's wrapped element is
+    never read.  Equal to ``jnp.take(f, where(keep, slot, slot - 1))``,
+    with no gather; ``f`` is a [S] plane or the [S, K] props plane."""
+    if f.ndim > 1:
+        keep = keep[:, None]
+    return jnp.where(keep, f, jnp.roll(f, 1, axis=0))
+
+
+def _pick(f: jnp.ndarray, at: jnp.ndarray) -> jnp.ndarray:
+    """``f[i]`` for the one slot i where ``at`` holds (0 where none does),
+    as a masked sum: no dynamic index, so no gather."""
+    return jnp.sum(jnp.where(at, f, 0), dtype=f.dtype)
+
+
 def _split_at(state: MTState, char_pos, ref_seq, client, enable,
               has_ob: bool = True, has_ov: bool = True,
               has_props: bool = True) -> MTState:
@@ -141,15 +160,15 @@ def _split_at(state: MTState, char_pos, ref_seq, client, enable,
     inside = (cum < char_pos) & (char_pos < cum + v)
     do = enable & inside.any()
     idx = jnp.argmax(inside)  # unique when present
-    off = char_pos - cum[idx]
     slot = jnp.arange(S)
-    src = jnp.where(slot <= idx, slot, slot - 1)
+    is_left = slot == idx
+    off = char_pos - _pick(cum, is_left)
+    keep = slot <= idx
 
     def shift(f):
-        return jnp.take(f, src, axis=0)
+        return _shift_right(f, keep)
 
     tstart, tlen = shift(state.tstart), shift(state.tlen)
-    is_left = slot == idx
     is_right = slot == idx + 1
     new_tlen = jnp.where(is_left, off, jnp.where(is_right, tlen - off, tlen))
     new_tstart = jnp.where(is_right, tstart + off, tstart)
@@ -229,7 +248,6 @@ def _apply_op(state: MTState, op, sequential: bool = False,
     # no pending segments; stop before the first sequenced segment).
     can = (cum >= op.a) & active
     j = jnp.where(can.any(), jnp.argmax(can), state.n)
-    src = jnp.where(slot <= j, slot, slot - 1)
 
     if sequential or not has_ob:
         # No stamp can exceed a sequential op's ref (and without
@@ -249,7 +267,7 @@ def _apply_op(state: MTState, op, sequential: bool = False,
         right_idx = jnp.min(jnp.where(present & (slot >= j), slot, S))
 
         def stamp_at(f, idx, valid):
-            return jnp.where(valid, f[jnp.clip(idx, 0, S - 1)],
+            return jnp.where(valid, _pick(f, slot == idx),
                              jnp.int32(NOT_REMOVED))
 
         has_left = left_idx >= 0
@@ -272,8 +290,10 @@ def _apply_op(state: MTState, op, sequential: bool = False,
         kill_client = jnp.where(k1s <= k2s, k1c, k2c)
         killed = kill_seq != NOT_REMOVED
 
+    keep = slot <= j
+
     def shifted(f, newval):
-        moved = jnp.take(f, src, axis=0)
+        moved = _shift_right(f, keep)
         if f.ndim == 1:
             return jnp.where(slot == j, newval, moved)
         return jnp.where((slot == j)[:, None], newval, moved)
@@ -288,7 +308,7 @@ def _apply_op(state: MTState, op, sequential: bool = False,
         rem_client=shifted(state.rem_client,
                            jnp.where(killed, kill_client, -1)),
         # Constant planes are shift-invariant (new slots get the same
-        # constant): skip their gathers under the facts.
+        # constant): skip their shifts under the facts.
         rem2_seq=shifted(state.rem2_seq, NOT_REMOVED) if has_ov
         else state.rem2_seq,
         rem2_client=shifted(state.rem2_client, -1) if has_ov
@@ -304,7 +324,7 @@ def _apply_op(state: MTState, op, sequential: bool = False,
         ob2_client=shifted(state.ob2_client, -1) if has_ob
         else state.ob2_client,
         # A constant PROP_ABSENT plane is shift-invariant: skip the
-        # gather+where entirely on props-free chunks.
+        # shift+where entirely on props-free chunks.
         props=shifted(
             state.props,
             jnp.where(op.pvals == PROP_NOT_TOUCHED, PROP_ABSENT, op.pvals),

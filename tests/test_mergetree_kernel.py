@@ -486,3 +486,191 @@ def test_ob_stamp_author_involvement_in_lagged_view():
     assert summary.digest() == oracle.summarize().digest(), (
         "stamp-author involvement: kernel != oracle"
     )
+
+
+# --- The scan step's one-slot shift: a roll and a select, equal to the
+# per-document take it replaced (which the TPU compiles to a general
+# batched gather).
+
+SHIFT_S, SHIFT_N, SHIFT_K = 16, 11, 3
+
+
+def _take_shift(f, keep):
+    """The former form: ``take`` along the slot axis from slot - 1 past
+    the prefix ``keep``."""
+    import jax.numpy as jnp
+
+    slot = jnp.arange(f.shape[0])
+    return jnp.take(f, jnp.where(keep, slot, slot - 1), axis=0)
+
+
+def _index_pick(f, at):
+    """The former form of a one-slot pick: a dynamic index."""
+    import jax.numpy as jnp
+
+    return f[jnp.argmax(at)]
+
+
+@pytest.mark.parametrize("idx", [0, SHIFT_N // 2, SHIFT_N - 1, SHIFT_S - 1],
+                         ids=["first", "middle", "last-live", "last-slot"])
+@pytest.mark.parametrize("plane", ["slots", "props"])
+def test_shift_right_equals_the_take_shift(plane, idx):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fluidframework_tpu.ops.mergetree_kernel import (
+        NOT_REMOVED,
+        _pick,
+        _shift_right,
+    )
+
+    rng = np.random.default_rng(idx)
+    shape = (SHIFT_S,) if plane == "slots" else (SHIFT_S, SHIFT_K)
+    f = rng.integers(-2, 1 << 20, size=shape).astype(np.int32)
+    f[rng.random(shape) < 0.2] = NOT_REMOVED
+    slot = jnp.arange(SHIFT_S)
+    keep = slot <= idx
+    np.testing.assert_array_equal(_shift_right(jnp.asarray(f), keep),
+                                  _take_shift(jnp.asarray(f), keep))
+    if plane == "slots":
+        assert int(_pick(jnp.asarray(f), slot == idx)) == int(f[idx])
+    # Under vmap each document has its own split point, as in the fold.
+    idxs = jnp.asarray([0, idx, SHIFT_N - 1, SHIFT_S - 1])
+    batch = jnp.asarray(np.stack([np.roll(f, d, axis=0) for d in range(4)]))
+    keeps = slot[None, :] <= idxs[:, None]
+    np.testing.assert_array_equal(jax.vmap(_shift_right)(batch, keeps),
+                                  jax.vmap(_take_shift)(batch, keeps))
+
+
+def _string_tail_docs(seed, n_docs, n_ops=96):
+    """The benchmark's concurrent SharedString tails (three clients,
+    lagged views, 30% of documents annotating) as kernel inputs."""
+    from benchmark.corpus import generator
+    from fluidframework_tpu.protocol.messages import (
+        MessageType,
+        SequencedMessage,
+    )
+
+    make = generator("string_tail")
+    docs = []
+    for i in range(n_docs):
+        tail = make(seed, i, n_ops)
+        ops = [SequencedMessage(seq=seq, client_id=client, client_seq=seq,
+                                ref_seq=ref, min_seq=msn,
+                                type=MessageType.OP, contents=contents)
+               for seq, client, ref, msn, contents in tail]
+        docs.append(MergeTreeDocInput(doc_id=f"d{i}", ops=ops,
+                                      final_seq=tail[-1][0],
+                                      final_msn=tail[-1][3]))
+    return docs
+
+
+TAIL_SEED = 2_147_483_659
+
+
+@pytest.fixture(scope="module")
+def tail_chunk():
+    from fluidframework_tpu.ops.mergetree_kernel import pack_mergetree_batch
+
+    docs = _string_tail_docs(TAIL_SEED, 36)
+    state, ops, meta = pack_mergetree_batch(docs)
+    assert not meta["sequential"] and meta["has_props"]
+    return docs, state, ops, meta
+
+
+def _use_former_helpers(patch):
+    from fluidframework_tpu.ops import mergetree_kernel as mk
+
+    patch.setattr(mk, "_shift_right", _take_shift)
+    patch.setattr(mk, "_pick", _index_pick)
+
+
+def _assert_states_equal(new, old):
+    import numpy as np
+
+    for name, a, b in zip(new._fields, new, old):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+
+
+def _fold(state, ops, facts):
+    """The vmapped scan, traced afresh, so that it reads the helpers as
+    they stand."""
+    import jax
+
+    from fluidframework_tpu.ops import mergetree_kernel as mk
+
+    return jax.jit(lambda s, o: mk.replay_vmapped(s, o, **facts))(state, ops)
+
+
+@pytest.mark.parametrize("facts", ["served", "full"])
+def test_string_tail_fold_equals_the_take_fold(tail_chunk, monkeypatch,
+                                               facts):
+    """The whole fold over concurrent tails, array for array, against the
+    same fold built on the former take shifts and index picks: under the
+    chunk's own facts (as served) and under the full semantics (every
+    plane shifted, the obliterate neighbor picks traced in)."""
+    import numpy as np
+
+    _docs, state, ops, meta = tail_chunk
+    kw = {}
+    if facts == "served":
+        kw = dict(sequential=bool(meta["sequential"]),
+                  has_ob=bool(meta["ob_rows"]), has_ov=bool(meta["ov_rows"]),
+                  has_props=bool(meta["has_props"]))
+    new = _fold(state, ops, kw)
+    with monkeypatch.context() as patch:
+        _use_former_helpers(patch)
+        old = _fold(state, ops, kw)
+    assert int(np.asarray(new.n).min()) > 0
+    _assert_states_equal(new, old)
+
+
+def test_string_tail_documents_match_the_oracle(tail_chunk):
+    docs = tail_chunk[0]
+    expected = []
+    for doc in docs:
+        replica = SharedString(doc.doc_id)
+        for msg in doc.ops:
+            replica.process(msg, local=False)
+        expected.append(replica.summarize().digest())
+    stats = {}
+    summaries = replay_mergetree_batch(docs, stats=stats)
+    assert stats.get("device_docs", 0) > len(docs) // 2, stats
+    assert [s.digest() for s in summaries] == expected
+
+
+@pytest.mark.parametrize("kind", ["split-disabled", "remove"])
+def test_scan_step_without_a_shift_equals_the_take_step(tail_chunk,
+                                                        monkeypatch, kind):
+    """Where no shift takes effect (a split with ``enable`` false; a
+    remove, whose insert branch is selected away) the step returns what
+    the former step returned, and a disabled split the state itself."""
+    import jax
+    import jax.numpy as jnp
+
+    from fluidframework_tpu.ops import mergetree_kernel as mk
+
+    _docs, state, ops, _meta = tail_chunk
+    d, t = 7, 60   # an annotating document, well into its tail
+    doc_state = jax.tree.map(lambda x: jnp.asarray(x[d]), state)
+    doc_ops = jax.tree.map(lambda x: jnp.asarray(x[d]), ops)
+    mid = mk.replay_scan(doc_state, jax.tree.map(lambda x: x[:t], doc_ops))
+    if kind == "split-disabled":
+        def step():
+            return mk._split_at(mid, jnp.int32(3), doc_ops.ref_seq[t],
+                                doc_ops.client[t], jnp.bool_(False))
+    else:
+        remove = jax.tree.map(lambda x: x[t], doc_ops)._replace(
+            kind=jnp.int32(mk.K_REMOVE), a=jnp.int32(1), b=jnp.int32(5))
+
+        def step():
+            return mk._apply_op(mid, remove)
+    new = step()
+    with monkeypatch.context() as patch:
+        _use_former_helpers(patch)
+        old = step()
+    _assert_states_equal(new, old)
+    if kind == "split-disabled":
+        _assert_states_equal(new, mid)

@@ -9,6 +9,7 @@ imports this file.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -93,25 +94,81 @@ def test_scan_fold_compiles(one_chip, mt_chunk):
     assert mem.temp_size_in_bytes < 16 * 2**30  # fits the chip's HBM
 
 
+@pytest.fixture(scope="module")
+def export_compiled(one_chip, mt_chunk):
+    """The single-chip fold+export of the bench chunk, compiled once per
+    start (cold or warm) for the tests that read it."""
+    done = {}
+
+    def compiled(warm):
+        if warm in done:
+            return done[warm]
+        facts, state_n, ops_n, doc_base, S = _export_args(mt_chunk)
+        args = [_specs(ops_n, one_chip), _specs(doc_base, one_chip)]
+        flags = (facts["i16"], facts["ob_rows"], "", facts["ov_rows"],
+                 facts["i8"], facts["sequential"], facts["has_props"])
+        if warm:
+            fn = _export_warm_fn(*flags, out_sharding=one_chip, digest=True)
+            args.insert(0, _specs(state_n, one_chip))
+        else:
+            fn = _export_cold_fn(S, *flags, out_sharding=one_chip,
+                                 digest=True)
+        done[warm] = fn.lower(*args).compile()
+        return done[warm]
+
+    return compiled
+
+
 @pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
-def test_export_compiles_with_forced_format_and_digest(one_chip, mt_chunk,
+def test_export_compiles_with_forced_format_and_digest(export_compiled,
                                                        warm):
     """The single-chip fold+export with the forced row-major Format (it
     decides from the described device, not the CPU backend) and the
     digest plane."""
-    facts, state_n, ops_n, doc_base, S = _export_args(mt_chunk)
-    args = [_specs(ops_n, one_chip), _specs(doc_base, one_chip)]
-    flags = (facts["i16"], facts["ob_rows"], "", facts["ov_rows"],
-             facts["i8"], facts["sequential"], facts["has_props"])
-    if warm:
-        fn = _export_warm_fn(*flags, out_sharding=one_chip, digest=True)
-        args.insert(0, _specs(state_n, one_chip))
-    else:
-        fn = _export_cold_fn(S, *flags, out_sharding=one_chip, digest=True)
-    compiled = fn.lower(*args).compile()
-    formats = jax.tree.leaves(compiled.output_formats)
+    formats = jax.tree.leaves(export_compiled(warm).output_formats)
     assert len(formats) >= 2  # the buffer(s) + the digest plane
     assert formats[0].layout.major_to_minor == (0, 1, 2)
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$")
+
+
+def _while_body_lines(hlo: str) -> list:
+    """The instruction lines of every computation a ``while`` body of the
+    optimized HLO module reaches (fusions, reductions, nested loops)."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line == "}":
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    todo = [name for lines in comps.values() for line in lines
+            for name in re.findall(r"\bbody=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        todo += [ref for line in comps[name]
+                 for ref in re.findall(r"%([\w.\-]+)", line)]
+    return [line for name in seen for line in comps[name]]
+
+
+@pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
+def test_scan_body_has_no_gather(export_compiled, warm):
+    """The merge-tree scan moves its segment pool by a one-slot roll and a
+    select.  A per-document ``take`` there compiles on the v5e to a
+    general batched gather of the whole [docs, slots] plane, about ten
+    times an elementwise pass, once per plane and scan step."""
+    body = _while_body_lines(export_compiled(warm).as_text())
+    assert body, "no while loop in the compiled fold"
+    gathers = [line.strip()[:160] for line in body
+               if re.search(r"\sgather\(", line)]
+    assert gathers == []
 
 
 def _map_args(n_docs):
