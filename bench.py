@@ -829,7 +829,7 @@ def _run_bench(device: dict) -> dict:
             f"i16={'yes' if warm_meta['i16_ok'] else 'no'}, "
             f"i8={'yes' if warm_meta.get('i8_ok') else 'no'}, "
             f"ob_rows={'yes' if warm_meta.get('ob_rows', True) else 'ELIDED'}, "
-            f"ov_rows={'yes' if warm_meta.get('ov_rows', True) else 'ELIDED'}, "
+            f"ov_slots={warm_meta.get('ov_slots', 1)}, "
             f"props={'carried' if warm_meta.get('has_props', True) else 'ELIDED'})",
             file=sys.stderr,
         )

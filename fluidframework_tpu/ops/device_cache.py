@@ -99,9 +99,14 @@ def tuple_sig(state, ops) -> tuple:
     sig = tuple((f, str(getattr(ops, f).dtype), getattr(ops, f).shape)
                 for f in type(ops)._fields)
     if state is not None:
-        sig += tuple((f, str(getattr(state, f).dtype),
-                      getattr(state, f).shape)
-                     for f in type(state)._fields)
+        for f in type(state)._fields:
+            v = getattr(state, f)
+            # A tuple field (the merge-tree overlap slots past the first)
+            # gives an entry per plane: a state with more slots never
+            # matches resident buffers with fewer.
+            planes = enumerate(v) if isinstance(v, tuple) else [(None, v)]
+            sig += tuple((f if i is None else f"{f}[{i}]", str(x.dtype),
+                          x.shape) for i, x in planes)
     return sig
 
 

@@ -8,7 +8,7 @@ tombstone: any historical view is reconstructible from the final arrays —
 - bounded visibility at fold position ``s`` for client ``c``:
   insert counts iff ``ins_seq <= ref`` or (own and ``ins_seq < s``); removal
   counts iff ``rem_seq <= ref`` or the client is a remover whose removal
-  sequenced before ``s`` (the second-remover fields carry exact overlap
+  sequenced before ``s`` (the overlap-remover slots carry exact overlap
   timing — the reason the kernel tracks (seq, client) pairs, not a bitmask);
 - reference slides replay lazily as a cascade: a ref attached at ``s`` on a
   segment removed at ``t >= s`` slides at ``t`` to the nearest segment that
@@ -42,8 +42,18 @@ class FinalStateView:
         self.ins_client = np.asarray(state_np["ins_client"][d, :n])
         self.rem_seq = np.asarray(state_np["rem_seq"][d, :n])
         self.rem_client = np.asarray(state_np["rem_client"][d, :n])
-        self.rem2_seq = np.asarray(state_np["rem2_seq"][d, :n])
-        self.rem2_client = np.asarray(state_np["rem2_client"][d, :n])
+        def slots(first, more):
+            """One row per overlap slot: rem2, then any further slots
+            (``remx_seq``/``remx_client``, absent from states without
+            them)."""
+            rows = np.asarray(state_np[first][d, :n])[None]
+            if more not in state_np:
+                return rows
+            return np.concatenate([rows,
+                                   np.asarray(state_np[more][d, :, :n])])
+
+        self.ov_seq = slots("rem2_seq", "remx_seq")
+        self.ov_client = slots("rem2_client", "remx_client")
         self.ob1_seq = np.asarray(state_np["ob1_seq"][d, :n])
         self.ob1_client = np.asarray(state_np["ob1_client"][d, :n])
         self.ob2_seq = np.asarray(state_np["ob2_seq"][d, :n])
@@ -79,7 +89,7 @@ class FinalStateView:
         removed = (
             (is_removed & (self.rem_seq <= ref))
             | ((self.rem_client == client) & (self.rem_seq < up_to))
-            | ((self.rem2_client == client) & (self.rem2_seq < up_to))
+            | ((self.ov_client == client) & (self.ov_seq < up_to)).any(0)
             # Ob-stamp authors are involved in the removal (the oracle's
             # rule; kernel-side gap found at fuzz seed 1500041) — the
             # stamp itself must be sequenced before the view's fold

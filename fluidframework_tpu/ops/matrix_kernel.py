@@ -94,8 +94,9 @@ class MatrixDocInput:
 
 def known_matrix_fallback(doc: MatrixDocInput) -> bool:
     """Pre-pack oracle-fallback predicate: >1 overlap remover on a base
-    permutation record (the device tracks exactly two removers and the base
-    format carries no overlap seqs — same rule as the merge-tree kernel)."""
+    permutation record (the matrix axes fold with one overlap slot, so the
+    device tracks exactly two removers, and the base format carries no
+    overlap seqs)."""
     if doc.base_summary is None:
         return False
     body = json.loads(doc.base_summary.blob_bytes("body"))

@@ -15,8 +15,9 @@ kept in sequence order as a struct-of-int32-arrays; each sequenced op is one
 3. the op body as masked updates: insert = shift + write at the tie-break
    index (first slot whose exclusive prefix ≥ pos — catch-up has no pending
    segments, so the SEMANTICS.md tie-break degenerates to exactly this);
-   remove = first-wins removal marking (+ exact-timed second-remover
-   fields for overlap); annotate = masked property-column writes.
+   remove = first-wins removal marking (+ exact-timed (seq, client)
+   overlap-remover slots, filled in seq order); annotate = masked
+   property-column writes.
 
 Catch-up is post-sequencing: the fold is sequential per document but
 embarrassingly parallel across documents — `vmap` over the doc axis, then
@@ -30,11 +31,13 @@ extraction.  Text bytes stay host-side in an arena; the device tracks
 
 Interval ops don't run on device: they are folded host-side over the final
 device state (ops/interval_replay.py), which retains every tombstone and so
-reconstructs any historical view.  Documents where >2 removers overlap one
-segment (device tracks two exactly; flag raised otherwise) or whose base
-summary carries >1 overlap removers fall back to a full oracle replay —
-correctness is never approximated.  Segment pool capacity = base segments +
-2·ops (each op splits ≤ 2).
+reconstructs any historical view.  Overlapping removers of one segment keep
+a (seq, client) slot each, as many slots as the chunk's own input can fill
+(``ov_slot_count``, capped at ``OV_SLOT_CAP``); a document with more
+removers than that (flag raised) or a base summary carrying more overlap
+removers falls back to a full oracle replay — correctness is never
+approximated.  Segment pool capacity = base segments + 2·ops (each op
+splits ≤ 2).
 """
 
 from __future__ import annotations
@@ -78,7 +81,13 @@ class MTState(NamedTuple):
     ob2_client: jnp.ndarray  # [S] second stamp client / -1
     props: jnp.ndarray       # [S, K] interned value ids / PROP_ABSENT
     n: jnp.ndarray           # [] live slot count
-    overflow: jnp.ndarray    # [] bool: >2 removers hit one segment
+    overflow: jnp.ndarray    # [] bool: a remover found every slot full
+    # Third and later removers: a (seq, client) plane pair per overlap
+    # slot past the first, filled in seq order.  Empty (no leaves) unless
+    # the chunk needs two or more overlap slots, so such chunks fold the
+    # same pytree as a state without these fields.
+    remx_seq: tuple = ()     # ([S], ...) remover seq / NOT_REMOVED
+    remx_client: tuple = ()  # ([S], ...) remover client / -1
 
 
 class MTOps(NamedTuple):
@@ -96,6 +105,57 @@ class MTOps(NamedTuple):
     pvals: jnp.ndarray    # [T, K] per-key values / PROP_NOT_TOUCHED
 
 
+#: most overlap-remover slots a chunk folds with; a document whose
+#: segment has more concurrent removers than 1 + this takes the oracle
+OV_SLOT_CAP = 8
+
+
+def ov_slot_count(tail_removers: np.ndarray, base_removers: np.ndarray,
+                  base_overlap: int, sequential: bool,
+                  cap: int = OV_SLOT_CAP) -> int:
+    """The overlap-remover slots a chunk folds with, from its packed input
+    alone.  A client removes a given character at most once (its own
+    remove is in its view), so the distinct clients with a remove or
+    obliterate row in a document's tail (``tail_removers``, per doc), plus
+    the most removers any one of its base records carries
+    (``base_removers``, per doc: the winner and its "ro" list), bound the
+    removers of any of its segments.  One of them wins; the rest need a
+    slot each.  Sequential views never see a removed segment, so only the
+    base records' own overlap removers (``base_overlap``, the longest "ro"
+    list) need slots there.  0 keeps the overlap planes constant; more is
+    rounded up to a power of two, at most ``cap``."""
+    need = int(base_overlap)
+    if not sequential and len(tail_removers):
+        need = max(need, int((tail_removers + base_removers).max()) - 1)
+    if need <= 0:
+        return 0
+    slots = 1
+    while slots < need:
+        slots *= 2
+    return min(slots, cap)
+
+
+def tail_remover_counts(kind: np.ndarray, client: np.ndarray) -> np.ndarray:
+    """Per document, the distinct clients with a remove or obliterate row
+    in a packed ``[D, T]`` op stream."""
+    rem = (kind == K_REMOVE) | (kind == K_OBLITERATE)
+    if not rem.any():
+        return np.zeros(kind.shape[0], np.int64)
+    col = np.where(rem, client + 1, 0)  # client -1 (none) is a client too
+    seen = np.zeros((kind.shape[0], int(col.max()) + 1), np.bool_)
+    d, t = np.nonzero(rem)
+    seen[d, col[d, t]] = True
+    return seen.sum(axis=1)
+
+
+def ov_slot_cap() -> int:
+    """``OV_SLOT_CAP``; 1 under the Pallas fold, which keeps one overlap
+    slot (a third remover overflows there, as before)."""
+    from .pallas_fold import pallas_fold_mode
+
+    return 1 if pallas_fold_mode() else OV_SLOT_CAP
+
+
 def _visible_len(state: MTState, ref_seq, client,
                  has_ob: bool = True) -> jnp.ndarray:
     slot = jnp.arange(state.tlen.shape[0])
@@ -106,6 +166,8 @@ def _visible_len(state: MTState, ref_seq, client,
         | (state.rem_client == client)
         | (state.rem2_client == client)
     )
+    for plane in state.remx_client:
+        rem_vis = rem_vis | (plane == client)
     if has_ob:
         # An obliterate STAMP makes its author involved in the removal
         # even when another client's remove won it: the author's
@@ -151,9 +213,9 @@ def _split_at(state: MTState, char_pos, ref_seq, client, enable,
 
     Constant planes are SHIFT-INVARIANT, so the chunk facts skip their
     shuffles outright: ob-free chunks never write the four ob columns
-    (they stay NOT_REMOVED/-1), second-remover-free chunks (fully
-    sequential views + no base "ro") never write rem2, props-free chunks
-    never write the [S, K] plane."""
+    (they stay NOT_REMOVED/-1), overlap-free chunks (``ov_slot_count``
+    0) never write rem2, props-free chunks never write the [S, K]
+    plane."""
     S = state.tlen.shape[0]
     v = _visible_len(state, ref_seq, client, has_ob)
     cum = _excl_cumsum(v)
@@ -189,6 +251,8 @@ def _split_at(state: MTState, char_pos, ref_seq, client, enable,
         props=shift(state.props) if has_props else state.props,
         n=state.n + 1,
         overflow=state.overflow,
+        remx_seq=tuple(shift(p) for p in state.remx_seq),
+        remx_client=tuple(shift(p) for p in state.remx_client),
     )
     return jax.tree.map(lambda new, old: jnp.where(do, new, old), out, state)
 
@@ -210,11 +274,14 @@ def _apply_op(state: MTState, op, sequential: bool = False,
     anywhere (no annotate ops, no base props — pack's interner is empty)
     keeps its constant PROP_ABSENT plane untouched: the per-op [S, K]
     plane shift and the annotate write trace away.  ``has_ov=False``
-    (the ov_rows export predicate: fully sequential views + no base
-    "ro", so a second remover cannot occur — a sequential remove can
-    never even target an already-removed segment, it is invisible in the
-    remover's view) keeps the two rem2 planes constant: their shifts and
-    the second/third-remover writes trace away."""
+    (``ov_slot_count`` 0: no segment of the chunk can have a second
+    remover — a sequential remove never even targets an already-removed
+    segment, it is invisible in the remover's view) keeps the two rem2
+    planes constant: their shifts and the overlap writes trace away.
+    Otherwise the overlap slots are rem2 and the ``remx_*`` plane pairs
+    the state carries (their count is the state's own structure): a later
+    remover takes the first free slot, and only a remover that finds
+    every slot full raises ``overflow``."""
     S = state.tlen.shape[0]
     ref_seq, client = op.ref_seq, op.client
     is_ins = op.kind == K_INSERT
@@ -331,6 +398,8 @@ def _apply_op(state: MTState, op, sequential: bool = False,
         ) if has_props else state.props,
         n=state.n + 1,
         overflow=state.overflow,
+        remx_seq=tuple(shifted(p, NOT_REMOVED) for p in state.remx_seq),
+        remx_client=tuple(shifted(p, -1) for p in state.remx_client),
     )
     state = jax.tree.map(
         lambda new, old: jnp.where(is_ins, new, old), ins_state, state
@@ -345,8 +414,14 @@ def _apply_op(state: MTState, op, sequential: bool = False,
     is_rem_like = is_rem | is_obl
     first_win = covered & (state.rem_seq == NOT_REMOVED) & is_rem_like
     again = covered & (state.rem_seq != NOT_REMOVED) & is_rem_like
-    second = again & (state.rem2_seq == NOT_REMOVED)
-    third = again & (state.rem2_seq != NOT_REMOVED)
+    # Overlap slots fill in seq order (occupied slots are a prefix): a
+    # later remover takes the first free one; ``full`` is left holding
+    # the segments where every slot was taken.
+    takes = []
+    full = again
+    for plane in (state.rem2_seq,) + state.remx_seq:
+        takes.append(full & (plane == NOT_REMOVED))
+        full = full & (plane != NOT_REMOVED)
     if has_ob:
         # Obliterate additionally stamps zero-width slots strictly inside
         # the range: tombstones (stamp only) and invisible concurrent
@@ -376,14 +451,18 @@ def _apply_op(state: MTState, op, sequential: bool = False,
         rem_client=jnp.where(first_win, client, state.rem_client),
     )
     if has_ov:
-        # Sequential view + no base "ro" (has_ov=False): a remove or
-        # obliterate can never target an already-removed segment
-        # (invisible to its author), so `second`/`third` are structurally
-        # false — rem2 stays constant and these writes trace away.
+        # No second remover possible (has_ov=False): a remove or
+        # obliterate can never target an already-removed segment, so
+        # every ``takes`` mask is structurally false — rem2 stays
+        # constant and these writes trace away.
         state = state._replace(
-            rem2_seq=jnp.where(second, op.seq, state.rem2_seq),
-            rem2_client=jnp.where(second, client, state.rem2_client),
-            overflow=state.overflow | third.any(),
+            rem2_seq=jnp.where(takes[0], op.seq, state.rem2_seq),
+            rem2_client=jnp.where(takes[0], client, state.rem2_client),
+            remx_seq=tuple(jnp.where(t, op.seq, p)
+                           for t, p in zip(takes[1:], state.remx_seq)),
+            remx_client=tuple(jnp.where(t, client, p)
+                              for t, p in zip(takes[1:], state.remx_client)),
+            overflow=state.overflow | full.any(),
         )
 
     if has_props:
@@ -403,7 +482,7 @@ def replay_scan(state: MTState, ops: MTOps, sequential: bool = False,
     """Pure single-document op-fold (no jit): scan the op stream.
     ``sequential``/``has_ob``/``has_props``/``has_ov`` are compile-time
     chunk facts (see ``_apply_op``); the defaults are the full
-    semantics."""
+    semantics.  The overlap slots are the ones ``state`` carries."""
 
     def step(carry, op):
         return _apply_op(carry, op, sequential, has_ob, has_props,
@@ -425,13 +504,15 @@ def replay_vmapped(state: MTState, ops: MTOps, sequential: bool = False,
 
 
 
-def _cold_start(ops: "MTOps", S: int) -> "MTState":
+def _cold_start(ops: "MTOps", S: int, ov_slots: int = 1) -> "MTState":
     """Empty initial state built IN-GRAPH: documents with no base summary
     start from all zeros/sentinels — constructing it on device instead of
     transferring (D, S) arrays of zeros cuts the per-chunk upload to the op
-    arrays alone."""
+    arrays alone.  ``ov_slots`` overlap slots: rem2, then a ``remx_*``
+    plane pair for each slot past the first."""
     D = ops.kind.shape[0]
     K = ops.pvals.shape[2]
+    extra = max(ov_slots - 1, 0)
     return MTState(
         tstart=jnp.zeros((D, S), jnp.int32),
         tlen=jnp.zeros((D, S), jnp.int32),
@@ -448,6 +529,10 @@ def _cold_start(ops: "MTOps", S: int) -> "MTState":
         props=jnp.full((D, S, K), PROP_ABSENT, jnp.int32),
         n=jnp.zeros((D,), jnp.int32),
         overflow=jnp.zeros((D,), jnp.bool_),
+        remx_seq=tuple(jnp.full((D, S), NOT_REMOVED, jnp.int32)
+                       for _ in range(extra)),
+        remx_client=tuple(jnp.full((D, S), -1, jnp.int32)
+                          for _ in range(extra)),
     )
 
 
@@ -459,7 +544,9 @@ def _replay_batch_cold(ops: "MTOps", S: int) -> "MTState":
 # Export row layout: per-slot fields stacked into ONE array so the
 # device→host copy costs a single transfer per fold (each transfer pays a
 # fixed latency — ten small arrays cost 10× one fused array in round 2).
-# Rows 0..7 are the slot fields, rows 8..8+K-1 the property columns, and
+# Rows 0..11 are the slot fields, rows 12..12+K-1 the property columns,
+# then one (seq, client) row pair per overlap slot past the first
+# (``ov_extra_fields``: none unless the chunk folds with two or more), and
 # the final row is misc: [n, overflow, live_len].
 #
 # Two element widths exist.  The int32 layout is the always-correct default;
@@ -481,13 +568,20 @@ EXPORT_SLOT_FIELDS = (
 NON_OB_SLOT_FIELDS = EXPORT_SLOT_FIELDS[:8]
 #: the obliterate rows elided from such exports, with their sentinel fills
 OB_SLOT_FIELDS = EXPORT_SLOT_FIELDS[8:]
-#: the overlap-remover rows, elided (``meta["ov_rows"]`` False) when the
-#: chunk provably cannot produce a second remover: every op rides a fully
-#: sequential view (ref_seq == seq-1 — an already-removed slot is never
-#: visible, so ``second`` can't fire) and no base record carries "ro"
+#: the first overlap slot's rows, elided (``meta["ov_slots"]`` 0) when
+#: no segment of the chunk can have a second remover (``ov_slot_count``)
 OV_SLOT_FIELDS = ("rem2_seq", "rem2_client")
 #: rows holding seqs with the NOT_REMOVED sentinel (narrow remap set)
 SENTINEL_SEQ_FIELDS = ("rem_seq", "rem2_seq", "ob1_seq", "ob2_seq")
+
+
+def _is_sentinel_seq(field: str) -> bool:
+    """A seq row with the NOT_REMOVED sentinel: every seq row but the
+    insert's, the overlap slots past the first (``ov_extra_fields``)
+    among them."""
+    return field.endswith("_seq") and field != "ins_seq"
+
+
 I16_NOT_REMOVED = np.int16(np.iinfo(np.int16).max)
 I16_LIMIT = int(np.iinfo(np.int16).max) - 1  # strict value bound for i16_ok
 #: int8 pair-packing (``meta["i8_ok"]``): when every exported value other
@@ -497,16 +591,23 @@ I8_NOT_REMOVED = np.int32(127)
 I8_LIMIT = 126
 
 
-def _export_fields(ob_rows: bool, ov_rows: bool):
+def _export_fields(ob_rows: bool, ov_slots: int):
     fields = list(EXPORT_SLOT_FIELDS if ob_rows else NON_OB_SLOT_FIELDS)
-    if not ov_rows:
+    if not ov_slots:
         fields = [f for f in fields if f not in OV_SLOT_FIELDS]
     return fields
 
 
+def ov_extra_fields(ov_slots: int) -> List[str]:
+    """The export rows of the overlap slots past the first, after the
+    props rows: ``rem3_seq, rem3_client, rem4_seq, ...``."""
+    return [f"rem{j}_{part}" for j in range(3, ov_slots + 2)
+            for part in ("seq", "client")]
+
+
 def _export_state(final: MTState, doc_base: Optional[jnp.ndarray] = None,
                   i16: bool = False, ob_rows: bool = True,
-                  ov_rows: bool = True, i8: bool = False,
+                  ov_slots: int = 1, i8: bool = False,
                   props_rows: bool = True) -> jnp.ndarray:
     """[D, rows, S] fused view of everything summary extraction and
     interval replay need from the final device state (int32, or int16 when
@@ -517,8 +618,9 @@ def _export_state(final: MTState, doc_base: Optional[jnp.ndarray] = None,
     (the device→host fetch is the pipeline's measured bottleneck):
     - ``ob_rows=False``: the four obliterate rows elided (no obliterate
       ops or base stamps in the chunk — pack-time fact);
-    - ``ov_rows=False``: the two overlap-remover rows elided (fully
-      sequential views + no base "ro" — a second remover cannot occur);
+    - ``ov_slots=0``: the two overlap-remover rows elided (no segment of
+      the chunk can have a second remover); each slot past the first
+      adds a row pair after the props rows;
     - ``props_rows=False``: the K props-plane rows elided (props-free
       chunk — the plane is constant PROP_ABSENT);
     - ``i8``: every byte-sized row pairs into one int16 lane
@@ -526,6 +628,8 @@ def _export_state(final: MTState, doc_base: Optional[jnp.ndarray] = None,
     i8 = i8 and i16  # byte packing presupposes the int16 transforms
     D, S = final.tlen.shape
     K = final.props.shape[2]
+    extra = ov_extra_fields(ov_slots)
+    assert len(extra) == 2 * len(final.remx_seq), "ov_slots != state"
     slot = jnp.arange(S)[None, :]
     active = slot < final.n[:, None]
     live = jnp.where(
@@ -540,18 +644,22 @@ def _export_state(final: MTState, doc_base: Optional[jnp.ndarray] = None,
     # ``widen_export`` (and export bytes are deterministic).
     tstart = jnp.where(active, final.tstart, 0)
     named = {"tstart": tstart}
-    fields = _export_fields(ob_rows, ov_rows)
+    named.update(zip(extra, (p for pair in zip(final.remx_seq,
+                                               final.remx_client)
+                             for p in pair)))
+    fields = _export_fields(ob_rows, ov_slots)
     if i16:
         named["tstart"] = jnp.where(active, tstart - doc_base[:, None], 0)
         sentinel = I8_NOT_REMOVED if i8 else jnp.int32(I16_NOT_REMOVED)
-        for f in SENTINEL_SEQ_FIELDS:
-            if f not in fields:
+        for f in fields + extra:
+            if not _is_sentinel_seq(f):
                 continue
-            val = getattr(final, f)
+            val = named[f] if f in named else getattr(final, f)
             named[f] = jnp.where(val == NOT_REMOVED, sentinel, val)
     rows = [named.get(f, getattr(final, f)) for f in fields]
     if props_rows:
         rows += [final.props[:, :, k] for k in range(K)]
+    rows += [named[f] for f in extra]
     if i8:
         byte_rows = rows[1:]
         if len(byte_rows) % 2:
@@ -590,6 +698,7 @@ def export_to_numpy(export):
 #: so bucket growth / row elisions / byte packing cannot perturb it.
 _DIGEST_PLANES = tuple(EXPORT_SLOT_FIELDS)
 _DIGEST_PROPS_BASE = 16  # props column k salts at 16 + k
+_DIGEST_OV_BASE = 1 << 20  # remx slot j salts seq at this + 2j, client + 1
 
 
 def _mix_u32(x: jnp.ndarray) -> jnp.ndarray:
@@ -618,7 +727,10 @@ def _doc_digests(final: MTState, doc_base: jnp.ndarray) -> jnp.ndarray:
       props key the document never set contributes ZERO (set values hash
       shifted by +1 — intern ids are >= 0, so "value 0" stays distinct
       from "absent"), so K-bucket growth (another doc's new annotate
-      key) cannot perturb it either;
+      key) cannot perturb it either; likewise an empty overlap slot past
+      the first contributes ZERO, so another doc's third remover (more
+      ``remx_*`` planes in the chunk) cannot perturb it, while every
+      occupied slot is mixed in;
     - 64 bits across two independently-salted lanes — a collision (the
       only way delta download could serve wrong bytes for inputs the
       host-side anchor check cannot distinguish) is a ~2^-64 event, and
@@ -653,6 +765,17 @@ def _doc_digests(final: MTState, doc_base: jnp.ndarray) -> jnp.ndarray:
             w = _mix_u32(slot_u * jnp.uint32(0x01000193)
                          + jnp.uint32(_DIGEST_PROPS_BASE + k) + lane_salt)
             acc = acc + (v * w).sum(axis=1, dtype=jnp.uint32)
+        for j, (seq, cl) in enumerate(zip(final.remx_seq,
+                                          final.remx_client)):
+            # An empty slot hashes 0; a taken one mixes its seq and its
+            # client + 2 (never 0: the universal client is -1).
+            taken = active & (seq != NOT_REMOVED)
+            for h, v in enumerate((seq, cl + 2)):
+                v = jnp.where(taken, v, 0).astype(jnp.uint32)
+                w = _mix_u32(slot_u * jnp.uint32(0x01000193)
+                             + jnp.uint32(_DIGEST_OV_BASE + 2 * j + h)
+                             + lane_salt)
+                acc = acc + (v * w).sum(axis=1, dtype=jnp.uint32)
         acc = acc ^ _mix_u32(final.n.astype(jnp.uint32) + lane_salt)
         acc = acc ^ _mix_u32(live_len.astype(jnp.uint32) * jnp.uint32(3)
                              + lane_salt)
@@ -740,26 +863,31 @@ def gather_export_rows(export, idx: np.ndarray):
     return (tuple(out) if isinstance(export, tuple) else out[0]), moved
 
 
-def _widen_desc(ob_rows: bool, ov_rows: bool, i8: bool, props_rows: bool,
+def _widen_desc(ob_rows: bool, ov_slots: int, i8: bool, props_rows: bool,
                 n_props: int):
     """The per-canonical-row descriptor table oppack_widen consumes:
-    [mode, arg, fill, flags] × (13 + K) rows.  Mirrors widen_export's
-    field order exactly (same _export_fields derivation)."""
-    fields = _export_fields(ob_rows, ov_rows)
+    [mode, arg, fill, flags] × (13 + K + 2·extra slots) rows.  Mirrors
+    widen_export's field order exactly (same _export_fields
+    derivation)."""
+    fields = _export_fields(ob_rows, ov_slots)
+    extra = ov_extra_fields(ov_slots)
+    K_src = n_props if props_rows else 0
 
-    def src_of(f: str):
+    def src(i: int):
+        """(mode, arg) of transfer row ``i`` (the source layout: fields,
+        props, extra overlap rows)."""
         if not i8:
-            return 1, fields.index(f)                       # ROW16
-        if f == "tstart":
+            return 1, i                                     # ROW16
+        if i == 0:
             return 1, 0                                     # 16-bit lane
-        i = fields.index(f) - 1                             # byte index
-        return 2, (1 + i // 2) * 2 + (i % 2)                # PAIR8
+        b = i - 1                                           # byte index
+        return 2, (1 + b // 2) * 2 + (b % 2)                # PAIR8
 
     desc = []
     for f in EXPORT_SLOT_FIELDS:
         if f in fields:
-            mode, arg = src_of(f)
-            flags = (1 if f in SENTINEL_SEQ_FIELDS else 0) \
+            mode, arg = src(fields.index(f))
+            flags = (1 if _is_sentinel_seq(f) else 0) \
                 | (2 if f == "tstart" else 0)
             desc.append((mode, arg, 0, flags))
         else:
@@ -767,27 +895,27 @@ def _widen_desc(ob_rows: bool, ov_rows: bool, i8: bool, props_rows: bool,
             desc.append((0, 0, fill, 0))
     for k in range(n_props):
         if props_rows:
-            if i8:
-                i = len(fields) - 1 + k
-                desc.append((2, (1 + i // 2) * 2 + (i % 2), 0, 0))
-            else:
-                desc.append((1, len(fields) + k, 0, 0))
+            desc.append(src(len(fields) + k) + (0, 0))
         else:
             desc.append((0, 0, int(PROP_ABSENT), 0))
+    for e, f in enumerate(extra):
+        mode, arg = src(len(fields) + K_src + e)
+        desc.append((mode, arg, 0, 1 if _is_sentinel_seq(f) else 0))
     if i8:
         desc.append((3, 0, 0, 0))                           # stitched misc
     else:
-        n_src = len(fields) + (n_props if props_rows else 0) + 1
+        n_src = len(fields) + K_src + len(extra) + 1
         desc.append((1, n_src - 1, 0, 0))                   # misc row
     return np.asarray(desc, np.int32).reshape(-1)
 
 
-def widen_export_native(export_np, doc_base, ob_rows: bool, ov_rows: bool,
+def widen_export_native(export_np, doc_base, ob_rows: bool, ov_slots: int,
                         i8: bool, n_props: int, props_rows: bool):
     """C++ single-pass widen of a narrow export buffer to the canonical
-    [D, 13+K, S] int32 layout — byte-identical to ``widen_export``
-    (pinned by tests), ~10× faster on the extraction hot path.  Returns
-    None when inapplicable (already int32, or no native library)."""
+    [D, 13+K+2·extra, S] int32 layout — byte-identical to
+    ``widen_export`` (pinned by tests), ~10× faster on the extraction hot
+    path.  Returns None when inapplicable (already int32, or no native
+    library)."""
     from .native_pack import load_library
 
     misc_np = None
@@ -799,7 +927,7 @@ def widen_export_native(export_np, doc_base, ob_rows: bool, ov_rows: bool,
     if lib is None:
         return None
     D, R_src, S = export_np.shape
-    desc = _widen_desc(ob_rows, ov_rows, i8, props_rows, n_props)
+    desc = _widen_desc(ob_rows, ov_slots, i8, props_rows, n_props)
     R_canon = len(desc) // 4
     dst = np.empty((D, R_canon, S), np.int32)
     src = np.ascontiguousarray(export_np, np.int16)
@@ -825,7 +953,7 @@ def widen_export_native(export_np, doc_base, ob_rows: bool, ov_rows: bool,
 
 def widen_export(export_np,
                  doc_base: Optional[np.ndarray],
-                 ob_rows: bool = True, ov_rows: bool = True,
+                 ob_rows: bool = True, ov_slots: int = 1,
                  i8: bool = False,
                  n_props: Optional[int] = None,
                  props_rows: bool = True) -> np.ndarray:
@@ -839,17 +967,20 @@ def widen_export(export_np,
     misc_np = None
     if isinstance(export_np, tuple):
         export_np, misc_np = export_np
-    fields = _export_fields(ob_rows, ov_rows)
+    fields = _export_fields(ob_rows, ov_slots)
+    extra = ov_extra_fields(ov_slots)
     if export_np.dtype == np.int32:
         out = export_np
     else:
+        K_src = (n_props if props_rows else 0) if i8 else \
+            export_np.shape[1] - len(fields) - len(extra) - 1
         if i8:
             # Unpack byte pairs back into the (elided) int16-equivalent
             # row layout: [tstart, byte rows...] + the stitched misc row.
             assert n_props is not None, "i8 widen needs the props width"
             assert misc_np is not None, "i8 widen needs the misc output"
             u = export_np.astype(np.uint16)
-            n_bytes = len(fields) - 1 + (n_props if props_rows else 0)
+            n_bytes = len(fields) - 1 + K_src + len(extra)
             rows = [export_np[:, 0, :].astype(np.int32)]
             for i in range(n_bytes):
                 pair = u[:, 1 + i // 2, :]
@@ -864,10 +995,11 @@ def widen_export(export_np,
         else:
             out = export_np.astype(np.int32)
         sentinel = int(I8_NOT_REMOVED) if i8 else int(I16_NOT_REMOVED)
-        for f in SENTINEL_SEQ_FIELDS:
-            if f not in fields:
+        names = fields + [None] * K_src + extra
+        for i, f in enumerate(names):
+            if f is None or not _is_sentinel_seq(f):
                 continue
-            row = out[:, fields.index(f), :]
+            row = out[:, i, :]
             row[row == sentinel] = NOT_REMOVED
         if doc_base is not None:
             # Re-add the per-doc arena base to live slots only (slots
@@ -888,12 +1020,14 @@ def widen_export(export_np,
         )
 
     if not props_rows:
-        # Reinsert the constant PROP_ABSENT plane rows before the misc row.
+        # Reinsert the constant PROP_ABSENT plane rows after the slot
+        # rows (before any extra overlap rows and the misc row).
         assert n_props is not None, "props-row reinsert needs the width"
         D, _R, S = out.shape
         filler = np.full((D, n_props, S), PROP_ABSENT, np.int32)
-        out = np.concatenate([out[:, :-1], filler, out[:, -1:]], axis=1)
-    if not ov_rows:
+        out = np.concatenate([out[:, :len(fields)], filler,
+                              out[:, len(fields):]], axis=1)
+    if not ov_slots:
         out = reinsert(out, OV_SLOT_FIELDS,
                        fields.index("rem_client") + 1)  # rem2 slots next
     if not ob_rows:
@@ -946,9 +1080,10 @@ def _fold_fn(mode: str, sequential: bool = False, has_ob: bool = True,
     compile time by the chunk facts — see ``_apply_op``); the Pallas
     VMEM-resident kernel (ops/pallas_fold.py) when FF_PALLAS_FOLD selects
     it — per-doc state stays on-chip across the whole tail instead of
-    round-tripping HBM every op step (SURVEY §7 hard-part #4).  The pallas
-    import stays inside the branches: the default scan path must not
-    depend on jax.experimental.pallas importability."""
+    round-tripping HBM every op step (SURVEY §7 hard-part #4).  The Pallas
+    fold keeps one overlap slot, so under it ``ov_slot_cap`` packs no
+    more.  The pallas import stays inside the branches: the default scan
+    path must not depend on jax.experimental.pallas importability."""
     if mode in ("tpu", "interpret"):
         from .pallas_fold import replay_vmapped_pallas
 
@@ -972,12 +1107,12 @@ def _export_out(i8: bool, sharding=None, digest: bool = False):
     return sharding if n_out == 1 else (sharding,) * n_out
 
 
-def _export_with_digest(final, doc_base, i16, ob_rows, ov_rows, i8,
+def _export_with_digest(final, doc_base, i16, ob_rows, ov_slots, i8,
                         has_props, digest: bool):
     """Export a final state, optionally appending the [D, 2] digest plane
     as the LAST output leaf (see ``split_export_digest``)."""
     with jax.named_scope("export"):
-        ex = _export_state(final, doc_base, i16, ob_rows, ov_rows, i8,
+        ex = _export_state(final, doc_base, i16, ob_rows, ov_slots, i8,
                            props_rows=has_props)
     if not digest:
         return ex
@@ -998,24 +1133,28 @@ def program_name(family: str, digest: bool, start: str = "") -> str:
 
 @functools.lru_cache(maxsize=None)
 def _export_cold_fn(S: int, i16: bool, ob_rows: bool = True,
-                    fold_mode: str = "", ov_rows: bool = True,
+                    fold_mode: str = "", ov_slots: int = 1,
                     i8: bool = False, sequential: bool = False,
                     has_props: bool = True, out_sharding=None,
                     digest: bool = False):
     """Compiled cold-start fold+export for one (S, width, layout) bucket,
-    its output laid out for a line-rate fetch.  ``ob_rows``/``ov_rows``
-    double as the fold facts (has_ob/has_ov): the export elides exactly
-    the planes the fold provably never writes.  ``out_sharding`` (a
+    its output laid out for a line-rate fetch.  ``ob_rows`` doubles as
+    the fold fact has_ob and ``ov_slots`` (``ov_slot_count``) gives
+    has_ov and the overlap slots the cold state starts with: the export
+    elides exactly the planes the fold provably never writes.  A chunk
+    with at most one overlap slot compiles the program it compiled
+    before overlap slots were counted.  ``out_sharding`` (a
     NamedSharding) builds the mesh-sharded variant of the same pipeline —
     ONE derivation point for single-chip and multi-chip exports.
     ``digest`` appends the per-doc state digest plane (delta download)."""
-    fold = _fold_fn(fold_mode, sequential, ob_rows, has_props, ov_rows)
+    ov_slots = int(ov_slots)
+    fold = _fold_fn(fold_mode, sequential, ob_rows, has_props, ov_slots > 0)
 
     def f(ops, doc_base):
         with jax.named_scope("fold"):
             ops = _widen_ops(ops, doc_base)
-            final = fold(_cold_start(ops, S), ops)
-        return _export_with_digest(final, doc_base, i16, ob_rows, ov_rows,
+            final = fold(_cold_start(ops, S, ov_slots), ops)
+        return _export_with_digest(final, doc_base, i16, ob_rows, ov_slots,
                                    i8, has_props, digest)
 
     f.__name__ = f.__qualname__ = program_name("mergetree", digest, "cold")
@@ -1025,19 +1164,21 @@ def _export_cold_fn(S: int, i16: bool, ob_rows: bool = True,
 
 @functools.lru_cache(maxsize=None)
 def _export_warm_fn(i16: bool, ob_rows: bool = True, fold_mode: str = "",
-                    ov_rows: bool = True, i8: bool = False,
+                    ov_slots: int = 1, i8: bool = False,
                     sequential: bool = False, has_props: bool = True,
                     out_sharding=None, digest: bool = False):
     """Compiled warm-start (base state uploaded) fold+export; see
-    ``_export_cold_fn`` for ``out_sharding``/``digest``."""
-    fold = _fold_fn(fold_mode, sequential, ob_rows, has_props, ov_rows)
+    ``_export_cold_fn`` for ``ov_slots``/``out_sharding``/``digest``.
+    The uploaded state carries the ``remx_*`` planes of its slots."""
+    ov_slots = int(ov_slots)
+    fold = _fold_fn(fold_mode, sequential, ob_rows, has_props, ov_slots > 0)
 
     def f(state, ops, doc_base):
         with jax.named_scope("fold"):
             state = _widen_state(state, doc_base)
             ops = _widen_ops(ops, doc_base)
             final = fold(state, ops)
-        return _export_with_digest(final, doc_base, i16, ob_rows, ov_rows,
+        return _export_with_digest(final, doc_base, i16, ob_rows, ov_slots,
                                    i8, has_props, digest)
 
     f.__name__ = f.__qualname__ = program_name("mergetree", digest, "warm")
@@ -1048,27 +1189,29 @@ def _export_warm_fn(i16: bool, ob_rows: bool = True, fold_mode: str = "",
 def export_layout_rows(meta: dict) -> int:
     """Row count of the transfer buffer replay_export emits for this
     packed chunk's layout facts (elisions + byte packing)."""
-    _i16, ob_rows, ov_rows, i8, props_rows = _export_flags(meta)
-    fields = _export_fields(ob_rows, ov_rows)
+    _i16, ob_rows, ov_slots, i8, props_rows = _export_flags(meta)
+    fields = _export_fields(ob_rows, ov_slots)
     K = meta.get("props_K", 1) if props_rows else 0
+    n_extra = len(ov_extra_fields(ov_slots))
     if i8:
-        n_bytes = len(fields) - 1 + K
+        n_bytes = len(fields) - 1 + K + n_extra
         return 1 + (n_bytes + 1) // 2  # misc rides the separate output
-    return len(fields) + K + 1
+    return len(fields) + K + n_extra + 1
 
 
 def _export_flags(meta: dict):
     """The transfer-layout facts BOTH sides of the export handshake use
     (dispatch builds the buffer, extraction widens it) — one derivation
     point so they can never disagree.  The pallas fold ignores the chunk
-    facts, so its mode forces the props rows back on at both ends."""
+    facts, so its mode forces the props rows back on at both ends.  The
+    third fact is the chunk's overlap slot count (``ov_slot_count``)."""
     from .pallas_fold import pallas_fold_mode
 
     i16 = bool(meta.get("i16_ok"))
     return (
         i16,
         bool(meta.get("ob_rows", True)),
-        bool(meta.get("ov_rows", True)),
+        int(meta.get("ov_slots", 1)),
         i16 and bool(meta.get("i8_ok")),
         bool(meta.get("has_props", True)) or pallas_fold_mode() != "",
     )
@@ -1151,24 +1294,35 @@ def narrow_state_for_upload(state: MTState, meta: dict) -> MTState:
     if int(np.abs(np.where(live, 0, state.tstart)).max(initial=0)) != 0:
         return state  # dead slots must be zero for the rebase round trip
     info = np.iinfo(np.int16)
-    narrow = {}
-    for f in EXPORT_SLOT_FIELDS:  # the 12 slot planes, export's own list
-        v = getattr(state, f)
-        if f == "tstart":
-            v = np.where(live, v - doc_base[:, None], 0)
-        elif f in SENTINEL_SEQ_FIELDS:
+
+    def narrow16(v, sentinel_seq: bool):
+        """``v`` as int16, or None where a value does not fit."""
+        if sentinel_seq:
             # Real values must stay STRICTLY below the remapped sentinel
             # (I16_LIMIT, the same bound i16_ok is defined against) — a
             # genuine 32767 would widen back as NOT_REMOVED and
             # resurrect a removed segment.
             reals = np.where(v == NOT_REMOVED, 0, v)
             if int(reals.max(initial=0)) > I16_LIMIT:
-                return state
+                return None
             v = np.where(v == NOT_REMOVED, np.int32(I16_NOT_REMOVED), v)
         if not (info.min <= int(v.min(initial=0))
                 and int(v.max(initial=0)) <= info.max):
-            return state
-        narrow[f] = v.astype(np.int16)
+            return None
+        return v.astype(np.int16)
+
+    narrow = {}
+    for f in EXPORT_SLOT_FIELDS:  # the 12 slot planes, export's own list
+        v = getattr(state, f)
+        if f == "tstart":
+            v = np.where(live, v - doc_base[:, None], 0)
+        narrow[f] = narrow16(v, f in SENTINEL_SEQ_FIELDS)
+    # The overlap slots past the first narrow like rem2.
+    remx_seq = tuple(narrow16(v, True) for v in state.remx_seq)
+    remx_client = tuple(narrow16(v, False) for v in state.remx_client)
+    if any(v is None for v in
+           list(narrow.values()) + list(remx_seq + remx_client)):
+        return state
     if not (int(state.props.min(initial=0)) >= info.min
             and int(state.props.max(initial=0)) <= info.max
             and int(state.n.max(initial=0)) <= info.max):
@@ -1178,6 +1332,8 @@ def narrow_state_for_upload(state: MTState, meta: dict) -> MTState:
         props=state.props.astype(np.int16),
         n=state.n.astype(np.int16),
         overflow=state.overflow,
+        remx_seq=remx_seq,
+        remx_client=remx_client,
     )
 
 
@@ -1197,10 +1353,17 @@ def _widen_state(state: MTState, doc_base: jnp.ndarray) -> MTState:
     S = state.tstart.shape[1]
     live = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1) < n[:, None]
     w["tstart"] = jnp.where(live, w["tstart"] + doc_base[:, None], 0)
+    def unmap(v):
+        v = v.astype(jnp.int32)
+        return jnp.where(v == int(I16_NOT_REMOVED), NOT_REMOVED, v)
+
     for f in SENTINEL_SEQ_FIELDS:
-        w[f] = jnp.where(w[f] == int(I16_NOT_REMOVED), NOT_REMOVED, w[f])
+        w[f] = unmap(w[f])
     return MTState(**w, props=state.props.astype(jnp.int32), n=n,
-                   overflow=state.overflow)
+                   overflow=state.overflow,
+                   remx_seq=tuple(unmap(v) for v in state.remx_seq),
+                   remx_client=tuple(v.astype(jnp.int32)
+                                     for v in state.remx_client))
 
 
 def _widen_ops(ops: MTOps, doc_base: jnp.ndarray) -> MTOps:
@@ -1244,7 +1407,7 @@ def replay_export(state: Optional[MTState], ops: MTOps, meta: dict,
     that ignore them."""
     from .pallas_fold import pallas_fold_mode
 
-    i16, ob_rows, ov_rows, i8, has_props = _export_flags(meta)
+    i16, ob_rows, ov_slots, i8, has_props = _export_flags(meta)
     mode = pallas_fold_mode()
     # The digest rebases tstart per doc even on non-i16 chunks, so an
     # unchanged document digests identically across repacks that moved
@@ -1260,26 +1423,30 @@ def replay_export(state: Optional[MTState], ops: MTOps, meta: dict,
     # shared dispatch/extraction derivation point).
     sequential = bool(meta.get("sequential")) and mode == ""
     if state is None:
-        return _export_cold_fn(int(S), i16, ob_rows, mode, ov_rows,
+        return _export_cold_fn(int(S), i16, ob_rows, mode, ov_slots,
                                i8, sequential, has_props,
                                digest=digest)(ops, doc_base)
     state = narrow_state_for_upload(state, meta)
-    return _export_warm_fn(i16, ob_rows, mode, ov_rows, i8,
+    return _export_warm_fn(i16, ob_rows, mode, ov_slots, i8,
                            sequential, has_props,
                            digest=digest)(state, ops, doc_base)
 
 
-def state_dict_from_export(export_np: np.ndarray) -> dict:
-    """Adapt a downloaded export buffer back to the state_np dict shape the
-    extraction/interval code consumes (zero-copy row views)."""
-    K = export_np.shape[1] - len(EXPORT_SLOT_FIELDS) - 1
+def state_dict_from_export(export_np: np.ndarray,
+                           ov_slots: int = 1) -> dict:
+    """Adapt a downloaded export buffer (canonical layout of a chunk with
+    ``ov_slots`` overlap slots) back to the state_np dict shape the
+    extraction/interval code consumes (zero-copy row views;
+    ``remx_seq``/``remx_client`` are ``[D, slots past the first, S]``)."""
+    n_extra = len(ov_extra_fields(ov_slots))
+    F = len(EXPORT_SLOT_FIELDS)
+    K = export_np.shape[1] - F - n_extra - 1
     out = {
         f: export_np[:, i, :] for i, f in enumerate(EXPORT_SLOT_FIELDS)
     }
-    out["props"] = np.moveaxis(
-        export_np[:, len(EXPORT_SLOT_FIELDS):len(EXPORT_SLOT_FIELDS) + K, :],
-        1, 2,
-    )
+    out["props"] = np.moveaxis(export_np[:, F:F + K, :], 1, 2)
+    out["remx_seq"] = export_np[:, F + K:F + K + n_extra:2, :]
+    out["remx_client"] = export_np[:, F + K + 1:F + K + n_extra:2, :]
     misc = export_np[:, -1, :]
     out["n"] = misc[:, 0]
     out["overflow"] = misc[:, 1]
@@ -1484,8 +1651,12 @@ def pack_mergetree_batch(docs: Sequence[MergeTreeDocInput]):
 
     doc_base = np.zeros((D,), np.int32)
     base_has_ob = False
-    base_has_ro = False
     base_max_tlen = 0
+    # Base records' overlap removers, written once the chunk's slot count
+    # is known: (doc, slot, client ids), and per doc the most removers
+    # (winner + "ro") any one record carries.
+    base_ro = []
+    base_removers = np.zeros((D,), np.int64)
     # One raw-pointer packer per chunk: base addresses captured once, no
     # per-doc ndarray marshalling (see native_pack.ChunkPacker).
     from .native_pack import chunk_packer, pack_doc_row
@@ -1519,15 +1690,9 @@ def pack_mergetree_batch(docs: Sequence[MergeTreeDocInput]):
             base_max_tlen = max(base_max_tlen, len(rec["t"]))
             ro = rec.get("ro", [])
             if ro:
-                base_has_ro = True
-                # Second-remover slot is exact for one overlap remover; the
-                # base summary doesn't carry overlap seqs, but any value
-                # below the base seq is faithful (it sequenced before every
-                # tail op).  >1 overlap removers → oracle fallback.
-                st["rem2_seq"][d, s] = doc.base_seq
-                st["rem2_client"][d, s] = pack.client_idx(ro[0])
-                if len(ro) > 1:
-                    pack.needs_fallback = True
+                base_ro.append((d, s, [pack.client_idx(c) for c in ro]))
+            base_removers[d] = max(int(base_removers[d]),
+                                   ("rs" in rec) + len(ro))
             for key, value in rec.get("p", {}).items():
                 st["props"][d, s, prop_keys.intern(key)] = values.intern(value)
         st["n"][d] = len(doc.base_records or [])
@@ -1599,13 +1764,29 @@ def pack_mergetree_batch(docs: Sequence[MergeTreeDocInput]):
         and len(values) < I8_LIMIT
         and max_clients < I8_LIMIT
     )
-    # Overlap-remover rows are live only if a second remover can occur:
-    # an op authored against a LAGGING view (ref_seq < seq-1 — an
-    # already-removed slot can still be visible to it), or a base record
-    # carrying overlap removers.  Fully sequential chunks elide them.
+    # A fully sequential chunk (every ref_seq == seq-1) never lets a
+    # remover see an already-removed slot.
     sequential = not bool(
         (real_ops & (op["ref_seq"] != op["seq"] - 1)).any()
     )
+    ov_slots = ov_slot_count(
+        tail_remover_counts(op["kind"], op["client"]), base_removers,
+        max((len(ids) for _d, _s, ids in base_ro), default=0), sequential,
+        ov_slot_cap())
+    st["remx_seq"] = tuple(np.full((D, S), NOT_REMOVED, np.int32)
+                           for _ in range(ov_slots - 1))
+    st["remx_client"] = tuple(np.full((D, S), -1, np.int32)
+                              for _ in range(ov_slots - 1))
+    for d, s, ids in base_ro:
+        # The base summary carries no overlap seqs, but any value below
+        # the base seq is faithful (it sequenced before every tail op).
+        if len(ids) > ov_slots:
+            doc_packs[d].needs_fallback = True  # past the slot cap
+            continue
+        seq = docs[d].base_seq
+        st["rem2_seq"][d, s], st["rem2_client"][d, s] = seq, ids[0]
+        for j, c in enumerate(ids[1:]):
+            st["remx_seq"][j][d, s], st["remx_client"][j][d, s] = seq, c
     meta = {
         "doc_packs": doc_packs,
         "prop_keys": list(prop_keys.values),
@@ -1621,7 +1802,9 @@ def pack_mergetree_batch(docs: Sequence[MergeTreeDocInput]):
         # (a pack-time fact: an obliterate op anywhere — including C++-
         # filled binary rows, which land in op["kind"] — or a base stamp).
         "ob_rows": base_has_ob or bool((op["kind"] == K_OBLITERATE).any()),
-        "ov_rows": base_has_ro or not sequential,
+        # Overlap-remover slots (``ov_slot_count``): 0 elides the rem2
+        # rows and keeps the planes constant in the fold.
+        "ov_slots": ov_slots,
         # Props-free chunk (no annotate ops, no base props — the interner
         # saw no keys from ANY source): the plane stays constant, the
         # per-op plane shift traces away.
@@ -1684,9 +1867,11 @@ def _extract_records(meta, state_np: dict, d: int,
             rec["rc"] = pack.clients.lookup(rc) if rc >= 0 else None
         if stamps:
             rec["ob"] = stamps
-        rc2 = int(state_np["rem2_client"][d, s])
-        if rc2 >= 0:
-            rec["ro"] = [pack.clients.lookup(rc2)]
+        ro = [int(state_np["rem2_client"][d, s])]
+        ro += [int(c) for c in state_np["remx_client"][d, :, s]]
+        ro = sorted(pack.clients.lookup(c) for c in ro if c >= 0)
+        if ro:
+            rec["ro"] = ro
         props = {}
         for k, key in enumerate(prop_keys):
             vid = int(state_np["props"][d, s, k])
@@ -1740,15 +1925,16 @@ def known_oracle_fallback(doc: MergeTreeDocInput) -> bool:
 
 def _known_oracle_fallback_uncached(doc: MergeTreeDocInput) -> bool:
     """True when a doc is known *before packing* to need the oracle path:
-    >1 overlap remover on a base record (the device tracks exactly two
-    removers and the base format carries no overlap seqs), >2 obliterate
-    stamps on a base record (two device stamp slots), or interval ops
-    mixed with obliterate ops (reference-slide timing over obliterated
-    segments is host-folded only through the oracle).  Pack-time's
-    ``needs_fallback`` applies the same rules; filtering first keeps such
-    docs from inflating the shared power-of-two buckets."""
+    more overlap removers on a base record than the device has slots
+    (``ov_slot_cap``), >2 obliterate stamps on a base record (two device
+    stamp slots), or interval ops mixed with obliterate ops
+    (reference-slide timing over obliterated segments is host-folded only
+    through the oracle).  Pack-time's ``needs_fallback`` applies the same
+    rules; filtering first keeps such docs from inflating the shared
+    power-of-two buckets."""
+    cap = ov_slot_cap()
     for r in doc.base_records or []:
-        if len(r.get("ro", [])) > 1 or len(r.get("ob", [])) > 2:
+        if len(r.get("ro", [])) > cap or len(r.get("ob", [])) > 2:
             return True
     has_interval = doc.base_intervals is not None
     has_obl = False
@@ -1769,8 +1955,9 @@ def _known_oracle_fallback_uncached(doc: MergeTreeDocInput) -> bool:
 
 def oracle_fallback_summary(doc: MergeTreeDocInput) -> SummaryTree:
     """Full oracle replay of one document — the exactness escape hatch for
-    the rare shapes the device path flags (>2 overlap removers on one
-    segment, or a base summary with >1)."""
+    the rare shapes the device path flags (more concurrent removers of one
+    segment than the chunk's overlap slots, or a base record with more
+    overlap removers than the slot cap)."""
     from ..dds.sequence import SharedString
 
     replica = SharedString(doc.doc_id)
@@ -1808,32 +1995,41 @@ def summaries_from_export(meta, export_np: np.ndarray,
     liboppack is available, else the per-slot Python extraction; interval
     blobs and oracle-fallback docs take the host paths either way.
     ``stats`` (optional dict) accumulates ``device_docs`` /
-    ``fallback_docs`` counters — the true device-vs-oracle split;
-    ``stage`` (optional dict) the seconds of the chunk's oracle folds
-    under ``fallback``."""
+    ``fallback_docs`` counters — the true device-vs-oracle split, with
+    the reason of each fallback (``fallback_pack``: flagged at pack time;
+    ``fallback_overflow``: a segment had more concurrent removers than
+    the chunk's overlap slots) — and ``ov_slots_<n>``, the chunks folded
+    with n overlap slots; ``stage`` (optional dict) the seconds of the
+    chunk's oracle folds under ``fallback``."""
+    from .batching import count_fallback
     from .interval_replay import FinalStateView, replay_intervals
     from .native_pack import extract_bodies
 
     docs = meta["docs"]
     D = len(docs)
-    _i16, ob_rows_f, ov_rows_f, i8_f, props_rows_f = _export_flags(meta)
+    _i16, ob_rows_f, ov_slots, i8_f, props_rows_f = _export_flags(meta)
     widened = widen_export_native(
-        export_np, meta.get("doc_base"), ob_rows_f, ov_rows_f, i8_f,
+        export_np, meta.get("doc_base"), ob_rows_f, ov_slots, i8_f,
         meta.get("props_K"), props_rows_f)
     export_np = widened if widened is not None else widen_export(
         export_np, meta.get("doc_base"),
-        ob_rows=ob_rows_f, ov_rows=ov_rows_f,
+        ob_rows=ob_rows_f, ov_slots=ov_slots,
         i8=i8_f, n_props=meta.get("props_K"),
         props_rows=props_rows_f)
-    state_np = state_dict_from_export(export_np)
+    state_np = state_dict_from_export(export_np, ov_slots)
     skip = np.zeros(D, np.uint8)
     for d in range(D):
-        if meta["doc_packs"][d].needs_fallback or state_np["overflow"][d]:
+        if meta["doc_packs"][d].needs_fallback:
             skip[d] = 1
+            count_fallback(stats, "pack")
+        elif state_np["overflow"][d]:
+            skip[d] = 1
+            count_fallback(stats, "overflow")
     if stats is not None:
         n_skip = int(skip.sum())
-        stats["fallback_docs"] = stats.get("fallback_docs", 0) + n_skip
         stats["device_docs"] = stats.get("device_docs", 0) + D - n_skip
+        key = f"ov_slots_{ov_slots}"
+        stats[key] = stats.get(key, 0) + 1
     msn = np.asarray([doc.final_msn for doc in docs], np.int32)
     arena_text = meta["arena"].finalize()
     # Attribution docs take the Python record path below (their key blob
@@ -1849,6 +2045,7 @@ def summaries_from_export(meta, export_np: np.ndarray,
         [list(meta["doc_packs"][d].clients.values) for d in range(D)],
         meta["prop_keys"], list(meta["values"].values),
         msn, body_skip, int(NOT_REMOVED),
+        ov_extra=max(ov_slots - 1, 0),
     )
     out: List[Optional[SummaryTree]] = []
     live_len = state_np["live_len"]

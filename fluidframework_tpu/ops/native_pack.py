@@ -212,10 +212,12 @@ def load_library() -> Optional[ctypes.CDLL]:
     lib.oppack_extract.argtypes = [
         np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),  # export
         ctypes.c_int32, ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+        ctypes.c_int32,                                    # extra slots
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,   # arena
         ctypes.c_char_p,                                   # client_json
         np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
         np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+        ctypes.c_void_p,                                   # client_rank
         ctypes.c_char_p,                                   # key_json
         np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
         np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
@@ -468,12 +470,15 @@ def extract_bodies(
     msn: np.ndarray,
     skip: np.ndarray,
     not_removed: int,
+    ov_extra: int = 0,
 ) -> Optional[List[bytes]]:
     """Canonical summary-body JSON bytes for every doc of a chunk, via the
     C++ extractor; None when the native library is unavailable (callers
     fall back to the per-slot Python extraction).
 
-    ``export_np``: the fused [D, F, S] int32 export buffer;
+    ``export_np``: the fused [D, F, S] int32 export buffer, with
+    ``ov_extra`` overlap slots past the first (a row pair each after the
+    property rows);
     ``doc_clients``: per-doc client-id tables in intern order;
     ``prop_keys`` / ``values``: the chunk-global intern tables;
     ``msn`` int32[D]; ``skip`` uint8[D] flags oracle-fallback docs."""
@@ -483,7 +488,7 @@ def extract_bodies(
     if lib is None:
         return None
     D, F, S = export_np.shape
-    K = F - 13
+    K = F - 13 - 2 * ov_extra
     export_np = np.ascontiguousarray(export_np, np.int32)
 
     def flatten(tokens: Sequence[bytes]):
@@ -504,10 +509,20 @@ def extract_bodies(
 
     client_tokens: List[bytes] = []
     doc_start = np.zeros(D + 1, np.int32)
+    ranks: List[int] = []
     for d, clients in enumerate(doc_clients):
         client_tokens.extend(json_str(c) for c in clients)
         doc_start[d + 1] = len(client_tokens)
+        if ov_extra:
+            # Each client's place in the doc's sorted names: the order
+            # the oracle lists overlap removers in.
+            by_name = sorted(range(len(clients)), key=clients.__getitem__)
+            rank = [0] * len(clients)
+            for r, i in enumerate(by_name):
+                rank[i] = r
+            ranks.extend(rank)
     client_blob, client_offs = flatten(client_tokens)
+    client_rank = np.asarray(ranks, np.int32)
 
     order = sorted(range(len(prop_keys)), key=lambda i: prop_keys[i])
     key_cols = np.asarray(order, np.int32) if order else \
@@ -539,9 +554,10 @@ def extract_bodies(
     for _attempt in range(3):
         out = np.empty(cap, np.uint8)  # C++ writes [0, out_offs[D])
         rc = lib.oppack_extract(
-            export_np, D, F, S, K,
+            export_np, D, F, S, K, ov_extra,
             arena_bytes, len(arena_bytes), len(arena_text),
             client_blob, client_offs, doc_start,
+            client_rank.ctypes.data if ov_extra else None,
             key_blob, key_offs, key_cols,
             val_blob, val_offs, len(values),
             msn, skip, not_removed,

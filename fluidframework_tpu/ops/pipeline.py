@@ -41,6 +41,7 @@ import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from ..utils.telemetry import span
@@ -64,10 +65,13 @@ from .mergetree_kernel import (
     narrow_ops_for_upload,
     narrow_state_for_upload,
     oracle_fallback_summary,
+    ov_slot_cap,
+    ov_slot_count,
     pack_mergetree_batch,
     replay_export,
     split_export_digest,
     summaries_from_export,
+    tail_remover_counts,
 )
 
 
@@ -390,8 +394,8 @@ def _extend_mergetree(entry: _PackEntry, chunk):
         doc_packs=doc_packs,
         prop_keys=sorted(key_ids, key=key_ids.__getitem__),
     )
-    _refresh_mergetree_facts(entry.state, op, new_meta, chunk)
-    return entry.state, MTOps(**op), new_meta
+    state = _refresh_mergetree_facts(entry.state, op, new_meta, chunk)
+    return state, MTOps(**op), new_meta
 
 
 def _fill_mergetree_suffixes(chunk, suffixes, entry, op, arena, values,
@@ -407,11 +411,14 @@ def _fill_mergetree_suffixes(chunk, suffixes, entry, op, arena, values,
                               pack, arena, key_ids.__getitem__, values)
 
 
-def _refresh_mergetree_facts(state, op, meta, chunk) -> None:
+def _refresh_mergetree_facts(state, op, meta, chunk):
     """Re-derive the chunk facts over the COMBINED arrays — same
     predicates as ``pack_mergetree_batch``, except the i16 text bound
     checks the actual per-doc rebased span ends (suffix text is not
-    contiguous with the doc's original arena span)."""
+    contiguous with the doc's original arena span).  Returns the base
+    state, given as many overlap planes as the combined chunk's
+    ``ov_slot_count`` (a new removing client can raise it, a suffix never
+    lowers it; the cached state itself is never written)."""
     doc_base = np.asarray(meta["doc_base"], np.int32)
     S = int(meta["_S"])
     is_ins = op["kind"] == K_INSERT
@@ -457,10 +464,26 @@ def _refresh_mergetree_facts(state, op, meta, chunk) -> None:
         (np.asarray(state.ob1_seq) != NOT_REMOVED).any()
         or (op["kind"] == K_OBLITERATE).any()
     )
-    meta["ov_rows"] = bool(
-        (np.asarray(state.rem2_client) >= 0).any()
-    ) or not sequential
+    # The base removers per record, read back off the base planes: the
+    # winner plus every occupied overlap slot.
+    overlap = (np.asarray(state.rem2_client) >= 0).astype(np.int64) + sum(
+        np.asarray(p) != NOT_REMOVED for p in state.remx_seq)
+    base = (np.asarray(state.rem_seq) != NOT_REMOVED) + overlap
+    ov_slots = ov_slot_count(
+        tail_remover_counts(op["kind"], op["client"]),
+        base.max(axis=1, initial=0), int(overlap.max(initial=0)),
+        sequential, ov_slot_cap())
+    meta["ov_slots"] = ov_slots
     meta["has_props"] = len(meta["prop_keys"]) > 0
+    extra = ov_slots - 1 - len(state.remx_seq)
+    if extra <= 0:
+        return state
+    D, S = np.shape(state.rem2_seq)
+    return state._replace(
+        remx_seq=tuple(state.remx_seq) + tuple(
+            np.full((D, S), NOT_REMOVED, np.int32) for _ in range(extra)),
+        remx_client=tuple(state.remx_client) + tuple(
+            np.full((D, S), -1, np.int32) for _ in range(extra)))
 
 
 # -- tier-0 delta-download routing: ONE derivation point --------------------
@@ -658,7 +681,7 @@ def _np_nbytes(tree) -> int:
     pass through and cost nothing)."""
     if tree is None:
         return 0
-    return int(sum(leaf.nbytes for leaf in tree
+    return int(sum(leaf.nbytes for leaf in jax.tree.leaves(tree)
                    if isinstance(leaf, np.ndarray)))
 
 
@@ -961,11 +984,11 @@ def _mt_dispatch(state, ops, meta, digest: bool, aux_dev):
 def _mt_dispatch_sharded(mesh, state, ops, meta, digest: bool, aux_dev):
     from ..parallel.shard import sharded_export_step
 
-    i16, ob_rows, ov_rows, i8, has_props = _export_flags(meta)
+    i16, ob_rows, ov_slots, i8, has_props = _export_flags(meta)
     sequential = bool(meta.get("sequential"))
     warm = state is not None
     step = sharded_export_step(mesh, int(meta["_S"]), i16, ob_rows,
-                               ov_rows, i8, sequential, has_props, warm,
+                               ov_slots, i8, sequential, has_props, warm,
                                digest=digest)
     return step(state, ops, aux_dev) if warm else step(ops, aux_dev)
 

@@ -128,7 +128,7 @@ def _docs_per_device(export, n_padded: int, n_real: int) -> dict:
 
 
 def sharded_export_step(mesh: Mesh, S: int, i16: bool, ob_rows: bool,
-                        ov_rows: bool, i8: bool, sequential: bool,
+                        ov_slots: int, i8: bool, sequential: bool,
                         has_props: bool, warm: bool,
                         digest: bool = False):
     """Mesh-sharded fold+EXPORT: the SAME cached builders as the
@@ -146,10 +146,10 @@ def sharded_export_step(mesh: Mesh, S: int, i16: bool, ob_rows: bool,
     its shard."""
     shard = NamedSharding(mesh, _doc_spec(mesh))
     if warm:
-        return _export_warm_fn(i16, ob_rows, "", ov_rows, i8, sequential,
+        return _export_warm_fn(i16, ob_rows, "", ov_slots, i8, sequential,
                                has_props, out_sharding=shard,
                                digest=digest)
-    return _export_cold_fn(S, i16, ob_rows, "", ov_rows, i8, sequential,
+    return _export_cold_fn(S, i16, ob_rows, "", ov_slots, i8, sequential,
                            has_props, out_sharding=shard, digest=digest)
 
 

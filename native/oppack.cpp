@@ -37,12 +37,17 @@
 // control chars escape, matching python json.dumps).  Slot rows carry two
 // obliterate stamp pairs (rows 8..11); in-window stamps (> msn) emit as
 // "ob":[[seq,"client"],...] and pin their tombstones past normal expiry.
+// Overlap removers (row 7, plus one row pair per further slot after the
+// property rows) emit as "ro":[...] in the oracle's sorted name order.
 
 #include <cstdint>
 #include <cstring>
 
 namespace {
 constexpr int64_t kHeader = 1 + 4 * 8;  // kind byte + 8 i32 fields
+// Overlap slots past the first that an export may carry (the kernel's
+// OV_SLOT_CAP less one).
+constexpr int32_t kMaxExtraRemovers = 7;
 
 inline int64_t count_codepoints(const uint8_t* p, int64_t n) {
     int64_t chars = 0;
@@ -160,18 +165,22 @@ int32_t oppack_pack(const uint8_t* buf, int64_t len,
 
 // Final device state → canonical summary-body JSON for every document of a
 // chunk, in one pass.  Layout contract with mergetree_kernel._export_state:
-//   export_buf: [D, F, S] int32, C order, F = 12 + K + 1
+//   export_buf: [D, F, S] int32, C order, F = 12 + K + 2X + 1
 //     rows 0..7: tstart, tlen, ins_seq, ins_client,
 //                rem_seq, rem_client, rem2_seq, rem2_client
 //     rows 8..11: ob1_seq, ob1_client, ob2_seq, ob2_client
 //     rows 12..12+K-1: property value ids (PROP_ABSENT = -1)
-//     row  12+K (misc): [n, overflow, live_len, 0...]
+//     rows 12+K..12+K+2X-1: (seq, client) of overlap slots 2..X+1
+//     row  12+K+2X (misc): [n, overflow, live_len, 0...]
 //   arena_utf8: the chunk text arena; tstart/tlen are CHAR offsets, so a
 //     char→byte index is built once here.
 //   client_json / key_json / val_json: pre-serialized JSON tokens
 //     (canonical_json of each client name / property key / value),
 //     flattened with offset tables.  clients are per-doc
 //     (client_doc_start[d] .. client_doc_start[d+1] index the offs table);
+//     client_rank (parallel to the tokens; may be null when X == 0) is
+//     each client's rank in its document's sorted name order, the order
+//     the oracle lists overlap removers in;
 //     keys arrive in SORTED key order with key_cols[k] = the export row of
 //     the k-th sorted key.
 //   msn / final over per doc: msn drives tombstone expiry + seq clamping.
@@ -183,16 +192,20 @@ int32_t oppack_pack(const uint8_t* buf, int64_t len,
 //   use -(need)-2).
 int64_t oppack_extract(
     const int32_t* export_buf, int32_t D, int32_t F, int32_t S, int32_t K,
+    int32_t X,
     const uint8_t* arena_utf8, int64_t arena_bytes_len, int64_t arena_chars,
     const uint8_t* client_json, const int64_t* client_offs,
-    const int32_t* client_doc_start,
+    const int32_t* client_doc_start, const int32_t* client_rank,
     const uint8_t* key_json, const int64_t* key_offs,
     const int32_t* key_cols,
     const uint8_t* val_json, const int64_t* val_offs, int32_t n_vals,
     const int32_t* msn, const uint8_t* skip,
     int32_t not_removed,
     uint8_t* out, int64_t out_cap, int64_t* out_offs) {
-    if (F != 12 + K + 1) return -1;
+    if (X < 0 || X > kMaxExtraRemovers || F != 12 + K + 2 * X + 1 ||
+        (X > 0 && client_rank == nullptr)) {
+        return -1;
+    }
     // char → byte index over the arena (one pass).
     int64_t* idx = new int64_t[arena_chars + 1];
     {
@@ -281,7 +294,7 @@ int64_t oppack_extract(
         const int32_t* p_ob1_client = ex + 9 * S;
         const int32_t* p_ob2_seq = ex + 10 * S;
         const int32_t* p_ob2_client = ex + 11 * S;
-        const int32_t n = ex[static_cast<int64_t>(12 + K) * S + 0];
+        const int32_t n = ex[static_cast<int64_t>(12 + K + 2 * X) * S + 0];
         const int32_t doc_msn = msn[d];
         if (n < 0 || n > S) { bad = true; break; }
 
@@ -292,6 +305,34 @@ int64_t oppack_extract(
             if (p_ob1_seq[s] != not_removed && p_ob1_seq[s] > doc_msn) ++count;
             if (p_ob2_seq[s] != not_removed && p_ob2_seq[s] > doc_msn) ++count;
             return count;
+        };
+        // The overlap removers of slot s (client ids), in sorted name
+        // order; returns how many.
+        const int32_t ndoc_clients =
+            client_doc_start[d + 1] - client_doc_start[d];
+        auto removers = [&](int32_t s, int32_t* out) {
+            int32_t m = 0;
+            if (p_rem2_client[s] >= 0) out[m++] = p_rem2_client[s];
+            for (int32_t j = 0; j < X; ++j) {
+                const int32_t c =
+                    ex[(12 + K + 2 * static_cast<int64_t>(j) + 1) * S + s];
+                if (c >= 0) out[m++] = c;
+            }
+            for (int32_t i = 0; i < m; ++i) {
+                if (out[i] >= ndoc_clients) { bad = true; return 0; }
+            }
+            for (int32_t i = 1; i < m; ++i) {  // insertion sort by rank
+                const int32_t c = out[i];
+                const int32_t r = client_rank[client_doc_start[d] + c];
+                int32_t k = i - 1;
+                while (k >= 0 &&
+                       client_rank[client_doc_start[d] + out[k]] > r) {
+                    out[k + 1] = out[k];
+                    --k;
+                }
+                out[k + 1] = c;
+            }
+            return m;
         };
         auto expired = [&](int32_t s) {
             const int32_t rs = p_rem_seq[s];
@@ -319,7 +360,18 @@ int64_t oppack_extract(
                        p_rem_client[x] != p_rem_client[y])) {
                 return false;
             }
-            if (p_rem2_client[x] != p_rem2_client[y]) return false;
+            if (X == 0) {
+                if (p_rem2_client[x] != p_rem2_client[y]) return false;
+            } else {
+                int32_t rx_ids[kMaxExtraRemovers + 1];
+                int32_t ry_ids[kMaxExtraRemovers + 1];
+                const int32_t mx = removers(x, rx_ids);
+                const int32_t my = removers(y, ry_ids);
+                if (mx != my) return false;
+                for (int32_t i = 0; i < mx; ++i) {
+                    if (rx_ids[i] != ry_ids[i]) return false;
+                }
+            }
             // in-window stamp lists must match
             const bool o1x = p_ob1_seq[x] != not_removed &&
                              p_ob1_seq[x] > doc_msn;
@@ -416,10 +468,17 @@ int64_t oppack_extract(
                 if (p_rem_client[s] < 0) put_lit("null");
                 else put_client(d, p_rem_client[s]);
             }
-            if (p_rem2_client[s] >= 0) {
-                put_lit(",\"ro\":[");
-                put_client(d, p_rem2_client[s]);
-                put_lit("]");
+            {
+                int32_t ro_ids[kMaxExtraRemovers + 1];
+                const int32_t m = removers(s, ro_ids);
+                if (m > 0) {
+                    put_lit(",\"ro\":[");
+                    for (int32_t i = 0; i < m; ++i) {
+                        if (i) put_lit(",");
+                        put_client(d, ro_ids[i]);
+                    }
+                    put_lit("]");
+                }
             }
             if (removed) {
                 put_lit(",\"rs\":");

@@ -136,12 +136,14 @@ def test_beast_soak_oracle_vs_kernel():
         assert len(replica.text) > 200  # the soak built a real document
 
 
-def test_beast_soak_pallas_interpret():
+def test_beast_soak_pallas_interpret(monkeypatch):
     """The genuinely-concurrent log through the Pallas-interpret fold:
     byte-identical summaries vs the fresh oracle.  A shorter prefix than
     the scan soak — interpret mode runs the step loop in Python — but the
     SAME generator, so arrival kills / overlap removers / lagged
-    annotates all appear."""
+    annotates all appear.  Packed as when the Pallas fold serves (one
+    overlap slot)."""
+    monkeypatch.setenv("FF_PALLAS_FOLD", "interpret")
     import jax.numpy as jnp
 
     from fluidframework_tpu.ops.mergetree_kernel import (

@@ -281,6 +281,55 @@ def test_suffix_without_pack_lineage_full_uploads():
     assert st["spliced"] == 0 and st["misses"] == 2, st
 
 
+def test_tail_needing_more_overlap_slots_misses_the_resident_planes():
+    """A warm chunk's grown tail adds a concurrent remover to a base
+    record that already carries one, so the chunk needs more overlap
+    slots than its resident base planes have: tier 2 still extends, but
+    tier 2.5 must miss and upload the wider state — never splice the
+    suffix onto the narrower resident planes."""
+    import json
+
+    from fluidframework_tpu.dds import SharedString
+    from tests.test_mergetree_kernel import _ins, _rm, _seq_msgs
+
+    msgs = _seq_msgs([
+        ("c0", 0, _ins(0, "abcdefgh")),
+        ("zed", 1, _rm(2, 6)),
+        ("kim", 1, _rm(2, 6)),          # the base record's one "ro"
+        ("c0", 3, _ins(0, "Q")),        # sequential tail op
+        ("amy", 1, _rm(2, 6)),          # a lagged third remover
+    ])
+    base = SharedString("ov")
+    for msg in msgs[:3]:
+        base.process(msg, local=False)
+    summary = base.summarize()
+    records = json.loads(summary.blob_bytes("body"))
+
+    def window(n):
+        return MergeTreeDocInput(
+            doc_id="ov", ops=msgs[3:n], base_records=records,
+            base_seq=3, base_msn=0, final_seq=msgs[n - 1].seq, final_msn=0,
+            cache_token=("ep", "ov", 0, ""))
+
+    dev, pack = DevicePackCache(), PackCache()
+    short = [window(4)]
+    got, _, stats = _run(short, dev, pack, chunk_docs=1)
+    assert got == [s.digest() for s in replay_mergetree_batch(short)]
+    assert stats["ov_slots_1"] == 1
+    grown = [window(5)]
+    got, _, stats = _run(grown, dev, pack, chunk_docs=1)
+    oracle = SharedString("ov")
+    oracle.load(summary)
+    for msg in msgs[3:]:
+        oracle.process(msg, local=False)
+    oracle.advance(msgs[-1].seq, 0)
+    assert got == [oracle.summarize().digest()]
+    assert stats["ov_slots_2"] == 1 and stats.get("fallback_docs", 0) == 0
+    assert pack.stats()["suffix_hits"] == 1
+    st = dev.stats()
+    assert st["spliced"] == 0 and st["misses"] == 2, st
+
+
 def test_bypasses_binary_and_tokenless_chunks():
     dev = DevicePackCache()
     binary = [bench.synth_doc(i, 16) for i in range(4)]  # no tokens

@@ -218,9 +218,9 @@ def test_export_widths_agree_and_widen_roundtrips():
     from fluidframework_tpu.ops.mergetree_kernel import _export_flags
 
     _i, ob_f, ov_f, i8_f, props_f = _export_flags(meta)
-    w16 = widen_export(ex16, meta["doc_base"], ob_rows=ob_f, ov_rows=ov_f,
+    w16 = widen_export(ex16, meta["doc_base"], ob_rows=ob_f, ov_slots=ov_f,
                        i8=i8_f, n_props=meta["props_K"], props_rows=props_f)
-    w32 = widen_export(ex32, None, ob_rows=ob_f, ov_rows=ov_f,
+    w32 = widen_export(ex32, None, ob_rows=ob_f, ov_slots=ov_f,
                        n_props=meta["props_K"], props_rows=props_f)
     if i8_f:
         # Bit-equality holds for the slots extraction reads ([0, n) per
@@ -272,7 +272,7 @@ def test_obliterate_rows_elided_when_chunk_has_none():
 
     state, ops, meta = pack_mergetree_batch([plain])
     assert meta["ob_rows"] is False
-    assert meta["ov_rows"] is False  # sequential: rem2 rows elided too
+    assert meta["ov_slots"] == 0  # sequential: rem2 rows elided too
     from fluidframework_tpu.ops.mergetree_kernel import export_to_numpy
 
     assert meta["i8_ok"], "fixture must qualify for the i8 layout"
@@ -617,7 +617,7 @@ def test_string_tail_fold_equals_the_take_fold(tail_chunk, monkeypatch,
     kw = {}
     if facts == "served":
         kw = dict(sequential=bool(meta["sequential"]),
-                  has_ob=bool(meta["ob_rows"]), has_ov=bool(meta["ov_rows"]),
+                  has_ob=bool(meta["ob_rows"]), has_ov=meta["ov_slots"] > 0,
                   has_props=bool(meta["has_props"]))
     new = _fold(state, ops, kw)
     with monkeypatch.context() as patch:
@@ -674,3 +674,144 @@ def test_scan_step_without_a_shift_equals_the_take_step(tail_chunk,
     _assert_states_equal(new, old)
     if kind == "split-disabled":
         _assert_states_equal(new, mid)
+
+
+# -- overlapping removers: one slot each, up to the cap ----------------------
+
+
+def _seq_msgs(spec):
+    """Sequenced messages from ``(client, ref_seq, contents)`` rows, seqs
+    from 1, nothing below the window expiring (min_seq 0)."""
+    from fluidframework_tpu.protocol.messages import (
+        MessageType,
+        SequencedMessage,
+    )
+
+    return [SequencedMessage(seq=seq, client_id=client, client_seq=seq,
+                             ref_seq=ref, min_seq=0, type=MessageType.OP,
+                             contents=contents)
+            for seq, (client, ref, contents) in enumerate(spec, 1)]
+
+
+def _rm(start, end, kind="remove"):
+    return {"kind": kind, "start": start, "end": end}
+
+
+def _ins(pos, text):
+    return {"kind": "insert", "pos": pos, "text": text}
+
+
+#: remover names out of their arrival order, so the summary's sorted "ro"
+#: lists differ from the slot order (and "ann" < "ann!" although the JSON
+#: token '"ann"' sorts after '"ann!"')
+REMOVERS = ("zed", "ann!", "kim", "ann", "bob", "eve", "max", "lou",
+            "pat", "sam")
+
+
+def _overlap_spec(n_removers):
+    """Two inserts, then ``n_removers`` (>= 3) clients removing [2, 6)
+    from the same view: a winner, a remover that takes [2, 4) and later
+    [4, 6) (its own view) around the others' whole-range removes, so the
+    two halves hold the same removers in other slot orders, the last
+    whole-range remover obliterating when there are four or more; then
+    lagged inserts by a remover and by a bystander, an interval where no
+    obliterate is (the two together take the oracle), and a sequential
+    tail op."""
+    names = REMOVERS[:n_removers]
+    spec = [("c0", 0, _ins(0, "abcdefgh")), ("c0", 1, _ins(8, "XYZ")),
+            (names[0], 2, _rm(2, 6)), (names[1], 2, _rm(2, 4))]
+    for n in names[2:]:
+        obliterate = n_removers >= 4 and n == names[-1]
+        spec.append((n, 2, _rm(2, 6, "obliterate" if obliterate
+                              else "remove")))
+    spec.append((names[1], 2, _rm(2, 4)))  # [4, 6) in its own view
+    spec.append((names[1], 2, _ins(3, "r")))      # its view hides [2, 6)
+    spec.append((names[-1], 2, _ins(3, "q")))     # ...from a later slot
+    spec.append(("by", 2, _ins(7, "b")))           # sees [2, 6) present
+    if n_removers < 4:
+        spec.append(("by", 2, {"kind": "intervalAdd", "label": "default",
+                               "id": "iv", "start": 1, "end": 7}))
+    spec.append(("c0", len(spec), _rm(0, 1)))
+    return spec
+
+
+def _oracle_digest(msgs, base_summary=None, final_seq=None):
+    replica = SharedString("ov")
+    if base_summary is not None:
+        replica.load(base_summary)
+    for msg in msgs:
+        replica.process(msg, local=False)
+    if final_seq is not None:
+        replica.advance(final_seq, 0)
+    return replica.summarize().digest()
+
+
+def _overlap_case(case):
+    """(doc, oracle digest) for a parity case: ``cold-<n>`` n removers of
+    one segment from no base; ``warm-ro<k>`` a base summary whose record
+    carries k overlap removers, then a tail adding one more lagged
+    remover; ``past-cap`` more removers than the winner plus
+    ``OV_SLOT_CAP``."""
+    from fluidframework_tpu.ops.mergetree_kernel import OV_SLOT_CAP
+
+    kind, _, arg = case.partition("-")
+    if kind == "past":
+        msgs = _seq_msgs([("c0", 0, _ins(0, "abcdefgh"))] + [
+            (f"r{k}", 1, _rm(2, 6)) for k in range(OV_SLOT_CAP + 2)])
+    else:
+        msgs = _seq_msgs(_overlap_spec(int(arg.lstrip("ro")) + (
+            2 if kind == "warm" else 0)))
+    final = msgs[-1].seq
+    if kind != "warm":
+        doc = MergeTreeDocInput(doc_id="ov", ops=msgs, final_seq=final,
+                                final_msn=0)
+        return doc, _oracle_digest(msgs)
+    # The base: the two inserts and the winner plus k overlap removers
+    # (the first removers' rows, all before the lagged tail).
+    n_ro = int(arg[2:])
+    base_seq = 2 + 1 + n_ro
+    partial = SharedString("ov")
+    for msg in msgs[:base_seq]:
+        partial.process(msg, local=False)
+    base = partial.summarize()
+    records = json.loads(base.blob_bytes("body"))
+    assert max(len(r.get("ro", [])) for r in records) == n_ro
+    tail = msgs[base_seq:]
+    doc = MergeTreeDocInput(doc_id="ov", ops=tail, base_records=records,
+                            final_seq=final, final_msn=0,
+                            base_seq=base_seq, base_msn=0,
+                            base_intervals=None)
+    expected = _oracle_digest(tail, base, final)
+    assert expected == _oracle_digest(msgs, final_seq=final)
+    return doc, expected
+
+
+@pytest.mark.parametrize("extractor", ["native", "python"])
+@pytest.mark.parametrize("case", ["cold-3", "cold-4", "cold-5", "warm-ro1",
+                                  "warm-ro2", "warm-ro3", "past-cap"])
+def test_overlapping_removers_fold_on_the_device(case, extractor,
+                                                 monkeypatch):
+    """Every concurrent remover of a segment keeps a slot on the device,
+    and the summary is byte-identical to the oracle's ("ro" sorted by
+    name, records merged by remover set).  Only past the slot cap does
+    the document still take the oracle, counted as an overflow."""
+    from fluidframework_tpu.ops import mergetree_kernel as mk
+    from fluidframework_tpu.ops import native_pack
+
+    if extractor == "python":
+        monkeypatch.setattr(native_pack, "extract_bodies",
+                            lambda *a, **k: None)
+        monkeypatch.setattr(mk, "widen_export_native",
+                            lambda *a, **k: None)
+    doc, expected = _overlap_case(case)
+    _state, _ops, meta = mk.pack_mergetree_batch([doc])
+    stats: dict = {}
+    [summary] = replay_mergetree_batch([doc], stats=stats)
+    assert summary.digest() == expected
+    if case == "past-cap":
+        assert meta["ov_slots"] == mk.OV_SLOT_CAP
+        assert stats["fallback_docs"] == stats["fallback_overflow"] == 1
+        return
+    assert meta["ov_slots"] >= 2
+    assert stats.get("fallback_docs", 0) == 0 and stats["device_docs"] == 1
+    assert stats[f"ov_slots_{meta['ov_slots']}"] == 1

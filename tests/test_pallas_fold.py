@@ -22,9 +22,30 @@ from fluidframework_tpu.ops.mergetree_kernel import (
 from fluidframework_tpu.ops.pallas_fold import replay_vmapped_pallas
 
 
+@pytest.fixture(autouse=True)
+def _packed_for_pallas(monkeypatch):
+    """Pack as when the Pallas fold serves: it keeps one overlap slot, so
+    under its mode the pack gives no chunk more (a third remover
+    overflows to the oracle in both folds)."""
+    monkeypatch.setenv("FF_PALLAS_FOLD", "interpret")
+
+
+def _planes(state):
+    """(name, plane) for every plane of a state, a tuple field's (the
+    overlap slots past the first) one by one."""
+    for field in state._fields:
+        v = getattr(state, field)
+        if isinstance(v, tuple):
+            yield from ((f"{field}[{i}]", x) for i, x in enumerate(v))
+        else:
+            yield field, v
+
+
 def _assert_states_equal(a, b, n_docs):
-    for field in a._fields:
-        av, bv = np.asarray(getattr(a, field)), np.asarray(getattr(b, field))
+    pa, pb = list(_planes(a)), list(_planes(b))
+    assert [f for f, _x in pa] == [f for f, _x in pb]
+    for (field, av), (_f, bv) in zip(pa, pb):
+        av, bv = np.asarray(av), np.asarray(bv)
         assert av.shape == bv.shape, field
         if field in ("n", "overflow"):
             np.testing.assert_array_equal(av, bv, err_msg=field)

@@ -241,14 +241,14 @@ def test_native_widen_beats_numpy_widen(packed_chunk, chunk_export):
     native = py = float("inf")
     for _ in range(2):  # warm both sides (allocator, library load)
         widen_export_native(ex, *args)
-        widen_export(ex, args[0], ob_rows=ob_f, ov_rows=ov_f, i8=i8_f,
+        widen_export(ex, args[0], ob_rows=ob_f, ov_slots=ov_f, i8=i8_f,
                      n_props=meta.get("props_K"), props_rows=props_f)
     for _ in range(5):
         t0 = time.time()
         assert widen_export_native(ex, *args) is not None
         native = min(native, time.time() - t0)
         t0 = time.time()
-        widen_export(ex, args[0], ob_rows=ob_f, ov_rows=ov_f, i8=i8_f,
+        widen_export(ex, args[0], ob_rows=ob_f, ov_slots=ov_f, i8=i8_f,
                      n_props=meta.get("props_K"), props_rows=props_f)
         py = min(py, time.time() - t0)
     assert native < py * 0.9, (

@@ -271,11 +271,14 @@ def test_span_count_does_not_grow_with_documents(stub_annotation):
 # -- host fallbacks -------------------------------------------------------------
 
 
-def _three_removers_doc(doc_id: str):
-    """One insert, then three clients removing the same text from the
-    same view: more overlapping removers than the device fold keeps, so
-    the extractor takes the oracle after the fold."""
-    from fluidframework_tpu.ops.mergetree_kernel import MergeTreeDocInput
+def _removers_past_cap_doc(doc_id: str):
+    """One insert, then more clients removing the same text from the same
+    view than the device fold has overlap slots for (the winner plus
+    ``OV_SLOT_CAP``), so the extractor takes the oracle after the fold."""
+    from fluidframework_tpu.ops.mergetree_kernel import (
+        OV_SLOT_CAP,
+        MergeTreeDocInput,
+    )
     from fluidframework_tpu.protocol.messages import (
         MessageType,
         SequencedMessage,
@@ -285,12 +288,12 @@ def _three_removers_doc(doc_id: str):
                             min_seq=0, type=MessageType.OP,
                             contents={"kind": "insert", "pos": 0,
                                       "text": "abcdef"})]
-    for k, client in enumerate(("c1", "c2", "c3")):
+    for k in range(OV_SLOT_CAP + 2):
         ops.append(SequencedMessage(
-            seq=2 + k, client_id=client, client_seq=1, ref_seq=1,
+            seq=2 + k, client_id=f"c{k + 1}", client_seq=1, ref_seq=1,
             min_seq=0, type=MessageType.OP,
             contents={"kind": "remove", "start": 1, "end": 4}))
-    return MergeTreeDocInput(doc_id=doc_id, ops=ops, final_seq=4,
+    return MergeTreeDocInput(doc_id=doc_id, ops=ops, final_seq=ops[-1].seq,
                              final_msn=0)
 
 
@@ -300,12 +303,13 @@ def test_post_fold_fallback_is_timed_in_stage():
     )
     from fluidframework_tpu.ops.pipeline import pipelined_mergetree_replay
 
-    docs = [_three_removers_doc("fb")] + [bench.synth_doc(i, 16)
-                                           for i in range(3)]
+    docs = [_removers_past_cap_doc("fb")] + [bench.synth_doc(i, 16)
+                                              for i in range(3)]
     stage: dict = {}
     stats: dict = {}
     out = pipelined_mergetree_replay(docs, stage=stage, stats=stats)
     assert stats["fallback_docs"] == 1
+    assert stats["fallback_overflow"] == 1  # the route's own counter
     assert stage["fallback"] > 0
     assert stage["extract"] >= stage["fallback"]  # counted inside it
     assert out[0].digest() == oracle_fallback_summary(docs[0]).digest()

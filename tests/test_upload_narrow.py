@@ -220,7 +220,7 @@ def test_native_widen_matches_python_widen_all_layouts():
                                      i8_f, meta.get("props_K"), props_f)
         assert native is not None, name
         py = widen_export(ex, meta.get("doc_base"), ob_rows=ob_f,
-                          ov_rows=ov_f, i8=i8_f,
+                          ov_slots=ov_f, i8=i8_f,
                           n_props=meta.get("props_K"), props_rows=props_f)
         np.testing.assert_array_equal(native, py, err_msg=name)
         assert native.dtype == py.dtype == np.int32

@@ -70,8 +70,8 @@ def _export_args(chunk):
     """The export builders' facts and the narrowed upload the pipeline
     dispatches (``replay_export``)."""
     state, ops, meta = chunk
-    i16, ob_rows, ov_rows, i8, has_props = _export_flags(meta)
-    facts = dict(i16=i16, ob_rows=ob_rows, ov_rows=ov_rows, i8=i8,
+    i16, ob_rows, ov_slots, i8, has_props = _export_flags(meta)
+    facts = dict(i16=i16, ob_rows=ob_rows, ov_slots=ov_slots, i8=i8,
                  sequential=bool(meta.get("sequential")),
                  has_props=has_props)
     return (facts, narrow_state_for_upload(state, meta),
@@ -97,24 +97,39 @@ def test_scan_fold_compiles(one_chip, mt_chunk):
 @pytest.fixture(scope="module")
 def export_compiled(one_chip, mt_chunk):
     """The single-chip fold+export of the bench chunk, compiled once per
-    start (cold or warm) for the tests that read it."""
+    start (cold or warm) and overlap slot count for the tests that read
+    it.  ``ov_slots`` None is the chunk's own count (0: its views are
+    sequential); a count of 1 or more compiles the chunk's shapes as a
+    concurrent chunk with that many overlap slots, the warm state
+    carrying a (seq, client) plane pair per slot past the first, narrowed
+    like rem2."""
     done = {}
 
-    def compiled(warm):
-        if warm in done:
-            return done[warm]
+    def compiled(warm, ov_slots=None):
+        key = (warm, ov_slots)
+        if key in done:
+            return done[key]
         facts, state_n, ops_n, doc_base, S = _export_args(mt_chunk)
+        sequential = facts["sequential"]
+        if ov_slots is None:
+            ov_slots = facts["ov_slots"]
+        else:
+            sequential = False
+            extra = max(ov_slots - 1, 0)
+            state_n = state_n._replace(
+                remx_seq=(np.asarray(state_n.rem2_seq),) * extra,
+                remx_client=(np.asarray(state_n.rem2_client),) * extra)
         args = [_specs(ops_n, one_chip), _specs(doc_base, one_chip)]
-        flags = (facts["i16"], facts["ob_rows"], "", facts["ov_rows"],
-                 facts["i8"], facts["sequential"], facts["has_props"])
+        flags = (facts["i16"], facts["ob_rows"], "", ov_slots,
+                 facts["i8"], sequential, facts["has_props"])
         if warm:
             fn = _export_warm_fn(*flags, out_sharding=one_chip, digest=True)
             args.insert(0, _specs(state_n, one_chip))
         else:
             fn = _export_cold_fn(S, *flags, out_sharding=one_chip,
                                  digest=True)
-        done[warm] = fn.lower(*args).compile()
-        return done[warm]
+        done[key] = fn.lower(*args).compile()
+        return done[key]
 
     return compiled
 
@@ -158,17 +173,88 @@ def _while_body_lines(hlo: str) -> list:
     return [line for name in seen for line in comps[name]]
 
 
-@pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
-def test_scan_body_has_no_gather(export_compiled, warm):
+@pytest.mark.parametrize("warm,ov_slots", [(False, None), (True, None),
+                                           (False, 2), (True, 2)],
+                         ids=["cold", "warm", "cold-ov2", "warm-ov2"])
+def test_scan_body_has_no_gather(export_compiled, warm, ov_slots):
     """The merge-tree scan moves its segment pool by a one-slot roll and a
     select.  A per-document ``take`` there compiles on the v5e to a
     general batched gather of the whole [docs, slots] plane, about ten
-    times an elementwise pass, once per plane and scan step."""
-    body = _while_body_lines(export_compiled(warm).as_text())
+    times an elementwise pass, once per plane and scan step.  That holds
+    for the overlap slots past the first too."""
+    body = _while_body_lines(export_compiled(warm, ov_slots).as_text())
     assert body, "no while loop in the compiled fold"
     gathers = [line.strip()[:160] for line in body
                if re.search(r"\sgather\(", line)]
     assert gathers == []
+
+
+@pytest.mark.parametrize("ov_slots", [None, 1, 2])
+def test_warm_program_takes_a_plane_per_extra_overlap_slot(
+        export_compiled, ov_slots):
+    """A chunk with at most one overlap slot uploads the same twelve slot
+    planes, props, n and overflow as before slots were counted (no
+    ``remx_*`` leaf in the compiled program's arguments); each slot past
+    the first adds a (seq, client) plane pair."""
+    state_args = export_compiled(True, ov_slots).args_info[0][0]
+    extra = max((ov_slots or 0) - 1, 0)
+    assert len(jax.tree.leaves(state_args)) == 15 + 2 * extra
+    assert len(state_args.remx_seq) == len(state_args.remx_client) == extra
+
+
+@pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
+@pytest.mark.parametrize("ov_slots", [0, 1])
+def test_program_with_at_most_one_overlap_slot_is_the_flag_program(
+        one_chip, mt_chunk, warm, ov_slots):
+    """A chunk with at most one overlap slot lowers to the program a fold
+    with only the on/off overlap flag lowers to: a state with no
+    ``remx_*`` planes, the fold given ``has_ov = ov_slots > 0``, and the
+    export with rem2's rows or without.  The slot count adds no operand
+    and no operation there."""
+    from fluidframework_tpu.ops.mergetree_kernel import (
+        _cold_start,
+        _export_out,
+        _export_with_digest,
+        _fold_fn,
+        _widen_ops,
+        _widen_state,
+        program_name,
+    )
+
+    facts, state_n, ops_n, doc_base, S = _export_args(mt_chunk)
+    assert state_n.remx_seq == () and state_n.remx_client == ()
+    i16, ob, i8, props = (facts["i16"], facts["ob_rows"], facts["i8"],
+                          facts["has_props"])
+    sequential = facts["sequential"] and ov_slots == 0
+    flags = (i16, ob, "", ov_slots, i8, sequential, props)
+    fold = _fold_fn("", sequential, ob, props, ov_slots > 0)
+
+    def flag_program(*args):
+        *state, ops, base = args
+        with jax.named_scope("fold"):
+            if state:
+                start = _widen_state(state[0], base)
+            ops = _widen_ops(ops, base)
+            if not state:
+                start = _cold_start(ops, S, 0)
+            final = fold(start, ops)
+        return _export_with_digest(final, base, i16, ob, ov_slots, i8,
+                                   props, True)
+
+    start = "warm" if warm else "cold"
+    flag_program.__name__ = flag_program.__qualname__ = program_name(
+        "mergetree", True, start)
+    args = [_specs(ops_n, one_chip), _specs(doc_base, one_chip)]
+    if warm:
+        args.insert(0, _specs(state_n, one_chip))
+        program = _export_warm_fn(*flags, digest=True)
+    else:
+        program = _export_cold_fn(S, *flags, digest=True)
+    fmt = _export_out(i8, None, True)
+    flag_jit = jax.jit(flag_program) if fmt is None else \
+        jax.jit(flag_program, out_shardings=fmt)
+    assert program.lower(*args).as_text() == \
+        flag_jit.lower(*args).as_text()
 
 
 def _map_args(n_docs):
@@ -224,7 +310,7 @@ def test_sharded_export_step_compiles_on_four_chips(topo, one_chip,
     from fluidframework_tpu.parallel.shard import sharded_export_step
 
     facts, _state_n, ops_n, doc_base, S = _export_args(mt_chunk)
-    flags = (S, facts["i16"], facts["ob_rows"], facts["ov_rows"],
+    flags = (S, facts["i16"], facts["ob_rows"], facts["ov_slots"],
              facts["i8"], facts["sequential"], facts["has_props"])
     per_device = {}
     for n_chips in (1, 4):

@@ -86,9 +86,21 @@ def gate_mergetree_parity():
     final_scan = jax.jit(replay_vmapped)(state, ops)
     final_pallas = replay_vmapped_pallas(state, ops, interpret=True)
     n = np.asarray(final_scan.n)
-    for field in final_scan._fields:
-        av = np.asarray(getattr(final_scan, field))
-        bv = np.asarray(getattr(final_pallas, field))
+
+    def planes(st):
+        """(name, plane) per plane; a tuple field (the overlap slots
+        past the first) plane by plane."""
+        for field in st._fields:
+            v = getattr(st, field)
+            if isinstance(v, tuple):
+                yield from ((f"{field}[{i}]", x) for i, x in enumerate(v))
+            else:
+                yield field, v
+
+    pa, pb = list(planes(final_scan)), list(planes(final_pallas))
+    assert [f for f, _x in pa] == [f for f, _x in pb]
+    for (field, av), (_f, bv) in zip(pa, pb):
+        av, bv = np.asarray(av), np.asarray(bv)
         assert av.shape == bv.shape, field
         if field in ("n", "overflow"):
             assert np.array_equal(av, bv), field
