@@ -33,10 +33,8 @@ from __future__ import annotations
 
 import json
 import os
-import queue
 import random
 import sys
-import threading
 import time
 
 import jax
@@ -46,11 +44,9 @@ from fluidframework_tpu.dds.sequence import SharedString
 from fluidframework_tpu.ops.interning import Interner
 from fluidframework_tpu.ops.mergetree_kernel import (
     MergeTreeDocInput,
-    export_to_numpy,
     pack_mergetree_batch,
     replay_export,
     replay_mergetree_batch,
-    summaries_from_export,
 )
 from fluidframework_tpu.ops.native_pack import (
     decode_string_ops,
@@ -515,36 +511,14 @@ def run_e2e(docs):
     """Pipelined end-to-end: returns
     (summaries, stats, stage_times, wall, packed_chunks).
 
+    Runs the PRODUCT pipeline (fluidframework_tpu.ops.pipeline) with the
+    bench's instrumentation hooks attached — the harness measures the
+    same code the catch-up service runs, not a private copy of it.
     Stage times are per-stage BUSY seconds (they overlap); ``wall`` is the
     honest end-to-end wall-clock the throughput number uses.
     ``packed_chunks`` [(state_or_None, ops, meta, S)] lets the
     steady-fold section reuse the pack work (warm chunks keep their base
-    state so the re-timed fold runs the e2e's own executable).
-
-    Two pipeline shapes, selected by ``BENCH_E2E_PIPELINE``:
-
-    - ``single-device-thread`` (default): ALL device interaction —
-      dispatch, async copy-to-host, blocking fetch — happens on the
-      calling thread; worker pools only pack (C++, GIL-released) and
-      extract (ditto).  Round 5's chip run showed the legacy shape's
-      concurrent dispatch (packer thread) + blocking ``np.asarray``
-      (downloader thread) leaving the device client persistently slow
-      (~3.66 s/chunk on a fold a clean process runs in ~0.2 ms; exec
-      latency 70–90 ms afterwards).  Overlap is preserved without a second device
-      thread: dispatch is async, ``copy_to_host_async`` starts the d2h
-      transfer at dispatch time, and the blocking fetch trails
-      ``BENCH_FETCH_DEPTH`` chunks behind the dispatch front.
-    - ``legacy``: the round-2..4 three-thread shape (packer dispatches,
-      downloader fetches concurrently), kept for hardware A/B."""
-    if os.environ.get("BENCH_E2E_PIPELINE", "").lower() == "legacy":
-        return _run_e2e_legacy(docs)
-    return _run_e2e_single_device_thread(docs)
-
-
-def _run_e2e_single_device_thread(docs):
-    """The PRODUCT pipeline (fluidframework_tpu.ops.pipeline) with the
-    bench's instrumentation hooks attached — the harness measures the
-    same code the catch-up service runs, not a private copy of it."""
+    state so the re-timed fold runs the e2e's own executable)."""
     from fluidframework_tpu.ops.pipeline import pipelined_mergetree_replay
 
     stage = {"pack": 0.0, "dispatch": 0.0, "upload": 0.0,
@@ -564,190 +538,6 @@ def _run_e2e_single_device_thread(docs):
         stage=stage,
         packed_out=packed_chunks,
     )
-    return summaries, stats, stage, time.time() - wall0, packed_chunks
-
-
-def _run_e2e_legacy(docs):
-    """The round-2..4 three-thread pipeline (packer thread dispatches,
-    downloader thread fetches concurrently) — kept for hardware A/B
-    against the single-device-thread default.  A failure in any stage
-    sets ``abort`` so the other stages unblock from their bounded queues
-    and the first error re-raises in the caller instead of
-    deadlocking."""
-    stage = {"pack": 0.0, "dispatch": 0.0, "upload": 0.0,
-             "device_wait": 0.0, "download": 0.0, "extract": 0.0,
-             "d2h_bytes": 0, "h2d_bytes": 0}
-    folded: queue.Queue = queue.Queue(maxsize=3)
-    downloaded: queue.Queue = queue.Queue(maxsize=3)
-    errors = []
-    abort = threading.Event()
-    packed_chunks = []
-
-    def put(q, item) -> bool:
-        while not abort.is_set():
-            try:
-                q.put(item, timeout=0.25)
-                return True
-            except queue.Full:
-                continue
-        return False
-
-    def get(q):
-        while True:
-            try:
-                return q.get(timeout=0.25)
-            except queue.Empty:
-                if abort.is_set():
-                    return None
-
-    def pack_one(lo):
-        t0 = time.time()
-        state, ops, meta = pack_mergetree_batch(docs[lo:lo + CHUNK_DOCS])
-        # Narrow on the pack thread (the product pipeline's split) so the
-        # dispatch leg can count the h2d bytes that really cross.
-        from fluidframework_tpu.ops.mergetree_kernel import (
-            narrow_ops_for_upload,
-        )
-
-        ops = narrow_ops_for_upload(ops, meta)
-        return state, ops, meta, time.time() - t0
-
-    def packer():
-        # Packing is parallel across chunks (the C++ row-filling releases
-        # the GIL), dispatch stays in submission order.  At 50× the whole
-        # pipeline budget is under a second — a single-threaded pack stage
-        # alone would exceed it.  Submission rides a bounded sliding
-        # window so in-flight packed chunks stay capped (backpressure from
-        # the downstream queues) and an abort only waits out the ≤
-        # PACK_THREADS packs already running, cancelling the rest.
-        import collections
-        from concurrent.futures import ThreadPoolExecutor
-
-        starts = list(range(0, len(docs), CHUNK_DOCS))
-        window = PACK_THREADS + 1
-        futs: collections.deque = collections.deque()
-        try:
-            with ThreadPoolExecutor(max_workers=PACK_THREADS) as pool:
-                try:
-                    next_i = 0
-                    while next_i < len(starts) and len(futs) < window:
-                        futs.append(pool.submit(pack_one, starts[next_i]))
-                        next_i += 1
-                    while futs:
-                        fut = futs.popleft()
-                        state, ops, meta, dt = fut.result()
-                        if next_i < len(starts):
-                            futs.append(
-                                pool.submit(pack_one, starts[next_i])
-                            )
-                            next_i += 1
-                        stage["pack"] += dt  # busy (overlapped) seconds
-                        t0 = time.time()
-                        S = state.tstart.shape[1]
-                        stage["h2d_bytes"] += int(sum(
-                            np.asarray(x).nbytes for x in ops))
-                        ex = replay_export(None, ops, meta, S=S)
-                        stage["dispatch"] += time.time() - t0
-                        packed_chunks.append((None, ops, meta, S))
-                        if not put(folded, (meta, ex)):
-                            return
-                finally:
-                    # Cancel BEFORE the pool context exits — shutdown
-                    # waits for queued futures, so cancelling after it
-                    # would be dead code and delay error surfacing.
-                    for f in futs:
-                        f.cancel()
-        except BaseException as e:  # surface in main thread
-            errors.append(e)
-            abort.set()
-        finally:
-            put(folded, None)
-
-    def downloader():
-        try:
-            while True:
-                item = get(folded)
-                if item is None:
-                    break
-                meta, ex = item
-                # Honest split (mirrors the product pipeline): wait for
-                # device completion first, so "download" times the copy.
-                t0 = time.time()
-                jax.block_until_ready(ex)
-                stage["device_wait"] += time.time() - t0
-                t0 = time.time()
-                arr = export_to_numpy(ex)  # the D2H link RPC(s)
-                stage["download"] += time.time() - t0
-                stage["d2h_bytes"] += int(sum(
-                    a.nbytes for a in
-                    (arr if isinstance(arr, tuple) else (arr,))))
-                if not put(downloaded, (meta, arr)):
-                    break
-        except BaseException as e:
-            errors.append(e)
-            abort.set()
-        finally:
-            put(downloaded, None)
-
-    tp = threading.Thread(target=packer, daemon=True)
-    td = threading.Thread(target=downloader, daemon=True)
-    wall0 = time.time()
-    tp.start()
-    td.start()
-    summaries, stats = [], {}
-
-    def extract_one(meta, arr):
-        t0 = time.time()
-        st: dict = {}
-        res = summaries_from_export(meta, arr, stats=st)
-        return res, st, time.time() - t0
-
-    import collections
-    from concurrent.futures import ThreadPoolExecutor
-
-    futures: collections.deque = collections.deque()
-
-    def collect(fut) -> None:
-        res, st, dt = fut.result()
-        summaries.extend(res)
-        stage["extract"] += dt  # busy (overlapped) seconds
-        for k, v in st.items():
-            stats[k] = stats.get(k, 0) + v
-
-    try:
-        # Extraction fans out across chunks (the C++ extractor releases
-        # the GIL) through a BOUNDED sliding window (same shape as the
-        # packer's): in-flight chunk buffers stay capped — preserving the
-        # queue's backpressure — and an extraction error aborts within a
-        # window, not after the whole stream.  Collection order = submit
-        # order, so the summary list stays chunk-ordered.
-        with ThreadPoolExecutor(max_workers=EXTRACT_THREADS) as pool:
-            window = EXTRACT_THREADS + 1
-            while True:
-                item = get(downloaded)
-                if item is None:
-                    break
-                meta, arr = item
-                futures.append(pool.submit(extract_one, meta, arr))
-                if len(futures) >= window:
-                    collect(futures.popleft())
-            while futures:
-                collect(futures.popleft())
-    except BaseException as e:
-        errors.append(e)
-        abort.set()
-        # An extraction error must not wait out the queued window on pool
-        # shutdown (mirrors the packer's cancel-before-exit discipline).
-        for f in futures:
-            f.cancel()
-        raise
-    finally:
-        if errors:
-            abort.set()
-        tp.join()
-        td.join()
-    if errors:
-        raise errors[0]
     return summaries, stats, stage, time.time() - wall0, packed_chunks
 
 
@@ -971,12 +761,6 @@ def _run_bench(device: dict) -> dict:
         **catchup,
         **delta,
         "op_upload_MB": round(upload_bytes / 1e6, 1),
-        # The resolved choice — the same predicate run_e2e dispatches on.
-        "e2e_pipeline": (
-            "legacy"
-            if os.environ.get("BENCH_E2E_PIPELINE", "").lower() == "legacy"
-            else "single-device-thread"
-        ),
         "n_docs": N_DOCS,
         "ops_per_doc": OPS_PER_DOC,
     }

@@ -111,8 +111,7 @@ OV_SLOT_CAP = 8
 
 
 def ov_slot_count(tail_removers: np.ndarray, base_removers: np.ndarray,
-                  base_overlap: int, sequential: bool,
-                  cap: int = OV_SLOT_CAP) -> int:
+                  base_overlap: int, sequential: bool) -> int:
     """The overlap-remover slots a chunk folds with, from its packed input
     alone.  A client removes a given character at most once (its own
     remove is in its view), so the distinct clients with a remove or
@@ -123,7 +122,7 @@ def ov_slot_count(tail_removers: np.ndarray, base_removers: np.ndarray,
     slot each.  Sequential views never see a removed segment, so only the
     base records' own overlap removers (``base_overlap``, the longest "ro"
     list) need slots there.  0 keeps the overlap planes constant; more is
-    rounded up to a power of two, at most ``cap``."""
+    rounded up to a power of two, at most ``OV_SLOT_CAP``."""
     need = int(base_overlap)
     if not sequential and len(tail_removers):
         need = max(need, int((tail_removers + base_removers).max()) - 1)
@@ -132,7 +131,7 @@ def ov_slot_count(tail_removers: np.ndarray, base_removers: np.ndarray,
     slots = 1
     while slots < need:
         slots *= 2
-    return min(slots, cap)
+    return min(slots, OV_SLOT_CAP)
 
 
 def tail_remover_counts(kind: np.ndarray, client: np.ndarray) -> np.ndarray:
@@ -146,14 +145,6 @@ def tail_remover_counts(kind: np.ndarray, client: np.ndarray) -> np.ndarray:
     d, t = np.nonzero(rem)
     seen[d, col[d, t]] = True
     return seen.sum(axis=1)
-
-
-def ov_slot_cap() -> int:
-    """``OV_SLOT_CAP``; 1 under the Pallas fold, which keeps one overlap
-    slot (a third remover overflows there, as before)."""
-    from .pallas_fold import pallas_fold_mode
-
-    return 1 if pallas_fold_mode() else OV_SLOT_CAP
 
 
 def _visible_len(state: MTState, ref_seq, client,
@@ -1074,22 +1065,10 @@ def _out_shardings_for(i8: bool, sharding=None, digest: bool = False):
     return tuple(out)
 
 
-def _fold_fn(mode: str, sequential: bool = False, has_ob: bool = True,
+def _fold_fn(sequential: bool = False, has_ob: bool = True,
              has_props: bool = True, has_ov: bool = True):
-    """The batch fold: the lax.scan path by default (specialized at
-    compile time by the chunk facts — see ``_apply_op``); the Pallas
-    VMEM-resident kernel (ops/pallas_fold.py) when FF_PALLAS_FOLD selects
-    it — per-doc state stays on-chip across the whole tail instead of
-    round-tripping HBM every op step (SURVEY §7 hard-part #4).  The Pallas
-    fold keeps one overlap slot, so under it ``ov_slot_cap`` packs no
-    more.  The pallas import stays inside the branches: the default scan
-    path must not depend on jax.experimental.pallas importability."""
-    if mode in ("tpu", "interpret"):
-        from .pallas_fold import replay_vmapped_pallas
-
-        interpret = mode == "interpret"
-        return lambda state, ops: replay_vmapped_pallas(
-            state, ops, interpret=interpret)
+    """The batch fold: the lax.scan path, specialized at compile time by
+    the chunk facts (see ``_apply_op``)."""
     return lambda state, ops: replay_vmapped(state, ops, sequential,
                                              has_ob, has_props, has_ov)
 
@@ -1133,10 +1112,9 @@ def program_name(family: str, digest: bool, start: str = "") -> str:
 
 @functools.lru_cache(maxsize=None)
 def _export_cold_fn(S: int, i16: bool, ob_rows: bool = True,
-                    fold_mode: str = "", ov_slots: int = 1,
-                    i8: bool = False, sequential: bool = False,
-                    has_props: bool = True, out_sharding=None,
-                    digest: bool = False):
+                    ov_slots: int = 1, i8: bool = False,
+                    sequential: bool = False, has_props: bool = True,
+                    out_sharding=None, digest: bool = False):
     """Compiled cold-start fold+export for one (S, width, layout) bucket,
     its output laid out for a line-rate fetch.  ``ob_rows`` doubles as
     the fold fact has_ob and ``ov_slots`` (``ov_slot_count``) gives
@@ -1148,7 +1126,7 @@ def _export_cold_fn(S: int, i16: bool, ob_rows: bool = True,
     ONE derivation point for single-chip and multi-chip exports.
     ``digest`` appends the per-doc state digest plane (delta download)."""
     ov_slots = int(ov_slots)
-    fold = _fold_fn(fold_mode, sequential, ob_rows, has_props, ov_slots > 0)
+    fold = _fold_fn(sequential, ob_rows, has_props, ov_slots > 0)
 
     def f(ops, doc_base):
         with jax.named_scope("fold"):
@@ -1163,15 +1141,15 @@ def _export_cold_fn(S: int, i16: bool, ob_rows: bool = True,
 
 
 @functools.lru_cache(maxsize=None)
-def _export_warm_fn(i16: bool, ob_rows: bool = True, fold_mode: str = "",
-                    ov_slots: int = 1, i8: bool = False,
-                    sequential: bool = False, has_props: bool = True,
-                    out_sharding=None, digest: bool = False):
+def _export_warm_fn(i16: bool, ob_rows: bool = True, ov_slots: int = 1,
+                    i8: bool = False, sequential: bool = False,
+                    has_props: bool = True, out_sharding=None,
+                    digest: bool = False):
     """Compiled warm-start (base state uploaded) fold+export; see
     ``_export_cold_fn`` for ``ov_slots``/``out_sharding``/``digest``.
     The uploaded state carries the ``remx_*`` planes of its slots."""
     ov_slots = int(ov_slots)
-    fold = _fold_fn(fold_mode, sequential, ob_rows, has_props, ov_slots > 0)
+    fold = _fold_fn(sequential, ob_rows, has_props, ov_slots > 0)
 
     def f(state, ops, doc_base):
         with jax.named_scope("fold"):
@@ -1202,18 +1180,15 @@ def export_layout_rows(meta: dict) -> int:
 def _export_flags(meta: dict):
     """The transfer-layout facts BOTH sides of the export handshake use
     (dispatch builds the buffer, extraction widens it) — one derivation
-    point so they can never disagree.  The pallas fold ignores the chunk
-    facts, so its mode forces the props rows back on at both ends.  The
-    third fact is the chunk's overlap slot count (``ov_slot_count``)."""
-    from .pallas_fold import pallas_fold_mode
-
+    point so they can never disagree.  The third fact is the chunk's
+    overlap slot count (``ov_slot_count``)."""
     i16 = bool(meta.get("i16_ok"))
     return (
         i16,
         bool(meta.get("ob_rows", True)),
         int(meta.get("ov_slots", 1)),
         i16 and bool(meta.get("i8_ok")),
-        bool(meta.get("has_props", True)) or pallas_fold_mode() != "",
+        bool(meta.get("has_props", True)),
     )
 
 
@@ -1405,10 +1380,7 @@ def replay_export(state: Optional[MTState], ops: MTOps, meta: dict,
     it on device so an exact warm hit uploads nothing); it must equal
     ``meta["doc_base"]`` — passing the real bases is inert on layouts
     that ignore them."""
-    from .pallas_fold import pallas_fold_mode
-
     i16, ob_rows, ov_slots, i8, has_props = _export_flags(meta)
-    mode = pallas_fold_mode()
     # The digest rebases tstart per doc even on non-i16 chunks, so an
     # unchanged document digests identically across repacks that moved
     # its absolute arena offsets (_export_state reads doc_base only
@@ -1417,18 +1389,14 @@ def replay_export(state: Optional[MTState], ops: MTOps, meta: dict,
         doc_base = jnp.asarray(meta["doc_base"]) if (i16 or digest) else \
             jnp.zeros((ops.kind.shape[0],), jnp.int32)
     ops = narrow_ops_for_upload(ops, meta)  # h2d transfer encoding
-    # The pallas fold ignores the chunk facts — normalize so mixed
-    # workloads don't compile duplicate executables per cache key
-    # (has_props is already mode-normalized inside _export_flags, the
-    # shared dispatch/extraction derivation point).
-    sequential = bool(meta.get("sequential")) and mode == ""
+    sequential = bool(meta.get("sequential"))
     if state is None:
-        return _export_cold_fn(int(S), i16, ob_rows, mode, ov_slots,
-                               i8, sequential, has_props,
+        return _export_cold_fn(int(S), i16, ob_rows, ov_slots, i8,
+                               sequential, has_props,
                                digest=digest)(ops, doc_base)
     state = narrow_state_for_upload(state, meta)
-    return _export_warm_fn(i16, ob_rows, mode, ov_slots, i8,
-                           sequential, has_props,
+    return _export_warm_fn(i16, ob_rows, ov_slots, i8, sequential,
+                           has_props,
                            digest=digest)(state, ops, doc_base)
 
 
@@ -1771,8 +1739,7 @@ def pack_mergetree_batch(docs: Sequence[MergeTreeDocInput]):
     )
     ov_slots = ov_slot_count(
         tail_remover_counts(op["kind"], op["client"]), base_removers,
-        max((len(ids) for _d, _s, ids in base_ro), default=0), sequential,
-        ov_slot_cap())
+        max((len(ids) for _d, _s, ids in base_ro), default=0), sequential)
     st["remx_seq"] = tuple(np.full((D, S), NOT_REMOVED, np.int32)
                            for _ in range(ov_slots - 1))
     st["remx_client"] = tuple(np.full((D, S), -1, np.int32)
@@ -1926,15 +1893,14 @@ def known_oracle_fallback(doc: MergeTreeDocInput) -> bool:
 def _known_oracle_fallback_uncached(doc: MergeTreeDocInput) -> bool:
     """True when a doc is known *before packing* to need the oracle path:
     more overlap removers on a base record than the device has slots
-    (``ov_slot_cap``), >2 obliterate stamps on a base record (two device
+    (``OV_SLOT_CAP``), >2 obliterate stamps on a base record (two device
     stamp slots), or interval ops mixed with obliterate ops
     (reference-slide timing over obliterated segments is host-folded only
     through the oracle).  Pack-time's ``needs_fallback`` applies the same
     rules; filtering first keeps such docs from inflating the shared
     power-of-two buckets."""
-    cap = ov_slot_cap()
     for r in doc.base_records or []:
-        if len(r.get("ro", [])) > cap or len(r.get("ob", [])) > 2:
+        if len(r.get("ro", [])) > OV_SLOT_CAP or len(r.get("ob", [])) > 2:
             return True
     has_interval = doc.base_intervals is not None
     has_obl = False

@@ -65,7 +65,6 @@ from .mergetree_kernel import (
     narrow_ops_for_upload,
     narrow_state_for_upload,
     oracle_fallback_summary,
-    ov_slot_cap,
     ov_slot_count,
     pack_mergetree_batch,
     replay_export,
@@ -472,7 +471,7 @@ def _refresh_mergetree_facts(state, op, meta, chunk):
     ov_slots = ov_slot_count(
         tail_remover_counts(op["kind"], op["client"]),
         base.max(axis=1, initial=0), int(overlap.max(initial=0)),
-        sequential, ov_slot_cap())
+        sequential)
     meta["ov_slots"] = ov_slots
     meta["has_props"] = len(meta["prop_keys"]) > 0
     extra = ov_slots - 1 - len(state.remx_seq)

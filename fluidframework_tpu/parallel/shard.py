@@ -146,10 +146,10 @@ def sharded_export_step(mesh: Mesh, S: int, i16: bool, ob_rows: bool,
     its shard."""
     shard = NamedSharding(mesh, _doc_spec(mesh))
     if warm:
-        return _export_warm_fn(i16, ob_rows, "", ov_slots, i8, sequential,
+        return _export_warm_fn(i16, ob_rows, ov_slots, i8, sequential,
                                has_props, out_sharding=shard,
                                digest=digest)
-    return _export_cold_fn(S, i16, ob_rows, "", ov_slots, i8, sequential,
+    return _export_cold_fn(S, i16, ob_rows, ov_slots, i8, sequential,
                            has_props, out_sharding=shard, digest=digest)
 
 

@@ -8,7 +8,9 @@ The platform is pinned with jax.config.update before the first backend use
 environment can move the suite onto an accelerator.
 """
 
+import importlib.util
 import os
+import pathlib
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -17,6 +19,7 @@ if "xla_force_host_platform_device_count" not in _flags:
     ).strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -27,3 +30,16 @@ def pytest_configure(config):
         "slow: long-running (nightly) tests, excluded from tier-1's "
         "-m 'not slow' run",
     )
+
+
+@pytest.fixture
+def graft_entry():
+    """The repo root's ``__graft_entry__`` module (the dryrun's entry points
+    and its hard-semantics documents), loaded from its file."""
+    spec = importlib.util.spec_from_file_location(
+        "__graft_entry__",
+        pathlib.Path(__file__).parent.parent / "__graft_entry__.py",
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

@@ -6,9 +6,9 @@ Multiple clients drive one document through thousands of random edits
 RANDOM PARTIAL DELIVERY, so sequenced ops carry genuinely lagged refs —
 the generator tracks per-client sequenced views instead of faking
 ``ref = seq - 1`` (VERDICT r4 weak #2: the old soak's concurrency knob
-was dead code).  The resulting log replays through the CPU oracle, the
-device kernel, and the Pallas-interpret fold with byte-identical
-summaries asserted at checkpoints and at the end.
+was dead code).  The resulting log replays through the CPU oracle and
+the device kernel with byte-identical summaries asserted at checkpoints
+and at the end.
 """
 
 import json
@@ -134,47 +134,6 @@ def test_beast_soak_oracle_vs_kernel():
                 f"kernel != oracle"
             )
         assert len(replica.text) > 200  # the soak built a real document
-
-
-def test_beast_soak_pallas_interpret(monkeypatch):
-    """The genuinely-concurrent log through the Pallas-interpret fold:
-    byte-identical summaries vs the fresh oracle.  A shorter prefix than
-    the scan soak — interpret mode runs the step loop in Python — but the
-    SAME generator, so arrival kills / overlap removers / lagged
-    annotates all appear.  Packed as when the Pallas fold serves (one
-    overlap slot)."""
-    monkeypatch.setenv("FF_PALLAS_FOLD", "interpret")
-    import jax.numpy as jnp
-
-    from fluidframework_tpu.ops.mergetree_kernel import (
-        _export_flags,
-        _export_state,
-        export_to_numpy,
-        pack_mergetree_batch,
-        summaries_from_export,
-    )
-    from fluidframework_tpu.ops.pallas_fold import replay_vmapped_pallas
-
-    N = 700
-    log, _live = _beast_log(21, N, obliterate=True)
-    assert _lagged_fraction(log) >= MIN_LAGGED_FRACTION
-    digests, _ = _oracle_digests(log, [log[-1].seq])
-    doc = MergeTreeDocInput(
-        doc_id="beast", ops=log, final_seq=log[-1].seq,
-        final_msn=max(m.min_seq for m in log),
-    )
-    state, ops, meta = pack_mergetree_batch([doc])
-    final = replay_vmapped_pallas(state, ops, interpret=True)
-    i16, ob_rows, ov_rows, i8, props_rows = _export_flags(meta)
-    doc_base = jnp.asarray(meta["doc_base"]) if i16 else \
-        jnp.zeros((1,), jnp.int32)
-    export = export_to_numpy(
-        _export_state(final, doc_base, i16, ob_rows, ov_rows, i8,
-                      props_rows=props_rows))
-    [summary] = summaries_from_export(meta, export)
-    assert summary.digest() == digests[log[-1].seq], (
-        "pallas-interpret summary != oracle on the concurrent soak"
-    )
 
 
 def test_beast_warm_restart_chain():

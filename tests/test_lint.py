@@ -214,14 +214,13 @@ def test_fluidfail_modules_lint_clean_with_zero_suppressions():
 
 def test_fluidshape_modules_lint_clean_with_zero_suppressions():
     """ISSUE 20 acceptance pin: every module the kernel family audits —
-    the Pallas fold, both kernel families, the resident-buffer cache,
-    the pipeline, and the mesh twin — passes ALL module rules (all six
-    families) with zero findings AND zero baseline entries.  The true
+    both kernel families, the resident-buffer cache, the pipeline, and
+    the mesh twin — passes ALL module rules (all six families) with zero
+    findings AND zero baseline entries.  The true
     positives the family caught (unannotated narrow casts in the export
     path, the unroutable delta-fetch gather index) were annotated with
     reviewed reasons, never baselined."""
     new_modules = [
-        "fluidframework_tpu/ops/pallas_fold.py",
         "fluidframework_tpu/ops/mergetree_kernel.py",
         "fluidframework_tpu/ops/tree_kernel.py",
         "fluidframework_tpu/ops/device_cache.py",

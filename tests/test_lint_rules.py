@@ -2704,7 +2704,7 @@ def test_kern_block_proven_violation_fires_despite_annotation():
 
 
 def test_kern_block_tuple_helper_route_accepted():
-    # the pallas_fold shape: dims unpacked from a tuple-returning wrapper
+    # the tuple-helper shape: dims unpacked from a tuple-returning wrapper
     # around the canonical round-up, consts aliased locally, grid algebra
     # over rounded names
     src = """

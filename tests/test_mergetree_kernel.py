@@ -383,6 +383,44 @@ def test_mergetree_kernel_obliterate_warm_start():
     assert summary.digest() == replicas[0].summarize().digest()
 
 
+def test_hard_mergetree_semantics_match_oracle(graft_entry):
+    """The dryrun's hard-semantics docs (deep-lag obliterate arrival kill,
+    overlap removers, annotate races, lagged fuzz logs, a warm obliterate
+    base) folded on one device through the served dispatch and
+    extraction, cold and warm, equal the CPU oracle byte for byte."""
+    from fluidframework_tpu.ops.mergetree_kernel import (
+        export_to_numpy,
+        pack_mergetree_batch,
+        replay_export,
+        summaries_from_export,
+    )
+
+    docs = graft_entry._hard_mergetree_docs()
+    oracle = {}
+    for doc in docs:
+        replica = SharedString(doc.doc_id)
+        if doc.base_records is not None:
+            replica.tree.load_records(doc.base_records, doc.base_seq,
+                                      doc.base_msn)
+        for m in doc.ops:
+            replica.process(m, local=False)
+        replica.advance(doc.final_seq, doc.final_msn)
+        oracle[doc.doc_id] = replica.summarize().digest()
+
+    cold = [d for d in docs if d.base_records is None]
+    assert len(cold) < len(docs), "hard docs must include a warm doc"
+    for chunk, warm in ((cold, False), (docs, True)):
+        state, ops, meta = pack_mergetree_batch(chunk)
+        export = replay_export(state if warm else None, ops, meta,
+                               S=state.tstart.shape[1])
+        stats = {}
+        summaries = summaries_from_export(meta, export_to_numpy(export),
+                                          stats=stats)
+        assert stats.get("device_docs") == len(chunk), stats
+        assert [s.digest() for s in summaries] == \
+            [oracle[d.doc_id] for d in chunk], f"warm={warm}"
+
+
 def test_sequential_tail_over_stamped_base_skips_kills_correctly():
     """The fold's sequential fast path skips the arrival-kill scan even
     when the BASE summary carries obliterate stamps (a stamp seq <=

@@ -74,20 +74,13 @@ def test_sharded_replay_single_doc_pads_to_mesh(fuzz_docs):
     assert summary.digest() == oracle_digests[0]
 
 
-def test_graft_entry_contract():
+def test_graft_entry_contract(graft_entry):
     """The driver's integration points: entry() compiles single-device;
     dryrun_multichip() runs the sharded step on the virtual mesh."""
-    import importlib.util, pathlib
-
-    spec = importlib.util.spec_from_file_location(
-        "__graft_entry__", pathlib.Path(__file__).parent.parent / "__graft_entry__.py"
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    fn, example_args = mod.entry()
+    fn, example_args = graft_entry.entry()
     out = jax.jit(fn)(*example_args)
     assert jax.tree.leaves(out), "entry() produced no outputs"
-    mod.dryrun_multichip(8)
+    graft_entry.dryrun_multichip(8)
 
 
 def test_dcn_mesh_shape_and_validation():
@@ -267,28 +260,14 @@ def test_matrix_sharded_matches_oracle_and_single_chip():
     assert [s.digest() for s in single] == oracle_digests
 
 
-def _graft_entry():
-    import importlib.util
-    import pathlib
-
-    spec = importlib.util.spec_from_file_location(
-        "__graft_entry__",
-        pathlib.Path(__file__).parent.parent / "__graft_entry__.py",
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_hard_mergetree_semantics_sharded_match_oracle():
+def test_hard_mergetree_semantics_sharded_match_oracle(graft_entry):
     """The dryrun's hard-semantics docs — deep-lag obliterate arrival
     kill, overlap removers, annotate races, lagged fuzz logs, warm
     obliterate base — must be RIGHT (CPU-oracle parity), not merely
     consistent between sharded and single-device (VERDICT r3 weak #4)."""
     from fluidframework_tpu.dds.sequence import SharedString
 
-    mod = _graft_entry()
-    docs = mod._hard_mergetree_docs()
+    docs = graft_entry._hard_mergetree_docs()
     directed = {d.doc_id: d for d in docs}
 
     # Directed deep-lag semantics, asserted on the oracle first: the
@@ -325,7 +304,7 @@ def test_hard_mergetree_semantics_sharded_match_oracle():
         [s.digest() for s in warm_single]
 
 
-def test_hard_tree_and_matrix_docs_sharded_match_single():
+def test_hard_tree_and_matrix_docs_sharded_match_single(graft_entry):
     from fluidframework_tpu.ops.matrix_kernel import replay_matrix_batch
     from fluidframework_tpu.ops.tree_kernel import replay_tree_batch
     from fluidframework_tpu.parallel import (
@@ -333,15 +312,14 @@ def test_hard_tree_and_matrix_docs_sharded_match_single():
         replay_tree_sharded,
     )
 
-    mod = _graft_entry()
-    tree_docs = mod._hard_tree_docs()
+    tree_docs = graft_entry._hard_tree_docs()
     assert any(d.base_summary is not None for d in tree_docs)
     t_sharded = replay_tree_sharded(tree_docs, mesh=doc_mesh())
     t_single = replay_tree_batch(tree_docs)
     assert [s.digest() for s in t_sharded] == \
         [s.digest() for s in t_single]
 
-    mx_docs = mod._hard_matrix_docs()
+    mx_docs = graft_entry._hard_matrix_docs()
     assert any(d.base_summary is not None for d in mx_docs)
     m_sharded = replay_matrix_sharded(mx_docs, mesh=doc_mesh())
     m_single = replay_matrix_batch(mx_docs)

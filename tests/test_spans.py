@@ -374,10 +374,10 @@ def _lower_mergetree(start: str, digest: bool):
     state, ops, doc_base, meta, (i16, ob, ov, i8, props) = _mt_chunk()
     sequential = bool(meta.get("sequential"))
     if start == "cold":
-        fn = _export_cold_fn(int(meta["_S"]), i16, ob, "", ov, i8,
+        fn = _export_cold_fn(int(meta["_S"]), i16, ob, ov, i8,
                              sequential, props, digest=digest)
         return fn.lower(ops, doc_base)
-    fn = _export_warm_fn(i16, ob, "", ov, i8, sequential, props,
+    fn = _export_warm_fn(i16, ob, ov, i8, sequential, props,
                          digest=digest)
     return fn.lower(state, ops, doc_base)
 

@@ -321,9 +321,8 @@ def test_summarize_wider_min_seq_emits_limbo():
     assert advanced.summarize(min_seq=4).digest() == wide.digest()
 
 
-# -- hardware-rule regression net: the tree family gets the same Mosaic
-# block-rule pin + non-divisible-bucket parity coverage that
-# test_pallas_fold.py gives the merge-tree family. --
+# -- hardware-rule regression net: the tree family's buckets satisfy the
+# Mosaic block rule, and pad rows stay inert on non-divisible buckets. --
 
 
 def _fuzz_doc_input(seed, steps):
@@ -334,8 +333,7 @@ def _fuzz_doc_input(seed, steps):
 
 
 def test_tree_buckets_satisfy_mosaic_block_rule():
-    """Mirror of test_pallas_fold.test_padded_block_dims_satisfy_mosaic_
-    rule for the tree family: every device-plane bucket the packer
+    """Every device-plane bucket the tree packer
     derives (N and T from tree_buckets, C inside pack_tree_batch) is a
     power-of-two ladder value at or above its floor — hence divisible
     by the 8-row sublane unit — and covers the per-doc used-row counts
@@ -366,8 +364,7 @@ def test_tree_buckets_satisfy_mosaic_block_rule():
 
 
 def test_tree_parity_on_nondivisible_buckets():
-    """Mirror of test_pallas_fold.test_pallas_fold_parity_on_
-    nondivisible_buckets: full digest parity on a batch whose natural
+    """Full digest parity on a batch whose natural
     buckets genuinely violate the (8, 128) lane rule — a doc count that
     is not a multiple of 8 and node/edit buckets that are not multiples
     of 128 — so pad rows must be provably inert, not accidentally

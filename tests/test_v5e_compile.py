@@ -120,7 +120,7 @@ def export_compiled(one_chip, mt_chunk):
                 remx_seq=(np.asarray(state_n.rem2_seq),) * extra,
                 remx_client=(np.asarray(state_n.rem2_client),) * extra)
         args = [_specs(ops_n, one_chip), _specs(doc_base, one_chip)]
-        flags = (facts["i16"], facts["ob_rows"], "", ov_slots,
+        flags = (facts["i16"], facts["ob_rows"], ov_slots,
                  facts["i8"], sequential, facts["has_props"])
         if warm:
             fn = _export_warm_fn(*flags, out_sharding=one_chip, digest=True)
@@ -226,8 +226,8 @@ def test_program_with_at_most_one_overlap_slot_is_the_flag_program(
     i16, ob, i8, props = (facts["i16"], facts["ob_rows"], facts["i8"],
                           facts["has_props"])
     sequential = facts["sequential"] and ov_slots == 0
-    flags = (i16, ob, "", ov_slots, i8, sequential, props)
-    fold = _fold_fn("", sequential, ob, props, ov_slots > 0)
+    flags = (i16, ob, ov_slots, i8, sequential, props)
+    fold = _fold_fn(sequential, ob, props, ov_slots > 0)
 
     def flag_program(*args):
         *state, ops, base = args
@@ -323,27 +323,3 @@ def test_sharded_export_step_compiles_on_four_chips(topo, one_chip,
             compiled.memory_analysis().argument_size_in_bytes
     assert per_device[4] * 4 == per_device[1], per_device
 
-
-class MosaicRefusal(Exception):
-    """The pinned refusal of the Pallas fold (see the xfail below)."""
-
-
-@pytest.mark.xfail(
-    raises=MosaicRefusal, strict=True,
-    reason="Mosaic refuses ops/pallas_fold.py: 'cannot statically prove "
-           "that index in dimension 1 is a multiple of 128' (ROADMAP "
-           "queue 1 item 2); the PR that fixes or deletes the Pallas fold "
-           "flips or removes this case")
-def test_pallas_fold_compiles(one_chip):
-    from fluidframework_tpu.ops.pallas_fold import replay_vmapped_pallas
-
-    state, ops, _meta = pack_mergetree_batch(
-        [bench.synth_doc(i, CHUNK_OPS) for i in range(64)])
-    fold = jax.jit(lambda s, o: replay_vmapped_pallas(s, o,
-                                                      interpret=False))
-    try:
-        fold.lower(_specs(state, one_chip), _specs(ops, one_chip)).compile()
-    except Exception as e:
-        if "cannot statically prove" in str(e):
-            raise MosaicRefusal(str(e)[:300]) from e
-        raise

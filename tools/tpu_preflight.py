@@ -18,19 +18,19 @@ stdout; exit 0 iff every gate passed.
   1. kernel-lint  — the fluidshape family (FL-KERN-*) over the package
      must be clean with ZERO suppressions (static Mosaic compliance,
      narrow-dtype bounds, bucket routing, pad masking, registry drift).
-  2. mergetree-parity — interpret-mode Pallas fold vs the jitted scan
-     reference on a small synth batch, field-exact on live slots.
+  2. mergetree-parity — the jitted scan fold, exported and extracted as
+     the catch-up path serves it, vs the CPU oracle on a small synth
+     batch, digest-exact.
   3. tree-parity  — device tree fold vs the CPU oracle on a minimal
      sequenced log, digest-exact.
   4. bench-schema — the roofline dict carries the keys the artifacts
      commit, and ``steady_fold_pct_of_bound`` is still derivable from
      it (and still spelled that way inside bench.py).
 
-NOTE (SEMANTICS.md): gate 1 is a static approximation and gates 2-3 run
-in interpret mode — passing preflight does NOT prove the kernel Mosaic-
-compiles on a real chip (gate 1 passes a Pallas fold that Mosaic
-refuses).  ``tests/test_v5e_compile.py`` compiles the kernels for a
-described v5e instead.  Preflight exists so chip time is never spent
+NOTE (SEMANTICS.md): gate 1 is a static approximation and gates 2-3 fold
+on the CPU backend — passing preflight does NOT prove the kernels compile
+for a real chip.  ``tests/test_v5e_compile.py`` compiles the kernels for
+a described v5e instead.  Preflight exists so chip time is never spent
 discovering what CPU could have told us.
 """
 
@@ -69,47 +69,23 @@ def gate_kernel_lint():
 
 
 def gate_mergetree_parity():
-    """Interpret-mode Pallas fold == jitted scan reference, field-exact
-    on live slots (dead-slot garbage above ``n`` may differ)."""
-    import jax
-    import numpy as np
-
+    """Device merge-tree fold (jitted scan, export, extraction) == CPU
+    oracle on a small synth batch, digest-exact."""
     import bench
     from fluidframework_tpu.ops.mergetree_kernel import (
+        export_to_numpy,
         pack_mergetree_batch,
-        replay_vmapped,
+        replay_export,
+        summaries_from_export,
     )
-    from fluidframework_tpu.ops.pallas_fold import replay_vmapped_pallas
 
     docs = [bench.synth_doc(i, 16) for i in range(5)]
-    state, ops, _meta = pack_mergetree_batch(docs)
-    final_scan = jax.jit(replay_vmapped)(state, ops)
-    final_pallas = replay_vmapped_pallas(state, ops, interpret=True)
-    n = np.asarray(final_scan.n)
-
-    def planes(st):
-        """(name, plane) per plane; a tuple field (the overlap slots
-        past the first) plane by plane."""
-        for field in st._fields:
-            v = getattr(st, field)
-            if isinstance(v, tuple):
-                yield from ((f"{field}[{i}]", x) for i, x in enumerate(v))
-            else:
-                yield field, v
-
-    pa, pb = list(planes(final_scan)), list(planes(final_pallas))
-    assert [f for f, _x in pa] == [f for f, _x in pb]
-    for (field, av), (_f, bv) in zip(pa, pb):
-        av, bv = np.asarray(av), np.asarray(bv)
-        assert av.shape == bv.shape, field
-        if field in ("n", "overflow"):
-            assert np.array_equal(av, bv), field
-            continue
-        for d in range(len(docs)):
-            nd = int(n[d])
-            assert np.array_equal(av[d, :nd], bv[d, :nd]), \
-                f"{field} doc {d}"
-    return f"{len(docs)} docs, scan == pallas(interpret=True)"
+    state, ops, meta = pack_mergetree_batch(docs)
+    export = replay_export(None, ops, meta, S=state.tstart.shape[1])
+    device = summaries_from_export(meta, export_to_numpy(export))
+    assert [s.digest() for s in device] == \
+        [bench.oracle_replay(d).summarize().digest() for d in docs]
+    return f"{len(docs)} docs, device digests == oracle digests"
 
 
 def gate_tree_parity():
