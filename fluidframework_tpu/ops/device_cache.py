@@ -76,8 +76,8 @@ from .mergetree_kernel import MTOps, MTState, _widen_ops, _widen_state
 # The shared tier-2/2.5 contracts live in the pipeline module (no
 # cycle: pipeline never imports this module): _np_nbytes is THE "what
 # the dispatch jit pushes over h2d" byte rule the reductions compare
-# against, _doc_window/match_windows THE window-identity rules.
-from .pipeline import _doc_window, _np_nbytes, match_windows
+# against, doc_window/match_windows THE window-identity rules.
+from .pipeline import _np_nbytes, doc_window, match_windows
 
 
 def _dev_nbytes(*trees) -> int:
@@ -257,10 +257,6 @@ class MergeTreeDeviceOps:
     """The merge-tree family's tier-2.5 hooks: int16/int8 narrow
     encodings (with the in-graph narrow→wide migration), a single
     op-row splice axis, and the per-doc arena base as the aux plane."""
-
-    @staticmethod
-    def bypass(docs) -> bool:
-        return any(d.binary_ops is not None for d in docs)
 
     @staticmethod
     def sig(state, ops) -> tuple:
@@ -540,7 +536,7 @@ class DevicePackCache:
         chunk about to dispatch: the resident buffers on an exact hit
         (zero upload), a donated suffix splice on a lineage-proven
         extension, else a full upload that (re)stores the entry.
-        Token-less / family-bypass chunks bypass and return the host
+        Token-less chunks bypass and return the host
         arrays unchanged (``aux=None`` — the dispatcher derives it as
         before); ``h2d_bytes`` is what this call actually put on the
         link.  MUST be called from the single device-interaction thread
@@ -552,7 +548,7 @@ class DevicePackCache:
         copies (cheaper than a repack; counted in ``h2d_bytes``)."""
         docs = meta["docs"]
         tokens = tuple(d.cache_token for d in docs)
-        if any(t is None for t in tokens) or self._fam.bypass(docs):
+        if any(t is None for t in tokens):
             with self._lock:
                 self.counters.bump("bypass")
             return state, ops, None, _np_nbytes(state) + _np_nbytes(ops)
@@ -626,7 +622,7 @@ class DevicePackCache:
         combined (extended) chunk."""
         n_ops, first_seq, last_seq = [], [], []
         for doc in docs:
-            n, first, last = _doc_window(doc)
+            n, first, last = doc_window(doc)
             n_ops.append(n)
             first_seq.append(first)
             last_seq.append(last)
@@ -669,7 +665,7 @@ class DevicePackCache:
                host_ops, meta: dict, pin: bool = False) -> None:
         n_ops, first_seq, last_seq = [], [], []
         for doc in docs:
-            n, first, last = _doc_window(doc)
+            n, first, last = doc_window(doc)
             n_ops.append(n)
             first_seq.append(first)
             last_seq.append(last)

@@ -44,8 +44,6 @@ class KernelFamily:
 
     pack / tier 2
       - ``pack(chunk)`` → ``(state, ops, meta)``;
-      - ``bypass(doc)`` → bool: cache-ineligible beyond a missing token
-        (e.g. merge-tree binary streams);
       - ``entry_rows(chunk, meta)`` → per-doc used op-row counts (the
         suffix fill offsets the cache entry tracks);
       - ``entry_nbytes(state, ops, meta)`` → retained bytes for the LRU
@@ -89,7 +87,6 @@ class KernelFamily:
     fallback_summary: Callable[[Any], Any]
     # pack / tier 2
     pack: Callable[[Any], Tuple[Any, Any, dict]]
-    bypass: Callable[[Any], bool]
     entry_rows: Callable[[Any, dict], Any]
     entry_nbytes: Callable[[Any, Any, dict], int]
     extend: Optional[Callable[[Any, Any], Any]]

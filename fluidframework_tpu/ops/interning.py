@@ -45,6 +45,15 @@ class Interner:
         self._by_key: Dict[Any, int] = {}
         self.values: List[Any] = []
 
+    @classmethod
+    def of(cls, values) -> "Interner":
+        """An interner whose ids are the positions of ``values`` (distinct,
+        as an encoder's intern table is)."""
+        out = cls()
+        out.values = list(values)
+        out._by_key = {cls._hashable(v): i for i, v in enumerate(out.values)}
+        return out
+
     def intern(self, value: Any) -> int:
         key = self._hashable(value)
         idx = self._by_key.get(key)

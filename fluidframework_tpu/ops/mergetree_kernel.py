@@ -44,7 +44,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Any, Dict, List, NamedTuple, Optional, Sequence
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +54,7 @@ from ..protocol.messages import MessageType, SequencedMessage
 from ..protocol.summary import SummaryTree, canonical_json
 from ..utils.telemetry import span
 from .interning import Interner, TextArena, next_bucket, next_bucket_fine
-from .native_pack import count_stream
+from .native_pack import count_streams
 
 NOT_REMOVED = np.int32(np.iinfo(np.int32).max)
 # Property-column sentinels (values are interned ids >= 0).
@@ -1442,8 +1442,9 @@ class MergeTreeDocInput:
     # Native fast path: the ops pre-encoded as the liboppack binary record
     # stream (ops/native_pack.py) + the encoder's doc-local intern tables
     # (client ids; property keys / values when the stream annotates).
-    # Interval ops never ride the stream.  When set, ``ops`` may be empty
-    # (the stream is authoritative) — C++ fills this doc's arrays,
+    # Interval ops never ride the stream, nor does a document with
+    # ``base_records`` (the pack refuses it).  When set, ``ops`` may be
+    # empty (the stream is authoritative) — C++ fills this doc's arrays,
     # translating doc-local property ids into the batch-global spaces.
     binary_ops: Optional[bytes] = None
     binary_clients: Optional[Sequence[str]] = None
@@ -1464,6 +1465,29 @@ class MergeTreeDocInput:
     #: (first_seq .. last_seq) prefix is byte-identical.  None (the
     #: default) opts the doc out of pack caching entirely.
     cache_token: Optional[tuple] = None
+
+
+def prime_stream_counts(docs: Sequence[MergeTreeDocInput]) -> None:
+    """Count the streams of ``docs`` not yet counted, in one native call
+    (``count_streams``), and keep each document's counts on it."""
+    todo = [d for d in docs if d.binary_ops is not None
+            and getattr(d, "_stream_counts", None) is None]
+    if todo:
+        counts = count_streams([d.binary_ops for d in todo]).tolist()
+        for doc, row in zip(todo, counts):
+            doc._stream_counts = tuple(row)
+
+
+def stream_counts(doc: MergeTreeDocInput) -> Tuple[int, int, int, int, int]:
+    """``(n_ops, text_bytes, text_chars, first_seq, last_seq)`` of a stream
+    document, computed once per document: the pack sizes its rows from
+    it, and the cache tiers read the op window (count, first and last
+    seq) from it."""
+    counts = getattr(doc, "_stream_counts", None)
+    if counts is None:
+        prime_stream_counts([doc])
+        counts = doc._stream_counts
+    return counts
 
 
 class _DocPack:
@@ -1558,8 +1582,7 @@ def pack_mergetree_batch(docs: Sequence[MergeTreeDocInput]):
     # Power-of-two buckets: jitted shapes stay stable across batches instead
     # of recompiling the vmapped scan per (D, S, T, K).
     K = next_bucket(max(len(prop_keys), 1), floor=1)
-    binary_counts = {}
-    for i, d in enumerate(docs):
+    for d in docs:
         if d.binary_ops is not None:
             if d.base_records:
                 # Base-record clients would shift the encoder's dense client
@@ -1569,7 +1592,9 @@ def pack_mergetree_batch(docs: Sequence[MergeTreeDocInput]):
                     f"{d.doc_id}: binary_ops cannot be combined with "
                     f"base_records"
                 )
-            binary_counts[i] = count_stream(d.binary_ops)
+    prime_stream_counts(docs)
+    binary_counts = {i: d._stream_counts for i, d in enumerate(docs)
+                     if d.binary_ops is not None}
     text_op_counts = [
         binary_counts[i][0] if i in binary_counts else
         sum(1 for m in d.ops if not m.contents["kind"].startswith("interval"))
@@ -1625,18 +1650,36 @@ def pack_mergetree_batch(docs: Sequence[MergeTreeDocInput]):
     # (winner + "ro") any one record carries.
     base_ro = []
     base_removers = np.zeros((D,), np.int64)
-    # One raw-pointer packer per chunk: base addresses captured once, no
-    # per-doc ndarray marshalling (see native_pack.ChunkPacker).
-    from .native_pack import chunk_packer, pack_doc_row
+    from .native_pack import pack_streams
 
-    packer = chunk_packer(op) if binary_counts else None
     for d, doc in enumerate(docs):
         pack = doc_packs[d]
-        doc_base[d] = len(arena)
         if known_oracle_fallback(doc):
             # Docs routed here without the partition_replay pre-filter
             # still get the oracle (the docstring's pack-time parity).
             pack.needs_fallback = True
+        if doc.binary_ops is not None:
+            pack.clients = Interner.of(doc.binary_clients or ())
+            if d and docs[d - 1].binary_ops is not None:
+                continue  # packed with the run it belongs to
+            # Native fast path: one C++ call fills the rows of this whole
+            # run of stream docs, translating encoder-local property ids
+            # into the batch-global intern spaces.
+            end = d + 1
+            while end < D and docs[end].binary_ops is not None:
+                end += 1
+            run = docs[d:end]
+            text, chars = pack_streams(
+                op, d, [r.binary_ops for r in run],
+                [[prop_keys.intern(k) for k in r.binary_prop_keys or ()]
+                 for r in run],
+                [[values.intern(v) for v in r.binary_values or ()]
+                 for r in run],
+                len(arena), sum(binary_counts[i][1] for i in range(d, end)))
+            doc_base[d:end] = len(arena) + chars[:-1]
+            arena.append(text)
+            continue
+        doc_base[d] = len(arena)
         for s, rec in enumerate(doc.base_records or []):
             st["tstart"][d, s] = arena.append(rec["t"])
             st["tlen"][d, s] = len(rec["t"])
@@ -1664,39 +1707,6 @@ def pack_mergetree_batch(docs: Sequence[MergeTreeDocInput]):
             for key, value in rec.get("p", {}).items():
                 st["props"][d, s, prop_keys.intern(key)] = values.intern(value)
         st["n"][d] = len(doc.base_records or [])
-
-        if doc.binary_ops is not None:
-            # Native fast path: C++ fills this doc's rows in one pass,
-            # translating encoder-local property ids to the batch-global
-            # intern spaces via the maps.
-            for client in (doc.binary_clients or []):
-                pack.client_idx(client)
-            key_map = val_map = None
-            if doc.binary_prop_keys:
-                key_map = np.asarray(
-                    [prop_keys.intern(k) for k in doc.binary_prop_keys],
-                    np.int32,
-                )
-            if doc.binary_values:
-                val_map = np.asarray(
-                    [values.intern(v) for v in doc.binary_values],
-                    np.int32,
-                )
-            doc_bytes = bytearray()
-            if packer is not None:
-                packer.pack(doc.binary_ops, d, len(arena), doc_bytes,
-                            text_bytes=binary_counts[d][1],
-                            key_map=key_map, val_map=val_map)
-            else:
-                row = {key: op[key][d]
-                       for key in ("kind", "seq", "client", "ref_seq",
-                                   "min_seq", "a", "b", "tstart", "tlen",
-                                   "pvals")}
-                pack_doc_row(doc.binary_ops, row, K, len(arena), doc_bytes,
-                             text_bytes=binary_counts[d][1],
-                             key_map=key_map, val_map=val_map)
-            arena.append(doc_bytes.decode("utf-8"))
-            continue
 
         fill_sequence_op_rows(op, d, -1, doc.ops, pack, arena,
                               prop_keys.intern, values)

@@ -1,11 +1,14 @@
 """Native op packing: binary record streams + the liboppack C++ packer.
 
-The ingestion side (sequencer/scriptorium, bench synthesis, or the catch-up
-service's flatten step) encodes each string-channel op stream ONCE into the
-flat binary record format documented in ``native/oppack.cpp``; packing a
-10k-document batch for the device then runs entirely in C++ — one pass per
-document filling the padded (D, T) arrays and the shared text arena, no
-Python objects in the loop.
+The catch-up service's prepare (``CatchupService._kernel_inputs``) encodes
+each cold string channel's tail ONCE, straight from the decoded grouped
+batches, into the flat binary record format documented in
+``native/oppack.cpp`` (:func:`encode_channel_stream`); ``bench.py``'s
+synthesis encodes message lists (:func:`encode_string_ops`).  Packing a
+chunk for the device then runs in C++ — one pass per document filling the
+padded (D, T) arrays and the shared text arena, no Python objects in the
+loop, with the interpreter lock released (ctypes drops it), so the
+pipeline's pack threads run in parallel.
 
 It also hosts the extraction fast path: ``oppack_extract`` turns the fused
 final-state export buffer into canonical summary-body JSON bytes for a whole
@@ -32,6 +35,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..protocol.messages import MessageType, SequencedMessage
+from .interning import Interner
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -84,6 +88,67 @@ def encode_string_ops(
             out += _PAIR.pack(*pair)
         out += text
     return bytes(out)
+
+
+def encode_channel_stream(decoded, ds_id: str, channel_id: str):
+    """One channel's ops → ``(stream, clients, prop_keys, values)``, the
+    binary record stream and its doc-local intern tables, in one pass over
+    ``decoded`` (the ``(msg, batch)`` pairs of ``decode_stream``): the
+    stream :func:`encode_string_ops` writes for the flattened ops, without
+    building a message per sub-op.  Sub-ops keep their batch's seq.
+
+    None where the channel must keep the message path: an interval op
+    (intervals never ride the stream), an op kind the stream has no code
+    for, an empty insert (the stream gives it no arena offset), or text
+    that is not valid UTF-8 (a lone surrogate)."""
+    out = bytearray()
+    header, pair, kinds = _HEADER.pack, _PAIR.pack, _KINDS
+    clients: Dict[str, int] = {}
+    keys = values = None
+    for msg, batch in decoded:
+        for sub in batch["ops"]:
+            if sub.get("ds") != ds_id or sub.get("channel") != channel_id:
+                continue
+            op = sub["contents"]
+            kind = kinds.get(op["kind"])
+            if kind is None:
+                return None
+            cid = msg.client_id
+            if cid is None:
+                client = -1
+            else:
+                client = clients.get(cid)
+                if client is None:
+                    client = clients[cid] = len(clients)
+            if kind == 1:
+                text = op["text"]
+                if not text:
+                    return None
+                try:
+                    text = text.encode("utf-8")
+                except UnicodeEncodeError:
+                    return None
+                a, b = op["pos"], 0
+            else:
+                text = b""
+                a, b = op["start"], op["end"]
+            props = op.get("props")
+            if props:
+                if keys is None:
+                    keys, values = Interner(), Interner()
+                out += header(kind, msg.seq, msg.ref_seq, msg.min_seq,
+                              client, a, b, len(props), len(text))
+                for key, value in props.items():
+                    out += pair(keys.intern(key),
+                                -1 if value is None else values.intern(value))
+            else:
+                out += header(kind, msg.seq, msg.ref_seq, msg.min_seq,
+                              client, a, b, 0, len(text))
+            out += text
+    return (bytes(out), list(clients),
+            None if keys is None else list(keys.values),
+            None if values is None or not len(values)
+            else list(values.values))
 
 
 def decode_string_ops(
@@ -191,22 +256,20 @@ def load_library() -> Optional[ctypes.CDLL]:
         lib = ctypes.CDLL(path)
     except OSError:
         return None
+    i32 = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
+    i64 = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
     lib.oppack_count.restype = ctypes.c_int
-    lib.oppack_count.argtypes = [
-        ctypes.c_char_p, ctypes.c_int64,
-        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int64),
-        ctypes.POINTER(ctypes.c_int64),
-    ]
-    lib.oppack_pack.restype = ctypes.c_int32
+    lib.oppack_count.argtypes = [ctypes.c_char_p, i64, ctypes.c_int32, i64]
+    lib.oppack_pack.restype = ctypes.c_int64
     lib.oppack_pack.argtypes = [
-        ctypes.c_char_p, ctypes.c_int64,
-        ctypes.c_int32, ctypes.c_int32, ctypes.c_int64,
-    ] + [np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")] * 10 + [
+        ctypes.c_char_p, i64, ctypes.c_int32,              # run
+        ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,    # row0, T, K
+        ctypes.c_int64,                                    # arena base
+    ] + [i32] * 10 + [
         np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS"),
         ctypes.c_int64,
-        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
-        ctypes.c_void_p, ctypes.c_int32,   # key_map, n_keys
-        ctypes.c_void_p, ctypes.c_int32,   # val_map, n_vals
+        i64,                                               # doc_chars
+        i32, i64, i32, i64,                                # key/val maps
     ]
     lib.oppack_extract.restype = ctypes.c_int64
     lib.oppack_extract.argtypes = [
@@ -246,26 +309,32 @@ def load_library() -> Optional[ctypes.CDLL]:
     return lib
 
 
-def count_stream(blob: bytes) -> Tuple[int, int, int]:
-    """(n_ops, text_bytes, text_chars) for one binary stream."""
+def _run_offsets(blobs: Sequence[bytes]) -> np.ndarray:
+    offs = np.zeros(len(blobs) + 1, np.int64)
+    np.cumsum([len(b) for b in blobs], out=offs[1:])
+    return offs
+
+
+def count_streams(blobs: Sequence[bytes]) -> np.ndarray:
+    """(n_ops, text_bytes, text_chars, first_seq, last_seq) of each stream,
+    as the rows of an int64 array (both seqs 0 for an empty stream): one
+    native call for the whole run."""
     lib = load_library()
-    if lib is not None:
-        n_ops = ctypes.c_int32()
-        text_bytes = ctypes.c_int64()
-        text_chars = ctypes.c_int64()
-        rc = lib.oppack_count(blob, len(blob), ctypes.byref(n_ops),
-                              ctypes.byref(text_bytes),
-                              ctypes.byref(text_chars))
-        if rc != 0:
-            raise ValueError("malformed binary op stream")
-        return n_ops.value, text_bytes.value, text_chars.value
-    return _count_py(blob)
+    if lib is None:
+        return np.asarray([_count_py(b) for b in blobs],
+                          np.int64).reshape(len(blobs), 5)
+    out = np.zeros((len(blobs), 5), np.int64)
+    if lib.oppack_count(b"".join(blobs), _run_offsets(blobs), len(blobs),
+                        out) != 0:
+        raise ValueError("malformed binary op stream")
+    return out
 
 
-def _count_py(blob: bytes) -> Tuple[int, int, int]:
+def _count_py(blob: bytes) -> Tuple[int, int, int, int, int]:
     off, n, tb, tc = 0, 0, 0, 0
+    first = last = 0
     while off < len(blob):
-        (_kind, _seq, _ref, _msn, _cl, _a, _b, n_props,
+        (_kind, seq, _ref, _msn, _cl, _a, _b, n_props,
          text_len) = _HEADER.unpack_from(blob, off)
         off += _HEADER.size + 8 * n_props
         text = blob[off:off + text_len]
@@ -274,8 +343,28 @@ def _count_py(blob: bytes) -> Tuple[int, int, int]:
         off += text_len
         tb += text_len
         tc += len(text.decode("utf-8"))
+        if n == 0:
+            first = seq
+        last = seq
         n += 1
-    return n, tb, tc
+    return n, tb, tc, first, last
+
+
+def stream_offset(blob: bytes, n: int) -> int:
+    """Byte offset of record ``n`` of a stream (``len(blob)`` when it holds
+    exactly ``n``): a header walk that decodes no text.  The cache tiers
+    read a record's seq, or the records past a cached window, from it."""
+    off = 0
+    for _ in range(n):
+        (_kind, _seq, _ref, _msn, _cl, _a, _b, n_props,
+         text_len) = _HEADER.unpack_from(blob, off)
+        off += _HEADER.size + 8 * n_props + text_len
+    return off
+
+
+def stream_seq_at(blob: bytes, i: int) -> int:
+    """The seq of record ``i`` of a stream."""
+    return _HEADER.unpack_from(blob, stream_offset(blob, i))[1]
 
 
 def binary_has_obliterate(blob: bytes) -> bool:
@@ -295,128 +384,58 @@ _ROW_FIELDS = ("kind", "seq", "client", "ref_seq", "min_seq", "a", "b",
                "tstart", "tlen")
 
 
-def _raw_pack(lib):
-    """A second prototype for the SAME ``oppack_pack`` symbol taking raw
-    ``c_void_p`` row pointers.  The ndpointer prototype re-marshals every
-    ndarray argument on every call (~40% of chunk pack time at 11 arrays
-    × 1024 docs — profiled round 5); the batch packer precomputes each
-    field's base address once per chunk and passes ``base + d*row_bytes``
-    as plain ints instead."""
-    fn = getattr(lib, "_oppack_pack_raw", None)
-    if fn is None:
-        proto = ctypes.CFUNCTYPE(
-            ctypes.c_int32,
-            ctypes.c_char_p, ctypes.c_int64,
-            ctypes.c_int32, ctypes.c_int32, ctypes.c_int64,
-            *([ctypes.c_void_p] * 10),
-            ctypes.c_void_p, ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_void_p, ctypes.c_int32,
-            ctypes.c_void_p, ctypes.c_int32,
-        )
-        fn = proto(("oppack_pack", lib))
-        lib._oppack_pack_raw = fn
-    return fn
+def _flat_maps(maps: Sequence[Sequence[int]]):
+    offs = _run_offsets(maps)
+    flat = np.fromiter((i for m in maps for i in m), np.int32,
+                       count=int(offs[-1]))
+    return flat, offs
 
 
-class ChunkPacker:
-    """Per-chunk fast row packer: base addresses captured once from the
-    batch op arrays (which must outlive the packer), one shared text
-    scratch reused across docs."""
+def pack_streams(op: Dict[str, np.ndarray], row0: int,
+                 blobs: Sequence[bytes], key_maps: Sequence[Sequence[int]],
+                 val_maps: Sequence[Sequence[int]], arena_base_chars: int,
+                 text_bytes: int) -> Tuple[str, np.ndarray]:
+    """Fill rows ``row0 ..`` of the batch op arrays from a run of documents'
+    streams, in one native call that holds no interpreter lock, so the
+    pipeline's pack threads run in parallel.  ``key_maps``/``val_maps``
+    translate each document's encoder-local property key / value ids into
+    the batch-global intern spaces (empty = identity); ``text_bytes`` is
+    the run's text size (``count_streams``).  Returns the run's text, to
+    append to the arena at char ``arena_base_chars``, and the run's chars
+    before each document (n + 1 entries).
 
-    def __init__(self, op: Dict[str, np.ndarray], lib):
-        self._fn = _raw_pack(lib)
-        self._T = int(op["kind"].shape[1])
-        self._K = int(op["pvals"].shape[2])
-        self._bases = [op[f].ctypes.data for f in _ROW_FIELDS]
-        self._pvals_base = op["pvals"].ctypes.data
-        self._keepalive = op  # pin the arrays behind the raw pointers
-        self._scratch = np.zeros(1, np.uint8)
-
-    def pack(self, blob: bytes, d: int, arena_base_chars: int,
-             arena: bytearray, text_bytes: int,
-             key_map: Optional[np.ndarray] = None,
-             val_map: Optional[np.ndarray] = None) -> int:
-        T, K = self._T, self._K
-        if self._scratch.nbytes < max(text_bytes, 1):
-            self._scratch = np.zeros(max(text_bytes, 1), np.uint8)
-        arena_bytes = ctypes.c_int64()
-        arena_chars = ctypes.c_int64()
-        row_off = d * T * 4
-        ptrs = [b + row_off for b in self._bases]
-        ptrs.append(self._pvals_base + d * T * K * 4)
-        km = None if key_map is None else \
-            np.ascontiguousarray(key_map, np.int32)
-        vm = None if val_map is None else \
-            np.ascontiguousarray(val_map, np.int32)
-        packed = self._fn(
-            blob, len(blob), T, K, arena_base_chars, *ptrs,
-            self._scratch.ctypes.data, self._scratch.nbytes,
-            ctypes.byref(arena_bytes), ctypes.byref(arena_chars),
-            None if km is None else km.ctypes.data,
-            0 if km is None else len(km),
-            None if vm is None else vm.ctypes.data,
-            0 if vm is None else len(vm),
-        )
-        if packed < 0:
-            raise ValueError("malformed binary op stream")
-        arena += self._scratch[:arena_bytes.value].tobytes()
-        return packed
-
-
-def chunk_packer(op: Dict[str, np.ndarray]) -> Optional["ChunkPacker"]:
-    """A ChunkPacker when liboppack is available, else None (callers fall
-    back to the per-doc ``pack_doc_row`` pure-Python path)."""
+    Without the native library the rows fill in Python (``_pack_py``)."""
+    n = len(blobs)
+    if not 0 <= row0 <= row0 + n <= op["kind"].shape[0]:
+        raise ValueError(f"rows {row0}..{row0 + n} outside the batch")
     lib = load_library()
-    return None if lib is None else ChunkPacker(op, lib)
-
-
-def pack_doc_row(
-    blob: bytes,
-    row: Dict[str, np.ndarray],
-    K: int,
-    arena_base_chars: int,
-    arena: bytearray,
-    text_bytes: Optional[int] = None,
-    key_map: Optional[np.ndarray] = None,
-    val_map: Optional[np.ndarray] = None,
-) -> int:
-    """Fill one document's row of the batch arrays from its binary stream;
-    appends text to ``arena`` (utf-8 bytes) and returns ops packed.
-
-    ``row`` maps field name → the 1-D row views (``op['kind'][d]`` etc.,
-    C-contiguous); ``pvals`` is the (T, K) row.  ``key_map``/``val_map``
-    (int32 arrays) translate encoder-local property key / value ids into
-    the batch-global intern spaces."""
-    T = row["kind"].shape[0]
-    lib = load_library()
-    if lib is not None:
-        if text_bytes is None:
-            _n, text_bytes, _tc = count_stream(blob)
-        scratch = np.zeros(max(text_bytes, 1), np.uint8)
-        arena_bytes = ctypes.c_int64()
-        arena_chars = ctypes.c_int64()
-        km = None if key_map is None else \
-            np.ascontiguousarray(key_map, np.int32)
-        vm = None if val_map is None else \
-            np.ascontiguousarray(val_map, np.int32)
-        packed = lib.oppack_pack(
-            blob, len(blob), T, K, arena_base_chars,
-            row["kind"], row["seq"], row["client"], row["ref_seq"],
-            row["min_seq"], row["a"], row["b"], row["tstart"], row["tlen"],
-            row["pvals"].reshape(-1),
-            scratch, len(scratch),
-            ctypes.byref(arena_bytes), ctypes.byref(arena_chars),
-            None if km is None else km.ctypes.data,
-            0 if km is None else len(km),
-            None if vm is None else vm.ctypes.data,
-            0 if vm is None else len(vm),
-        )
-        if packed < 0:
-            raise ValueError("malformed binary op stream")
-        arena += scratch[:arena_bytes.value].tobytes()
-        return packed
-    return _pack_py(blob, row, K, arena_base_chars, arena, key_map, val_map)
+    if lib is None:
+        arena = bytearray()
+        doc_chars = np.zeros(n + 1, np.int64)
+        K = int(op["pvals"].shape[2])
+        for i, blob in enumerate(blobs):
+            d = row0 + i
+            row = {key: op[key][d] for key in _ROW_FIELDS + ("pvals",)}
+            before = len(arena)
+            _pack_py(blob, row, K, arena_base_chars + int(doc_chars[i]),
+                     arena, key_maps[i] or None, val_maps[i] or None)
+            doc_chars[i + 1] = doc_chars[i] + len(
+                arena[before:].decode("utf-8"))
+        return arena.decode("utf-8"), doc_chars
+    T, K = int(op["kind"].shape[1]), int(op["pvals"].shape[2])
+    scratch = np.empty(max(text_bytes, 1), np.uint8)
+    doc_chars = np.zeros(n + 1, np.int64)
+    key_flat, key_offs = _flat_maps(key_maps)
+    val_flat, val_offs = _flat_maps(val_maps)
+    nbytes = lib.oppack_pack(
+        b"".join(blobs), _run_offsets(blobs), n, row0, T, K,
+        arena_base_chars, *(op[f] for f in _ROW_FIELDS), op["pvals"],
+        scratch, scratch.nbytes, doc_chars,
+        key_flat, key_offs, val_flat, val_offs,
+    )
+    if nbytes < 0:
+        raise ValueError("malformed binary op stream")
+    return scratch[:nbytes].tobytes().decode("utf-8"), doc_chars
 
 
 def _pack_py(blob: bytes, row: Dict[str, np.ndarray], K: int,

@@ -69,9 +69,11 @@ from .mergetree_kernel import (
     pack_mergetree_batch,
     replay_export,
     split_export_digest,
+    stream_counts,
     summaries_from_export,
     tail_remover_counts,
 )
+from .native_pack import decode_string_ops, stream_offset, stream_seq_at
 
 
 # ---------------------------------------------------------------------------
@@ -127,17 +129,28 @@ class _PackEntry:
         self.nbytes = nbytes
 
 
-def _doc_window(doc):
+def doc_window(doc):
+    """``(op count, first seq, last seq)`` of a document's tail, the window
+    tiers 0, 2 and 2.5 match on; a stream document's is read off its
+    record headers."""
+    if getattr(doc, "binary_ops", None) is not None:
+        n, _bytes, _chars, first, last = stream_counts(doc)
+        return n, first, last
     n = len(doc.ops)
     if n == 0:
         return 0, 0, 0
     return n, doc.ops[0].seq, doc.ops[-1].seq
 
 
+def _seq_at(doc, i: int) -> int:
+    blob = getattr(doc, "binary_ops", None)
+    return doc.ops[i].seq if blob is None else stream_seq_at(blob, i)
+
+
 def match_windows(n_ops, first_seq, last_seq, chunk) -> Optional[str]:
     """THE window-matching rule shared by tier 2 (:class:`PackCache`)
     and tier 2.5 (``ops/device_cache.DevicePackCache``), for EVERY
-    family (both carry ascending-seq message lists): "exact" when every
+    family (ascending-seq message lists or record streams): "exact" when every
     doc's op window is unchanged vs the cached per-doc
     ``(n_ops, first_seq, last_seq)``, "suffix" when every window
     extends its cached one (same first seq, the old tail still in
@@ -148,15 +161,15 @@ def match_windows(n_ops, first_seq, last_seq, chunk) -> Optional[str]:
     disagree with the packed host arrays they mirror."""
     exact = True
     for d, doc in enumerate(chunk):
-        n, first, _last = _doc_window(doc)
+        n, first, _last = doc_window(doc)
         cached_n = n_ops[d]
         if n < cached_n:
             return None
         if cached_n:
             if first != first_seq[d] \
-                    or doc.ops[cached_n - 1].seq != last_seq[d]:
+                    or _seq_at(doc, cached_n - 1) != last_seq[d]:
                 return None
-            if n > cached_n and doc.ops[cached_n].seq <= last_seq[d]:
+            if n > cached_n and _seq_at(doc, cached_n) <= last_seq[d]:
                 return None
         if n != cached_n:
             exact = False
@@ -175,9 +188,8 @@ class PackCache:
 
     Chunks are keyed by the ordered tuple of per-doc ``cache_token``s
     (doc + base summary + storage generation identity, supplied by the
-    catch-up service); any doc without a token — or any doc the family
-    marks ``bypass`` (e.g. binary-stream docs, whose C++ pack is already
-    the fast path) — bypasses the cache.
+    catch-up service); a chunk with any doc without a token bypasses the
+    cache.
 
     Three outcomes per chunk:
 
@@ -239,8 +251,7 @@ class PackCache:
         freshly packed."""
         family = self.family
         tokens = tuple(d.cache_token for d in chunk)
-        if any(t is None for t in tokens) \
-                or any(family.bypass(d) for d in chunk):
+        if any(t is None for t in tokens):
             with self._lock:
                 self.counters.bump("bypass")
             return family.pack(chunk)
@@ -289,7 +300,7 @@ class PackCache:
         must still be unique per produced array set)."""
         n_ops, first_seq, last_seq = [], [], []
         for doc in chunk:
-            n, first, last = _doc_window(doc)
+            n, first, last = doc_window(doc)
             n_ops.append(n)
             first_seq.append(first)
             last_seq.append(last)
@@ -346,7 +357,7 @@ def _extend_mergetree(entry: _PackEntry, chunk):
     new_t_counts, suffixes = [], []
     new_keys = []
     for d, doc in enumerate(chunk):
-        suffix = doc.ops[entry.n_ops[d]:]
+        suffix = _suffix_ops(doc, entry.n_ops[d])
         suffixes.append(suffix)
         t_count = entry.t_rows[d]
         for msg in suffix:
@@ -395,6 +406,19 @@ def _extend_mergetree(entry: _PackEntry, chunk):
     )
     state = _refresh_mergetree_facts(entry.state, op, new_meta, chunk)
     return state, MTOps(**op), new_meta
+
+
+def _suffix_ops(doc, n: int):
+    """A document's ops past its first ``n``, as messages: a stream
+    document decodes only its records past the cached window, so the one
+    row fill below serves both kinds."""
+    if doc.binary_ops is None:
+        return doc.ops[n:]
+    blob = doc.binary_ops
+    return decode_string_ops(blob[stream_offset(blob, n):],
+                             list(doc.binary_clients or []),
+                             prop_keys=doc.binary_prop_keys,
+                             values=doc.binary_values)
 
 
 def _fill_mergetree_suffixes(chunk, suffixes, entry, op, arena, values,
@@ -994,6 +1018,7 @@ def _mt_dispatch_sharded(mesh, state, ops, meta, digest: bool, aux_dev):
 
 def _mt_entry_rows(chunk, meta):
     return [
+        stream_counts(doc)[0] if doc.binary_ops is not None else
         sum(1 for m in doc.ops
             if not m.contents["kind"].startswith("interval"))
         for doc in chunk
@@ -1022,7 +1047,6 @@ MERGETREE_FAMILY = KernelFamily(
     known_fallback=known_oracle_fallback,
     fallback_summary=oracle_fallback_summary,
     pack=pack_mergetree_batch,
-    bypass=lambda d: d.binary_ops is not None,
     entry_rows=_mt_entry_rows,
     entry_nbytes=_mt_entry_nbytes,
     extend=_extend_mergetree,

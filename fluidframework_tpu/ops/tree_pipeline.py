@@ -407,10 +407,6 @@ class TreeDeviceOps:
     and container state rows the suffix's inserts materialized."""
 
     @staticmethod
-    def bypass(docs) -> bool:
-        return False  # tree docs carry no binary-stream form
-
-    @staticmethod
     def sig(state, edits) -> tuple:
         return tuple_sig(state, edits)
 
@@ -520,7 +516,6 @@ TREE_FAMILY = KernelFamily(
     known_fallback=known_tree_fallback,
     fallback_summary=oracle_fallback_summary,
     pack=pack_tree_batch,
-    bypass=lambda d: False,
     entry_rows=_tree_entry_rows,
     entry_nbytes=_tree_entry_nbytes,
     extend=_extend_tree,

@@ -123,8 +123,8 @@ def flatten_channel_ops(
         for sub in batch["ops"]:
             if sub.get("ds") == ds_id and sub.get("channel") == channel_id:
                 # Direct construction — dataclasses.replace is ~4.5× the
-                # cost and this rewrap runs once per sub-op of every doc
-                # on the bulk catch-up path (keywords: robust to field
+                # cost and this rewrap runs once per sub-op of every
+                # message-path channel (keywords: robust to field
                 # insertion at ~the same cost).
                 out.append(SequencedMessage(
                     seq=msg.seq, client_id=msg.client_id,
@@ -727,8 +727,13 @@ class CatchupService:
         plan_idx): host-folded channel tree})``."""
         from ..ops.map_kernel import MapDocInput
         from ..ops.matrix_kernel import MatrixDocInput
+        from ..ops.native_pack import encode_channel_stream, load_library
         from ..ops.tree_kernel import TreeDocInput
 
+        # Without the native packer a stream would pack in Python, slower
+        # than the message pack: every string channel keeps the messages.
+        native = load_library() is not None
+        stats = self.pipeline_stats
         # Collect per-kernel inputs; (work_idx, plan_idx) → result slot.
         string_in: List[MergeTreeDocInput] = []
         map_in: List[MapDocInput] = []
@@ -744,7 +749,6 @@ class CatchupService:
             for pi, (ds_id, channel_id, type_name, channel_tree) in \
                     enumerate(work.plan):
                 cid = f"{work.doc_id}/{ds_id}/{channel_id}"
-                ops = flatten_channel_ops(work.decoded, ds_id, channel_id)
 
                 def channel_token(tree=channel_tree, cid=cid):
                     # THE append-only cache identity (tiers 0/2/2.5)
@@ -758,21 +762,43 @@ class CatchupService:
                     return (epoch, cid, work.ref_seq,
                             tree.digest() if tree is not None else "")
 
+                if type_name == STRING_TYPE:
+                    # A cold channel rides the native packer as a record
+                    # stream; a warm base keeps the messages (base-record
+                    # clients would shift the encoder's client ids), as
+                    # does a channel with interval ops (None here).
+                    stream = encode_channel_stream(
+                        work.decoded, ds_id, channel_id) \
+                        if native and channel_tree is None else None
+                    if stream is None:
+                        source = {"ops": flatten_channel_ops(
+                            work.decoded, ds_id, channel_id)}
+                    else:
+                        blob, clients, keys, values = stream
+                        source = {"ops": [], "binary_ops": blob,
+                                  "binary_clients": clients,
+                                  "binary_prop_keys": keys,
+                                  "binary_values": values}
+                    path = "pack_message_docs" if stream is None \
+                        else "pack_stream_docs"
+                    stats[path] = stats.get(path, 0) + 1
+                    slots[wi, pi] = (STRING_TYPE, len(string_in))
+                    string_in.append(MergeTreeDocInput(
+                        doc_id=cid, final_seq=final_seq,
+                        final_msn=final_msn,
+                        attribution=work.attribution,
+                        cache_token=channel_token(),
+                        **source,
+                        **self._string_base_kwargs(channel_tree),
+                    ))
+                    continue
+                ops = flatten_channel_ops(work.decoded, ds_id, channel_id)
                 if type_name not in KERNEL_TYPES:
                     self.host_channels += 1
                     host_trees[wi, pi] = self._host_channel_fold(
                         type_name, channel_id, channel_tree, ops, work,
                         final_msn,
                     )
-                elif type_name == STRING_TYPE:
-                    slots[wi, pi] = (STRING_TYPE, len(string_in))
-                    string_in.append(MergeTreeDocInput(
-                        doc_id=cid, ops=ops, final_seq=final_seq,
-                        final_msn=final_msn,
-                        attribution=work.attribution,
-                        cache_token=channel_token(),
-                        **self._string_base_kwargs(channel_tree),
-                    ))
                 elif type_name == MAP_TYPE:
                     base = None
                     if channel_tree is not None:

@@ -323,7 +323,7 @@ class DeltaExportCache:
       64-bit device digest to all match;
     - any missing entry, anchor drift, or digest mismatch falls back to
       the full download — the delta path can lose a win, never bytes;
-    - binary-stream and token-less documents bypass entirely.
+    - token-less documents bypass entirely.
 
     No wall-clock (LRU over insertion order); all mutation under one
     lock.  Counters: ``served`` (documents whose download+extract was
@@ -346,17 +346,16 @@ class DeltaExportCache:
 
     @staticmethod
     def _anchor(doc) -> tuple:
-        return (len(doc.ops), doc.final_seq, doc.final_msn,
+        # The op count of the window tiers 2/2.5 match on: a stream
+        # document's comes off its record headers (its ``ops`` is empty).
+        from ..ops.pipeline import doc_window
+
+        return (doc_window(doc)[0], doc.final_seq, doc.final_msn,
                 bool(doc.attribution))
 
     @staticmethod
     def _eligible(doc) -> bool:
-        # Binary-stream docs carry their ops opaquely (len(doc.ops) == 0
-        # would alias every window): bypass, like tier 2 does.  Families
-        # without a binary form (tree) simply lack the attribute — the
-        # tier is family-generic (round 14), so probe, don't assume.
-        return doc.cache_token is not None \
-            and getattr(doc, "binary_ops", None) is None
+        return doc.cache_token is not None
 
     # -- introspection ---------------------------------------------------------
 

@@ -8,7 +8,7 @@
 // that stream and fills the arrays in one pass — no Python objects, no
 // per-op dict lookups.
 //
-// Record layout (little-endian, packed):
+// Record layout (little-endian, packed; records in ascending seq):
 //   u8  kind        (1=insert, 2=remove, 3=annotate, 4=obliterate)
 //   i32 seq
 //   i32 ref_seq
@@ -26,8 +26,8 @@
 // can decode the byte arena once and every (tstart, tlen) span aligns.
 //
 // API (C ABI, ctypes-consumed):
-//   oppack_count(...)    — sizing pre-pass
-//   oppack_pack(...)     — fill one document's row of the batch arrays
+//   oppack_count(...)    — sizing pre-pass and the first/last seq, per stream
+//   oppack_pack(...)     — fill a run of documents' rows of the batch arrays
 //   oppack_extract(...)  — final device state → canonical summary-body JSON
 //
 // oppack_extract consumes the fused export buffer ([D, F, S] int32, see
@@ -60,50 +60,65 @@ inline int64_t count_codepoints(const uint8_t* p, int64_t n) {
 
 extern "C" {
 
-// Sizing pre-pass.  Returns 0 on success; -1 on truncated/malformed input.
-int oppack_count(const uint8_t* buf, int64_t len,
-                 int32_t* n_ops, int64_t* text_bytes, int64_t* text_chars) {
-    int64_t off = 0;
-    int32_t ops = 0;
-    int64_t bytes = 0, chars = 0;
-    while (off < len) {
-        if (off + kHeader > len) return -1;
-        int32_t fields[8];
-        std::memcpy(fields, buf + off + 1, 4 * 8);
-        const int32_t n_props = fields[6];
-        const int32_t text_len = fields[7];
-        off += kHeader;
-        if (n_props < 0 || text_len < 0) return -1;
-        if (off + 8 * static_cast<int64_t>(n_props) + text_len > len)
-            return -1;
-        off += 8 * static_cast<int64_t>(n_props);
-        chars += count_codepoints(buf + off, text_len);
-        bytes += text_len;
-        off += text_len;
-        ops += 1;
+// Sizing pre-pass over a run of `n` streams concatenated in `buf` (stream
+// i at doc_off[i] .. doc_off[i+1]): out[5*i ..] receives stream i's op
+// count, text bytes, text chars, and first and last seq (0, 0 when
+// empty), the window the cache tiers match on.  Returns 0 on success; -1
+// on truncated/malformed input.
+int oppack_count(const uint8_t* buf, const int64_t* doc_off, int32_t n,
+                 int64_t* out) {
+    for (int32_t i = 0; i < n; ++i) {
+        int64_t off = doc_off[i];
+        const int64_t len = doc_off[i + 1];
+        int64_t ops = 0, bytes = 0, chars = 0;
+        int32_t first = 0, last = 0;
+        while (off < len) {
+            if (off + kHeader > len) return -1;
+            int32_t fields[8];
+            std::memcpy(fields, buf + off + 1, 4 * 8);
+            const int32_t n_props = fields[6];
+            const int32_t text_len = fields[7];
+            off += kHeader;
+            if (n_props < 0 || text_len < 0) return -1;
+            if (off + 8 * static_cast<int64_t>(n_props) + text_len > len)
+                return -1;
+            off += 8 * static_cast<int64_t>(n_props);
+            chars += count_codepoints(buf + off, text_len);
+            bytes += text_len;
+            off += text_len;
+            if (ops == 0) first = fields[0];
+            last = fields[0];
+            ops += 1;
+        }
+        int64_t* o = out + 5 * static_cast<int64_t>(i);
+        o[0] = ops;
+        o[1] = bytes;
+        o[2] = chars;
+        o[3] = first;
+        o[4] = last;
     }
-    *n_ops = ops;
-    *text_bytes = bytes;
-    *text_chars = chars;
     return 0;
 }
 
+}  // extern "C"
+
+namespace {
 // Packs one document's record stream into row-slices of the batch arrays.
 // `pvals` is the (T, K) row in C order, pre-filled with PROP_NOT_TOUCHED.
 // `key_map` / `val_map` translate the encoder's doc-local property key and
 // value ids into the batch-global intern spaces (null = identity; negative
 // value ids — PROP_ABSENT — pass through untranslated).
 // Returns ops packed, or -1 on malformed input / capacity overflow.
-int32_t oppack_pack(const uint8_t* buf, int64_t len,
-                    int32_t T, int32_t K, int64_t arena_base_chars,
-                    int32_t* kind, int32_t* seq, int32_t* client,
-                    int32_t* ref_seq, int32_t* min_seq, int32_t* a,
-                    int32_t* b, int32_t* tstart, int32_t* tlen,
-                    int32_t* pvals,
-                    uint8_t* arena_out, int64_t arena_capacity,
-                    int64_t* arena_bytes, int64_t* arena_chars,
-                    const int32_t* key_map, int32_t n_keys,
-                    const int32_t* val_map, int32_t n_vals) {
+int32_t pack_doc(const uint8_t* buf, int64_t len,
+                 int32_t T, int32_t K, int64_t arena_base_chars,
+                 int32_t* kind, int32_t* seq, int32_t* client,
+                 int32_t* ref_seq, int32_t* min_seq, int32_t* a,
+                 int32_t* b, int32_t* tstart, int32_t* tlen,
+                 int32_t* pvals,
+                 uint8_t* arena_out, int64_t arena_capacity,
+                 int64_t* arena_bytes, int64_t* arena_chars,
+                 const int32_t* key_map, int64_t n_keys,
+                 const int32_t* val_map, int64_t n_vals) {
     int64_t off = 0;
     int32_t t = 0;
     int64_t out_bytes = 0, out_chars = 0;
@@ -161,6 +176,55 @@ int32_t oppack_pack(const uint8_t* buf, int64_t len,
     *arena_bytes = out_bytes;
     *arena_chars = out_chars;
     return t;
+}
+}  // namespace
+
+extern "C" {
+
+// Packs a run of `n` documents' record streams, concatenated in `buf`
+// (document i's at doc_off[i] .. doc_off[i+1]), into rows row0 .. row0+n-1
+// of the [D, T] op arrays (`pvals` [D, T, K]) in one call, so a chunk's
+// pack crosses from Python once.  The run's text lands in `arena_out` in
+// document order, its first char at arena char `arena_base_chars`;
+// doc_chars[i] receives the run's chars before document i (doc_chars[n]
+// the run's total).  key_map / val_map hold the documents' translation
+// tables back to back, document i's at key_off[i] .. key_off[i+1] (an
+// empty table = identity).  Returns the run's text bytes, or -1 on
+// malformed input / capacity overflow.
+int64_t oppack_pack(const uint8_t* buf, const int64_t* doc_off, int32_t n,
+                    int32_t row0, int32_t T, int32_t K,
+                    int64_t arena_base_chars,
+                    int32_t* kind, int32_t* seq, int32_t* client,
+                    int32_t* ref_seq, int32_t* min_seq, int32_t* a,
+                    int32_t* b, int32_t* tstart, int32_t* tlen,
+                    int32_t* pvals,
+                    uint8_t* arena_out, int64_t arena_capacity,
+                    int64_t* doc_chars,
+                    const int32_t* key_map, const int64_t* key_off,
+                    const int32_t* val_map, const int64_t* val_off) {
+    int64_t bytes = 0, chars = 0;
+    for (int32_t i = 0; i < n; ++i) {
+        const int64_t row = static_cast<int64_t>(row0 + i) * T;
+        const int64_t n_keys = key_off[i + 1] - key_off[i];
+        const int64_t n_vals = val_off[i + 1] - val_off[i];
+        int64_t doc_bytes = 0, doc_chars_i = 0;
+        doc_chars[i] = chars;
+        if (pack_doc(buf + doc_off[i], doc_off[i + 1] - doc_off[i], T, K,
+                     arena_base_chars + chars,
+                     kind + row, seq + row, client + row, ref_seq + row,
+                     min_seq + row, a + row, b + row, tstart + row,
+                     tlen + row, pvals + row * K,
+                     arena_out + bytes, arena_capacity - bytes,
+                     &doc_bytes, &doc_chars_i,
+                     n_keys ? key_map + key_off[i] : nullptr, n_keys,
+                     n_vals ? val_map + val_off[i] : nullptr,
+                     n_vals) < 0)
+            return -1;
+        bytes += doc_bytes;
+        chars += doc_chars_i;
+    }
+    doc_chars[n] = chars;
+    return bytes;
 }
 
 // Final device state → canonical summary-body JSON for every document of a

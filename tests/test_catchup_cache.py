@@ -444,6 +444,8 @@ def test_pack_cache_bails_to_full_pack_when_buckets_grow():
 
 
 def test_pack_cache_bypasses_binary_and_untokened_docs():
+    """A chunk with a token-less doc bypasses tier 2; a binary-stream
+    chunk whose docs all carry tokens is cached like any other."""
     cache = PackCache()
     binary = [bench.synth_doc(i, 16) for i in range(4)]  # no tokens
     got = pipelined_mergetree_replay(binary, chunk_docs=8,
@@ -452,6 +454,16 @@ def test_pack_cache_bypasses_binary_and_untokened_docs():
         [s.digest() for s in replay_mergetree_batch(binary)]
     stats = cache.stats()
     assert stats["bypass"] == 1 and stats["inserts"] == 0, stats
+    for i, doc in enumerate(binary):
+        doc.cache_token = ("tok", i)
+    for _pass in range(2):
+        got = pipelined_mergetree_replay(binary, chunk_docs=8,
+                                         pack_cache=cache)
+        assert [s.digest() for s in got] == \
+            [s.digest() for s in replay_mergetree_batch(binary)]
+    stats = cache.stats()
+    assert (stats["bypass"], stats["inserts"], stats["exact_hits"]) == \
+        (1, 1, 1), stats
 
 
 def test_pack_cache_byte_bound_evicts():
@@ -462,6 +474,75 @@ def test_pack_cache_byte_bound_evicts():
         [s.digest() for s in replay_mergetree_batch(docs)]
     stats = cache.stats()
     assert stats["entries"] == 0 and stats["evictions"] >= 1, stats
+
+
+def _append_ops(service, doc_id, msgs):
+    """Append a doc's sequenced channel ops to the op log, each in the
+    groupedBatch envelope the container runtime emits."""
+    from fluidframework_tpu.protocol.messages import (
+        MessageType,
+        SequencedMessage,
+    )
+
+    for m in msgs:
+        service.oplog.append(doc_id, SequencedMessage(
+            seq=m.seq, client_id=m.client_id, client_seq=m.client_seq,
+            ref_seq=m.ref_seq, min_seq=m.min_seq, type=MessageType.OP,
+            contents={"type": "groupedBatch", "ops": [
+                {"ds": "ds", "channel": "text", "clientSeq": m.client_seq,
+                 "contents": m.contents}]}))
+
+
+def stream_service(n_docs=8, lo=120, hi=128):
+    """Cold string documents (an empty summary at seq 0) holding the first
+    ``lo`` ops of the pinned synth streams, which the catch-up service
+    packs as record streams; returns (service, doc ids, a ``grow(docs)``
+    that appends the ops up to ``hi``).  120 → 128 ops stays inside the
+    T=128 / S=256 fine buckets, so a grown window can extend in place."""
+    from fluidframework_tpu.runtime.container import ContainerRuntime
+
+    service = LocalOrderingService()
+    seeded = ContainerRuntime()
+    seeded.create_datastore("ds").create_channel("sequence-tpu", "text")
+    tree = seeded.summarize()
+    streams = [bench.doc_ops(bench.synth_doc(i, hi)) for i in range(n_docs)]
+    doc_ids = [f"sd{i}" for i in range(n_docs)]
+    for doc_id, msgs in zip(doc_ids, streams):
+        service.storage.upload(doc_id, tree, 0)
+        _append_ops(service, doc_id, msgs[:lo])
+
+    def grow(which):
+        for i in which:
+            _append_ops(service, doc_ids[i], streams[i][lo:])
+
+    return service, doc_ids, grow
+
+
+def container_fold(service, doc_ids):
+    cpu = CatchupService(service, mesh=None, cache=None, pack_cache=None)
+    cpu._device_plan = lambda w: None
+    return cpu.catch_up(doc_ids, upload=False)
+
+
+def test_service_stream_docs_ride_the_pack_cache():
+    """Stream documents keep tier 2: a grown tail suffix-extends the
+    cached window (decoding only the new records), an unchanged one hits
+    it exactly, and every fold matches the container fold."""
+    service, doc_ids, grow = stream_service()
+    svc = CatchupService(service, mesh=None, cache=None)
+    assert svc.catch_up(doc_ids, upload=False) == \
+        container_fold(service, doc_ids)
+    assert svc.pipeline_stats["pack_stream_docs"] == len(doc_ids)
+    grow([0, 7])
+    assert svc.catch_up(doc_ids, upload=False) == \
+        container_fold(service, doc_ids)
+    pc = svc._pack_cache.stats()
+    assert (pc["misses"], pc["suffix_hits"], pc["bypass"]) == (1, 1, 0), pc
+    assert svc.catch_up(doc_ids, upload=False) == \
+        container_fold(service, doc_ids)
+    assert svc._pack_cache.stats()["exact_hits"] == 1
+    assert svc.pipeline_stats["pack_stream_docs"] == 3 * len(doc_ids)
+    assert svc.pipeline_stats.get("pack_message_docs", 0) == 0
 
 
 def test_service_growth_rides_pack_suffix_reuse():

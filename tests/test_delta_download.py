@@ -178,6 +178,8 @@ def test_tier0_anchor_guards_host_side_inputs():
 
 
 def test_tier0_bypasses_binary_and_tokenless_docs():
+    """Token-less docs, binary or not, bypass tier 0; a tokened binary
+    doc is anchored on its stream's op count."""
     delta = DeltaExportCache()
     binary = bench.synth_doc(0, 16)  # binary stream, no token
     tokenless = MergeTreeDocInput(
@@ -188,6 +190,15 @@ def test_tier0_bypasses_binary_and_tokenless_docs():
         assert delta.serve(doc, (0, 0)) is None
         delta.put(doc, (0, 0), replay_mergetree_batch([doc])[0])
     assert len(delta) == 0
+    binary.cache_token = ("ep", "b", 0, "")
+    tree = replay_mergetree_batch([binary])[0]
+    delta.put(binary, (0, 0), tree)
+    assert delta.candidate(binary)
+    assert delta.serve(binary, (0, 0)) is tree
+    shorter = bench.synth_doc(0, 15)  # same token, one op fewer
+    shorter.cache_token = binary.cache_token
+    shorter.final_seq = binary.final_seq
+    assert not delta.candidate(shorter)
 
 
 def test_tier0_byte_bound_and_lru_eviction():

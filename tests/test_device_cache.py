@@ -331,12 +331,51 @@ def test_tail_needing_more_overlap_slots_misses_the_resident_planes():
 
 
 def test_bypasses_binary_and_tokenless_chunks():
+    """A token-less chunk bypasses tier 2.5; a binary-stream chunk whose
+    docs all carry tokens stays resident."""
     dev = DevicePackCache()
     binary = [bench.synth_doc(i, 16) for i in range(4)]  # no tokens
     got, stage, _ = _run(binary, dev, None, chunk_docs=4)
     assert got == [s.digest() for s in replay_mergetree_batch(binary)]
     assert dev.stats()["bypass"] == 1 and len(dev) == 0
     assert stage["h2d_bytes"] > 0  # the full upload is still counted
+    for i, doc in enumerate(binary):
+        doc.cache_token = ("ep", f"b{i}", 0, "")
+    _run(binary, dev, None, chunk_docs=4)
+    got, stage, _ = _run(binary, dev, None, chunk_docs=4)
+    assert got == [s.digest() for s in replay_mergetree_batch(binary)]
+    assert dev.stats()["served"] == 1 and len(dev) == 1
+    assert stage["h2d_bytes"] == 0
+
+
+def test_service_stream_docs_splice_and_serve_resident():
+    """Stream documents keep tiers 2.5 and 0 through the service: a grown
+    tail uploads only its suffix rows through the splice; an unchanged
+    catch-up dispatches the resident buffers with zero h2d bytes and
+    serves every summary from tier 0 without a download."""
+    from fluidframework_tpu.service.catchup import CatchupService
+    from tests.test_catchup_cache import container_fold, stream_service
+
+    service, doc_ids, grow = stream_service()
+    svc = CatchupService(service, mesh=None, cache=None)
+    stage = svc.pipeline_stage
+    svc.catch_up(doc_ids, upload=False)
+    cold_h2d = stage["h2d_bytes"]
+    assert svc.pipeline_stats["pack_stream_docs"] == len(doc_ids)
+
+    grow([2])
+    assert svc.catch_up(doc_ids, upload=False) == \
+        container_fold(service, doc_ids)
+    assert svc.device_cache.stats()["spliced"] == 1
+    assert 0 < stage["h2d_bytes"] - cold_h2d < cold_h2d
+
+    h2d, delta = stage["h2d_bytes"], svc.pipeline_stats.get("delta_docs", 0)
+    assert svc.catch_up(doc_ids, upload=False) == \
+        container_fold(service, doc_ids)
+    assert stage["h2d_bytes"] == h2d, "exact hit must upload nothing"
+    assert svc.device_cache.stats()["served"] == 1
+    assert svc.pipeline_stats["delta_docs"] - delta == len(doc_ids)
+    assert svc.delta_cache.stats()["served"] >= len(doc_ids)
 
 
 # --- cache unit behavior -----------------------------------------------------
